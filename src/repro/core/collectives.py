@@ -10,7 +10,7 @@ collectives:
     cross-pod broadcast  = [payload lane-sharded on root pod] -> psum(pod) -> all_gather(intra)
     cross-pod alltoall   = all_to_all(intra, regroup) -> all_to_all(pod)
 
-Every function here must be called INSIDE ``jax.experimental.shard_map``
+Every function here must be called INSIDE ``jax.shard_map``
 (they use named-axis collectives), mirroring how ``jax.lax.psum`` et al. are
 used.  The k-ported tree algorithms are also provided, compiled from the
 schedule generators into ``ppermute`` round programs — they exist so the
@@ -43,17 +43,10 @@ __all__ = [
 ]
 
 
-def _axis_size_one(axis_name) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    # pinned 0.4.x: core.axis_frame(name) resolves to the bound axis size
-    return int(jax.core.axis_frame(axis_name))
-
-
 def axis_size(axis_name) -> int:
     if isinstance(axis_name, (tuple, list)):
-        return int(np.prod([_axis_size_one(a) for a in axis_name]))
-    return _axis_size_one(axis_name)
+        return int(np.prod([jax.lax.axis_size(a) for a in axis_name]))
+    return int(jax.lax.axis_size(axis_name))
 
 
 def _pad_to_multiple(x: jax.Array, m: int, axis: int = 0):
@@ -160,7 +153,7 @@ def _axis_linear_index(axis_names: Sequence[str]):
         return jax.lax.axis_index(axis_names)
     idx = jax.lax.axis_index(axis_names[0])
     for a in axis_names[1:]:
-        idx = idx * _axis_size_one(a) + jax.lax.axis_index(a)
+        idx = idx * axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

@@ -6,8 +6,15 @@ On TPU this is the local ``[No, Ni, blk, d] -> [Ni, No, blk, d]`` block
 transpose that sits on either side of the two ``lax.all_to_all`` phases in
 ``repro.core.collectives.fulllane_all_to_all``.  XLA usually fuses this
 copy; the kernel exists to make the data movement explicit and VMEM-tiled
-(one (blk, d) tile per grid step, so arbitrary No*Ni fan-outs stream
-through VMEM instead of materializing a transposed HBM temp).
+(one ``(tb, td)`` tile of a ``(blk, d)`` block per grid step, so arbitrary
+No*Ni fan-outs and block sizes stream through VMEM instead of
+materializing a transposed HBM temp).  ``fulllane_all_to_all`` itself
+still leaves the transpose to XLA: no program path calls this kernel yet.
+
+Tiles are capped at ``_TILE_BYTES``: the pipeline double-buffers the input
+and the output tile, so a step holds four tiles, which must fit the scoped
+VMEM limit (16 MiB by default on v5e).  A whole ``(blk, d)`` block does not:
+at 64 MiB per chip one block of ``[2, 2, 8192, 512]`` f32 is 16 MiB.
 """
 
 from __future__ import annotations
@@ -16,11 +23,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import sublanes, tile
+
 __all__ = ["a2a_pack_kernel", "a2a_pack_pallas"]
+
+_TILE_BYTES = 2 << 20
 
 
 def a2a_pack_kernel(x_ref, o_ref):
-    # x block: [1, 1, blk, d] at (o, i); written to (i, o).
+    # x tile: [1, 1, tb, td] of block (o, i); written to block (i, o).
     o_ref[...] = x_ref[...]
 
 
@@ -31,11 +42,16 @@ def a2a_pack_pallas(
 ) -> jax.Array:
     """Returns x with the leading two (destination-group) dims swapped."""
     No, Ni, blk, d = x.shape
+    itemsize = x.dtype.itemsize
+    rows = sublanes(x.dtype)
+    td = tile(d, 128, max(128, _TILE_BYTES // (rows * itemsize)))
+    tb = tile(blk, rows, max(rows, _TILE_BYTES // (td * itemsize)))
+    spec = (1, 1, tb, td)
     return pl.pallas_call(
         a2a_pack_kernel,
-        grid=(No, Ni),
-        in_specs=[pl.BlockSpec((1, 1, blk, d), lambda o, i: (o, i, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, blk, d), lambda o, i: (i, o, 0, 0)),
+        grid=(No, Ni, blk // tb, d // td),
+        in_specs=[pl.BlockSpec(spec, lambda o, i, r, c: (o, i, r, c))],
+        out_specs=pl.BlockSpec(spec, lambda o, i, r, c: (i, o, r, c)),
         out_shape=jax.ShapeDtypeStruct((Ni, No, blk, d), x.dtype),
         interpret=interpret,
     )(x)

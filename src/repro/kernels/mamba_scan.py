@@ -11,8 +11,10 @@ dimension).  Within a chunk the recurrence is a ``fori_loop`` over time —
 the arithmetic-intensity-poor inner loop the VPU handles while the MXU-bound
 projections around it stay in XLA land.
 
-VMEM per step: a/b blocks 2 * chunk * block_d * N fp32 + state — at
-(chunk=64, block_d=512, N=16) about 4.5 MB.
+VMEM per step: the a/b blocks, double-buffered, are 4 * chunk * block_d *
+128 fp32 words, since VMEM pads the minor N dim (16 for Mamba-1) to 128
+lanes.  The defaults (chunk=8, block_d=512) take 8 MiB of the 16 MiB scoped
+limit on v5e; the old chunk=64 took 64 MiB and was refused at d_inner 8192.
 """
 
 from __future__ import annotations
@@ -62,7 +64,7 @@ def mamba_scan_pallas(
     b: jax.Array,  # [B, S, di, N] fp32 input
     c: jax.Array,  # [B, S, N]     fp32 readout
     *,
-    chunk: int = 64,
+    chunk: int = 8,
     block_d: int = 512,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:

@@ -55,7 +55,7 @@ def flash_attention(
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d"))
-def mamba_scan(a, b, c, *, chunk=64, block_d=512):
+def mamba_scan(a, b, c, *, chunk=8, block_d=512):
     return mamba_scan_pallas(
         a, b, c, chunk=chunk, block_d=block_d, interpret=not on_tpu()
     )

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import sublanes, tile
+
 __all__ = ["rmsnorm_kernel", "rmsnorm_pallas"]
 
 
@@ -33,9 +35,7 @@ def rmsnorm_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     T, d = x.shape
-    block_rows = min(block_rows, T)
-    while T % block_rows:
-        block_rows -= 1
+    block_rows = tile(T, sublanes(x.dtype), block_rows)
     kernel = functools.partial(rmsnorm_kernel, eps=eps)
     return pl.pallas_call(
         kernel,

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import SHAPES, ModelConfig, ShapeSpec
 from repro.launch import specs as SP
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hloanalysis import analyze_module
 from repro.launch.mesh import make_production_mesh
 from repro.models import lm
@@ -233,6 +234,7 @@ def main() -> None:
     ap.add_argument("--out-dir", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = ARCH_IDS if args.all else [args.arch.replace("-", "_")]
     shapes = list(SHAPES) if args.all or args.shape is None else [args.shape]

@@ -11,18 +11,12 @@ module touches no jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 explicit-sharding API; absent on the pinned 0.4.x
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-__all__ = ["make_production_mesh", "make_test_mesh"]
+__all__ = ["make_host_mesh", "make_production_mesh", "make_test_mesh"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -30,6 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _make_mesh(shape, axes)
+
+
+def make_host_mesh():
+    """``(pod, data, model) = (1, n, 1)`` over the ``n`` devices present: one
+    host is one pod, data-parallel across its chips."""
+    return _make_mesh((1, jax.device_count(), 1), ("pod", "data", "model"))
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):

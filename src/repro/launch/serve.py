@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.engine import Request, ServeEngine, temperature_sample, greedy_sample
 
@@ -29,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = lm.init_model(cfg, jax.random.PRNGKey(args.seed))

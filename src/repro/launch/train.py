@@ -1,6 +1,7 @@
 """Training driver: config -> mesh -> fault-tolerant train loop.
 
-Usage (CPU-scale example, the real mesh comes from make_production_mesh):
+Usage (CPU-scale example; without --mesh the mesh is (1, n, 1) over the
+n devices present):
 
   PYTHONPATH=src python -m repro.launch.train --arch yi_6b --smoke \\
       --steps 50 --batch 8 --seq 64 --ckpt-dir /tmp/ck --mesh 2,2,2
@@ -18,21 +19,29 @@ import dataclasses
 import time
 
 import jax
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke_config
-from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, make_test_mesh
 from repro.models import lm
 from repro.training import checkpoint as ckpt
 from repro.training.data import Prefetcher, SyntheticLM
 from repro.training.elastic import StragglerMonitor
 from repro.training.optimizer import OptConfig, init_opt_state
 from repro.training.train_step import (
+    batch_pspec,
     make_train_step_pjit,
     make_train_step_shardmap,
-    opt_pspecs,
-    param_pspecs,
 )
+
+
+def _place(tree, specs, mesh):
+    """Put each leaf of ``tree`` where its partition spec says, so the
+    compiled step receives its arguments sharded as it expects."""
+    return jax.device_put(tree, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P)))
 
 
 def main(argv=None) -> dict:
@@ -44,9 +53,11 @@ def main(argv=None) -> dict:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=3e-4)
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help=">0: cut the depth to this many layers, widths kept")
     ap.add_argument("--mesh", default="",
                     help="comma shape, e.g. 2,2,2 (pod,data,model); default "
-                         "production mesh")
+                         "(1, n, 1) over the n devices present")
     ap.add_argument("--backend", default="xla", choices=["xla", "fulllane"])
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=25)
@@ -55,8 +66,13 @@ def main(argv=None) -> dict:
     ap.add_argument("--corpus-size", type=int, default=0,
                     help=">0: cycle over a fixed corpus (learnable target)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.num_layers:
+        print(f"[train] depth cut: {cfg.name} num_layers {cfg.num_layers} -> "
+              f"{args.num_layers}")
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
     if args.backend != "xla":
         cfg = dataclasses.replace(
             cfg, parallel=dataclasses.replace(cfg.parallel, fsdp=False)
@@ -67,7 +83,8 @@ def main(argv=None) -> dict:
         axes = ("pod", "data", "model")[-len(shape):]
         mesh = make_test_mesh(shape, axes)
     else:
-        mesh = make_production_mesh()
+        mesh = make_host_mesh()
+    print(f"[train] mesh {dict(mesh.shape)} backend={args.backend}")
 
     opt_cfg = OptConfig(learning_rate=args.lr,
                         moment_dtype=cfg.parallel.optimizer_dtype)
@@ -92,40 +109,51 @@ def main(argv=None) -> dict:
     )
     sample_batch = next(iter(SyntheticLM(cfg, args.batch, args.seq)))[1]
     if args.backend == "xla":
-        mk, _ = make_train_step_pjit(cfg, mesh, opt_cfg)
+        mk, (pspec, ospec) = make_train_step_pjit(cfg, mesh, opt_cfg)
     else:
-        mk, _ = make_train_step_shardmap(cfg, mesh, opt_cfg,
-                                         backend=args.backend)
-    step_fn = mk(sample_batch)
+        mk, (pspec, ospec) = make_train_step_shardmap(cfg, mesh, opt_cfg,
+                                                      backend=args.backend)
+    bspec = batch_pspec(mesh, sample_batch)
+    params = _place(params, pspec, mesh)
+    opt_state = _place(opt_state, ospec, mesh)
+    t0 = time.time()
+    step_fn = mk(sample_batch).lower(params, opt_state, sample_batch).compile()
+    compile_s = time.time() - t0
+    print(f"[train] step compiled in {compile_s:.2f}s")
 
     saver = ckpt.AsyncCheckpointer(args.ckpt_dir) if args.ckpt_dir else None
     monitor = StragglerMonitor()
-    history = []
+    history, grad_norms = [], []
     t_total = time.time()
     for step, batch in stream:
         if step >= args.steps:
             break
         t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
+        params, opt_state, metrics = step_fn(params, opt_state,
+                                             _place(batch, bspec, mesh))
         loss = float(metrics["loss"])  # sync point
+        gnorm = float(metrics["grad_norm"])
         dt = time.time() - t0
         action = monitor.observe(dt)
         if action != "ok":
             print(f"[train] step {step}: straggler action={action} "
                   f"({dt:.2f}s vs ema {monitor.ema:.2f}s)")
         history.append(loss)
+        grad_norms.append(gnorm)
         if step % args.log_every == 0:
             print(f"[train] step {step:5d} loss={loss:.4f} "
-                  f"gnorm={float(metrics['grad_norm']):.3f} {dt:.2f}s")
+                  f"gnorm={gnorm:.3f} {dt:.2f}s")
         if saver and step > start_step and step % args.ckpt_every == 0:
             saver.save(step, {"params": params, "opt": opt_state},
                        extra={"arch": args.arch})
     if saver:
         saver.wait()
     out = {"first_loss": history[0], "last_loss": history[-1],
-           "steps": len(history), "seconds": time.time() - t_total}
+           "steps": len(history), "seconds": time.time() - t_total,
+           "compile_s": compile_s}
     print(f"[train] done: {out}")
-    return out
+    return {**out, "losses": history, "grad_norms": grad_norms,
+            "compiled": step_fn}
 
 
 if __name__ == "__main__":

@@ -41,24 +41,6 @@ from repro.models.params import partition_specs
 from repro.training.optimizer import OptConfig, adamw_update, init_opt_state
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """jax.shard_map, failing cleanly on JAX versions without the API.
-
-    The 0.4.x ``jax.experimental.shard_map`` spelling (``auto`` = complement
-    of axis_names, ``check_rep``) is NOT a usable fallback here: compiling a
-    partial-manual program on the pinned jaxlib aborts the process inside
-    XLA, which would take the whole test run down with it.
-    """
-    if not hasattr(jax, "shard_map"):
-        raise NotImplementedError(
-            "make_train_step_shardmap requires jax.shard_map with "
-            "axis_names/check_vma (partial-manual lowering crashes the "
-            "pinned 0.4.x jaxlib); use make_train_step_pjit instead"
-        )
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names=axis_names,
-                         check_vma=check_vma)
-
 __all__ = [
     "dp_axes",
     "mesh_axis_sizes",
@@ -169,22 +151,7 @@ def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
         is_leaf=lambda x: isinstance(x, P),
     )
 
-    # Pinned-jax (0.4.37) miscompilation guard: with gradient accumulation
-    # AND a multi-codebook embed, the dim-0 DP sharding constraint makes
-    # GSPMD produce *wrong forward values* (the loss itself changes, and
-    # grad_norm drifts ~sqrt(n) — e.g. musicgen smoke on a 2x2x2 mesh:
-    # grad_norm 3.67 -> 5.03 at microbatches=2).  Characterized by
-    # bisection: eager and constraint-free pjit agree to 5 digits for any
-    # microbatch count; single-codebook models (yi, gemma) are unaffected;
-    # both the backbone-entry and scan-body constraint sites independently
-    # trigger it, with lax.scan and unrolled accumulation alike — i.e. the
-    # partitioner, not the accumulation math.  Correctness beats the
-    # constraint's perf intent, so drop the hook for exactly the affected
-    # configs (musicgen ships parallel.microbatches=8).
-    if cfg.num_codebooks > 1 and max(cfg.parallel.microbatches, 1) > 1:
-        act = None
-    else:
-        act = make_act_shard(cfg, mesh)
+    act = make_act_shard(cfg, mesh)
 
     def step(params, opt_state, batch):
         grads, metrics = _grad_and_metrics(cfg, params, batch, act_shard=act)
@@ -249,7 +216,7 @@ def make_train_step_shardmap(
 
     def jitted(batch_tree):
         bspec_in = jax.tree.map(lambda _: P(dp), batch_tree)
-        inner = _shard_map(
+        inner = jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(rep(pspec), rep(ospec), bspec_in),

@@ -1,13 +1,3 @@
-import os
-
-# 8 virtual CPU devices for the shard_map / pjit distribution tests.
-# (The 512-device override is dryrun.py-only, per the launch design.)
-# XLA_FLAGS must be set before jax initializes its backends; the pinned JAX
-# does not recognize the jax_num_cpu_devices config option.
-_FLAG = "--xla_force_host_platform_device_count=8"
-if _FLAG not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 try:
     import jax
 except ImportError:
@@ -17,10 +7,9 @@ except ImportError:
     jax = None
 
 if jax is not None:
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass  # older JAX: XLA_FLAGS above already forces 8 host devices
+    # 8 virtual CPU devices for the shard_map / pjit distribution tests.
+    # (The 512-device override is dryrun.py-only, per the launch design.)
+    jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest
 

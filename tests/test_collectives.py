@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # pinned 0.4.x spells it jax.experimental.shard_map
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives as C
@@ -20,7 +17,7 @@ pytestmark = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), ("pod", "lane"))
+    return make_test_mesh((2, 4), ("pod", "lane"))
 
 
 def _sm(mesh, f):

@@ -5,13 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # pinned 0.4.x spells it jax.experimental.shard_map
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives as C
+from repro.launch.mesh import make_test_mesh
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices")
 
@@ -19,11 +17,7 @@ SHAPES = [(2, 4), (4, 2), (8, 1), (1, 8)]
 
 
 def _mesh(shape):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, ("pod", "lane"))
-    return jax.make_mesh(shape, ("pod", "lane"),
-                         axis_types=(axis_type.Auto,) * 2)
+    return make_test_mesh(shape, ("pod", "lane"))
 
 
 @pytest.mark.parametrize("shape", SHAPES)

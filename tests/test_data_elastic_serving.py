@@ -3,10 +3,7 @@
 import jax
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis; deterministic sampling stub
-    from _hypstub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config
 from repro.models import lm

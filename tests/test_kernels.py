@@ -46,6 +46,7 @@ def test_flash_attention_noncausal():
     (2, 64, 32, 8, 16, 16),
     (1, 128, 64, 16, 32, 32),
     (3, 96, 16, 4, 16, 16),
+    (1, 32, 512, 16, 8, 512),  # the default tiles
 ])
 def test_mamba_scan(B, S, di, N, chunk, bd):
     a = jnp.asarray(RNG.rand(B, S, di, N) * 0.9, jnp.float32)
@@ -59,6 +60,7 @@ def test_mamba_scan(B, S, di, N, chunk, bd):
 
 @pytest.mark.parametrize("T,d,dt", [
     (64, 128, jnp.float32), (100, 96, jnp.bfloat16), (256, 512, jnp.float32),
+    (360, 3840, jnp.float32), (360, 3840, jnp.bfloat16),
 ])
 def test_rmsnorm(T, d, dt):
     x = jnp.asarray(RNG.randn(T, d), dt)
@@ -69,7 +71,10 @@ def test_rmsnorm(T, d, dt):
     assert err < (2e-2 if dt == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("No,Ni,blk,d", [(3, 4, 8, 16), (2, 2, 4, 4), (8, 1, 2, 32)])
+@pytest.mark.parametrize("No,Ni,blk,d", [
+    (3, 4, 8, 16), (2, 2, 4, 4), (8, 1, 2, 32),
+    (1, 2, 2048, 512),  # 4 MiB blocks: tiled in two 1024-row steps
+])
 def test_a2a_pack(No, Ni, blk, d):
     x = jnp.asarray(RNG.randn(No, Ni, blk, d), jnp.float32)
     np.testing.assert_allclose(ops.a2a_pack(x), ref.a2a_pack_ref(x))

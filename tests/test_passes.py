@@ -8,10 +8,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis; deterministic sampling stub
-    from _hypstub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import schedule as S
 from repro.core import schedule_ir as IR
@@ -230,7 +227,7 @@ def test_unknown_optimize_mode():
 
 
 # ---------------------------------------------------------------------------
-# property-style invariants (hypothesis or the deterministic stub)
+# property-style invariants (hypothesis)
 # ---------------------------------------------------------------------------
 
 ALG_IDX = st.integers(min_value=0, max_value=len(ALL_ALGS) - 1)

@@ -4,10 +4,7 @@ import math
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis; deterministic sampling stub
-    from _hypstub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import schedule as S
 from repro.core.topology import Topology, log_radix
