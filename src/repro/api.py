@@ -49,6 +49,7 @@ from repro.core import selector as _selector
 from repro.core.faults import FaultSpec
 from repro.core.schedule_ir import compiled_schedule
 from repro.core.selector import Choice, Decision
+from repro.obs.trace import TRACER
 
 __all__ = ["PlanRequest", "Plan", "plan", "plan_batch", "explain"]
 
@@ -112,7 +113,13 @@ class Plan:
         """Materialize the runnable compiled schedule for this plan on the
         request's (real, un-proxied) topology — the ``PlanRequest``
         overload of :func:`repro.core.schedule_ir.compiled_schedule`."""
-        return compiled_schedule(self.request, self.algorithm)
+        sp = TRACER.start("plan.schedule", algorithm=self.algorithm) \
+            if TRACER else None
+        try:
+            return compiled_schedule(self.request, self.algorithm)
+        finally:
+            if sp:
+                TRACER.finish(sp)
 
     def as_dict(self) -> dict:
         return {
@@ -149,22 +156,31 @@ def plan_batch(requests) -> list[Plan]:
     per mesh, all payloads priced in one stacked simulator pass.
     Faulted, deadline-bounded, or ``optimize=False`` requests take the
     per-query ladder — those modes are racing *policies*, not prices, and
-    never batch."""
+    never batch.
+
+    The call is one ``plan`` span (attribute ``requests``): the selector's
+    ``select.batch`` / ``select`` spans and the ``compile`` spans under
+    them name its ``sid`` as their ancestor."""
     requests = list(requests)
-    results: list[Plan | None] = [None] * len(requests)
-    fast_idx: list[int] = []
-    fast_q: list[tuple] = []
-    for i, req in enumerate(requests):
-        if req.is_healthy and req.deadline_s is None and req.optimize:
-            fast_idx.append(i)
-            fast_q.append((req.op, req.payload_elems, req.num_nodes,
-                           req.procs_per_node, req.k_lanes))
-        else:
-            results[i] = plan(req)
-    if fast_q:
-        for i, choice in zip(fast_idx, _selector.select_batch(fast_q)):
-            results[i] = _wrap(requests[i], choice)
-    return results
+    sp = TRACER.start("plan", requests=len(requests)) if TRACER else None
+    try:
+        results: list[Plan | None] = [None] * len(requests)
+        fast_idx: list[int] = []
+        fast_q: list[tuple] = []
+        for i, req in enumerate(requests):
+            if req.is_healthy and req.deadline_s is None and req.optimize:
+                fast_idx.append(i)
+                fast_q.append((req.op, req.payload_elems, req.num_nodes,
+                               req.procs_per_node, req.k_lanes))
+            else:
+                results[i] = plan(req)
+        if fast_q:
+            for i, choice in zip(fast_idx, _selector.select_batch(fast_q)):
+                results[i] = _wrap(requests[i], choice)
+        return results
+    finally:
+        if sp:
+            TRACER.finish(sp)
 
 
 def explain(request: PlanRequest) -> Decision:

@@ -58,7 +58,6 @@ import math
 import numpy as np
 
 from repro.core.topology import Machine
-from repro.obs import metrics as obs_metrics
 
 __all__ = [
     "Diagnostic",
@@ -529,9 +528,6 @@ def analyze_schedule(
         op=cs.op, algorithm=cs.algorithm, p=int(cs.p), k=int(cs.k),
         rounds=cs.num_rounds, msgs=cs.num_msgs, diagnostics=tuple(out),
     )
-    obs_metrics.counter("analyze.runs").inc()
-    if not report.ok:
-        obs_metrics.counter("analyze.failures").inc()
     return report
 
 

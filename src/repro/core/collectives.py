@@ -10,6 +10,12 @@ collectives:
     cross-pod broadcast  = [payload lane-sharded on root pod] -> psum(pod) -> all_gather(intra)
     cross-pod alltoall   = all_to_all(intra, regroup) -> all_to_all(pod)
 
+Each function opens a ``jax.named_scope`` of its own name, and each phase
+a scope inside it (``reduce_scatter`` / ``cross_pod`` / ``all_gather``,
+``intra`` / ``cross_pod``, ``round<r>``), so every device op of a phase
+carries ``<function>/<phase>`` in its HLO ``op_name``.  Scopes are trace-time
+metadata only: the compiled program is the same without them.
+
 Every function here must be called INSIDE ``jax.shard_map``
 (they use named-axis collectives), mirroring how ``jax.lax.psum`` et al. are
 used.  The k-ported tree algorithms are also provided, compiled from the
@@ -73,16 +79,21 @@ def hierarchical_psum(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
     Mathematically identical to ``psum(x, (outer, inner))``; the win is that
     the cross-pod traffic per chip drops from ``2*C`` to ``2*C/n``.
     """
-    n = axis_size(inner_axis)
-    shape = x.shape
-    flat = x.reshape(-1)
-    flat, pad = _pad_to_multiple(flat, n)
-    part = jax.lax.psum_scatter(flat, inner_axis, scatter_dimension=0, tiled=True)
-    part = jax.lax.psum(part, outer_axis)
-    full = jax.lax.all_gather(part, inner_axis, axis=0, tiled=True)
-    if pad:
-        full = full[: flat.shape[0] - pad]
-    return full.reshape(shape)
+    with jax.named_scope("hierarchical_psum"):
+        n = axis_size(inner_axis)
+        shape = x.shape
+        flat = x.reshape(-1)
+        flat, pad = _pad_to_multiple(flat, n)
+        with jax.named_scope("reduce_scatter"):
+            part = jax.lax.psum_scatter(flat, inner_axis, scatter_dimension=0,
+                                        tiled=True)
+        with jax.named_scope("cross_pod"):
+            part = jax.lax.psum(part, outer_axis)
+        with jax.named_scope("all_gather"):
+            full = jax.lax.all_gather(part, inner_axis, axis=0, tiled=True)
+        if pad:
+            full = full[: flat.shape[0] - pad]
+        return full.reshape(shape)
 
 
 # The paper's name for the family:
@@ -101,10 +112,13 @@ def fulllane_broadcast(x: jax.Array, outer_axis, inner_axis, *, root: int = 0) -
     Returns the *full* payload (all inner shards concatenated on axis 0) on
     every device.
     """
-    pod = jax.lax.axis_index(outer_axis)
-    masked = jnp.where(pod == root, x, jnp.zeros_like(x))
-    seeded = jax.lax.psum(masked, outer_axis)  # chunk broadcast across pods
-    return jax.lax.all_gather(seeded, inner_axis, axis=0, tiled=True)
+    with jax.named_scope("fulllane_broadcast"):
+        pod = jax.lax.axis_index(outer_axis)
+        masked = jnp.where(pod == root, x, jnp.zeros_like(x))
+        with jax.named_scope("cross_pod"):
+            seeded = jax.lax.psum(masked, outer_axis)  # chunk across pods
+        with jax.named_scope("all_gather"):
+            return jax.lax.all_gather(seeded, inner_axis, axis=0, tiled=True)
 
 
 def fulllane_all_to_all(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
@@ -128,18 +142,27 @@ def fulllane_all_to_all(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
         raise ValueError(f"leading dim {x.shape[0]} != mesh size {P}")
     blk = x.shape[1:]
 
-    # [No, Ni, *blk], indexed by (dest_outer, dest_inner).
-    y = x.reshape((No, Ni) + blk)
-    # Phase A (on-node): exchange over inner so that device (v, l) holds the
-    # blocks of all (v, j) destined to inner rank l: split dest_inner, concat
-    # a new source_inner dimension.
-    y = jax.lax.all_to_all(y, inner_axis, split_axis=1, concat_axis=1, tiled=False)
-    # y: [No, Ni_src, *blk] — y[o, j] = block from (v, j) destined to (o, l).
-    # Phase B (cross-pod): deliver node-combined blocks; split dest_outer,
-    # concat source_outer.
-    y = jax.lax.all_to_all(y, outer_axis, split_axis=0, concat_axis=0, tiled=False)
-    # y: [No_src, Ni_src, *blk] — y[w, j] = block from (w, j) destined (v, l).
-    return y.reshape((P,) + blk)
+    # The reshapes stay outside the phase scopes, so the regrouping is the
+    # executor's and not a phase's (the compiler may still give a layout
+    # copy the op_name of the collective it follows).
+    with jax.named_scope("fulllane_all_to_all"):
+        # [No, Ni, *blk], indexed by (dest_outer, dest_inner).
+        y = x.reshape((No, Ni) + blk)
+        # Phase A (on-node): exchange over inner so that device (v, l) holds
+        # the blocks of all (v, j) destined to inner rank l: split
+        # dest_inner, concat a new source_inner dimension.
+        with jax.named_scope("intra"):
+            y = jax.lax.all_to_all(y, inner_axis, split_axis=1, concat_axis=1,
+                                   tiled=False)
+        # y: [No, Ni_src, *blk] — y[o, j] = block from (v, j) destined to
+        # (o, l).  Phase B (cross-pod): deliver node-combined blocks; split
+        # dest_outer, concat source_outer.
+        with jax.named_scope("cross_pod"):
+            y = jax.lax.all_to_all(y, outer_axis, split_axis=0, concat_axis=0,
+                                   tiled=False)
+        # y: [No_src, Ni_src, *blk] — y[w, j] = block from (w, j) destined
+        # (v, l).
+        return y.reshape((P,) + blk)
 
 
 # ---------------------------------------------------------------------------
@@ -157,21 +180,12 @@ def _axis_linear_index(axis_names: Sequence[str]):
     return idx
 
 
-def kported_broadcast_ppermute(
-    x: jax.Array, axis_names, *, k: int, root: int = 0
-) -> jax.Array:
-    """The paper's §2.1 radix-(k+1) divide & conquer broadcast, executed as
-    ``ceil(log_{k+1} P)`` rounds of (up to k sequential) ``ppermute``s.
-
-    On a machine without true k-ported chips the k sends of a round
-    serialize — exactly the effect the paper measures; the dry-run uses this
-    to compare collective schedules, and it is the faithful baseline.
-    """
-    P = axis_size(axis_names)
-    schedule = sched.kported_broadcast(P, k, c=1, root=root)
-    me = _axis_linear_index(axis_names)
+def _run_rounds(x: jax.Array, schedule, axis_names, me) -> jax.Array:
+    """Run ``schedule``'s rounds as ``ppermute`` waves, round ``r`` inside
+    a ``round<r>`` scope: device ``me`` takes a wave's payload where it is
+    a destination and keeps its buffer elsewhere."""
     cur = x
-    for rnd in schedule.rounds:
+    for r, rnd in enumerate(schedule.rounds):
         # Each round has at most k messages per source; ppermute supports one
         # message per source, so split the round into <= k waves.
         waves: list[list[tuple[int, int]]] = []
@@ -182,12 +196,30 @@ def kported_broadcast_ppermute(
             while len(waves) <= w:
                 waves.append([])
             waves[w].append((m.src, m.dst))
-        for wave in waves:
-            recv = jax.lax.ppermute(cur, axis_names, perm=wave)
-            dsts = jnp.asarray([d for _, d in wave])
-            is_dst = jnp.any(me == dsts)
-            cur = jnp.where(is_dst, recv, cur)
+        with jax.named_scope(f"round{r}"):
+            for wave in waves:
+                recv = jax.lax.ppermute(cur, axis_names, perm=wave)
+                dsts = jnp.asarray([d for _, d in wave])
+                is_dst = jnp.any(me == dsts)
+                cur = jnp.where(is_dst, recv, cur)
     return cur
+
+
+def kported_broadcast_ppermute(
+    x: jax.Array, axis_names, *, k: int, root: int = 0
+) -> jax.Array:
+    """The paper's §2.1 radix-(k+1) divide & conquer broadcast, executed as
+    ``ceil(log_{k+1} P)`` rounds of (up to k sequential) ``ppermute``s.
+
+    On a machine without true k-ported chips the k sends of a round
+    serialize — exactly the effect the paper measures; the dry-run uses this
+    to compare collective schedules, and it is the faithful baseline.
+    """
+    with jax.named_scope("kported_broadcast_ppermute"):
+        P = axis_size(axis_names)
+        schedule = sched.kported_broadcast(P, k, c=1, root=root)
+        return _run_rounds(x, schedule, axis_names,
+                           _axis_linear_index(axis_names))
 
 
 def kported_scatter_ppermute(
@@ -205,24 +237,11 @@ def kported_scatter_ppermute(
     P = axis_size(axis_names)
     if x.shape[0] != P:
         raise ValueError(f"leading dim {x.shape[0]} != axis size {P}")
-    schedule = sched.kported_scatter(P, k, c=1, root=root)
-    me = _axis_linear_index(axis_names)
-    cur = x
-    for rnd in schedule.rounds:
-        waves: list[list[tuple[int, int]]] = []
-        per_src: dict[int, int] = {}
-        for m in rnd.msgs:
-            w = per_src.get(m.src, 0)
-            per_src[m.src] = w + 1
-            while len(waves) <= w:
-                waves.append([])
-            waves[w].append((m.src, m.dst))
-        for wave in waves:
-            recv = jax.lax.ppermute(cur, axis_names, perm=wave)
-            dsts = jnp.asarray([d for _, d in wave])
-            is_dst = jnp.any(me == dsts)
-            cur = jnp.where(is_dst, recv, cur)
-    return jnp.take(cur, me, axis=0)
+    with jax.named_scope("kported_scatter_ppermute"):
+        schedule = sched.kported_scatter(P, k, c=1, root=root)
+        me = _axis_linear_index(axis_names)
+        cur = _run_rounds(x, schedule, axis_names, me)
+        return jnp.take(cur, me, axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -476,8 +476,26 @@ def select_batch(queries) -> list[Choice]:
     scatter) chunk payloads with remainders — not linear in ``c`` — so
     they fall back to the cached per-query race, which amortizes across
     the batch anyway.
+
+    The grouping, the unit-payload compiles and the stacked pricing run
+    inside one ``select.batch`` span (attributes ``queries``, ``groups``).
     """
     queries = list(queries)
+    sp = TRACER.start("select.batch", queries=len(queries)) if TRACER \
+        else None
+    try:
+        results, groups = _select_batch(queries)
+    except BaseException:
+        if sp:
+            TRACER.finish(sp, outcome="error")
+        raise
+    if sp:
+        TRACER.finish(sp, groups=groups)
+    return results
+
+
+def _select_batch(queries: list) -> tuple[list[Choice], int]:
+    """:func:`select_batch`'s body; also returns the number of groups."""
     results: list[Choice | None] = [None] * len(queries)
     groups: dict[tuple, list[tuple[int, int]]] = {}
     for i, q in enumerate(queries):
@@ -525,7 +543,7 @@ def select_batch(queries) -> list[Choice]:
             best, est = ranked[0]
             results[i] = Choice(op=op, algorithm=best, est_us=est,
                                 candidates=ranked)
-    return results
+    return results, len(groups)
 
 
 def selector_cache_reset() -> None:

@@ -18,6 +18,11 @@ fusions/calls/conditionals inherit), and accumulates:
                          upper-bound-flavored traffic model).
 
 This is the §Roofline extraction layer; values feed benchmarks/roofline.py.
+
+:func:`collective_scopes` lists each collective with the ``jax.named_scope``
+path it was traced under (its ``op_name`` metadata), which is how a phase of
+``core/collectives.py`` or a stage of the train step is found in a compiled
+program.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ import dataclasses
 import re
 from collections import defaultdict
 
-__all__ = ["analyze_module", "HloCost"]
+__all__ = ["analyze_module", "collective_scopes", "HloCost"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -145,7 +150,8 @@ def _parse_computations(text: str) -> tuple[dict[str, _Computation], str]:
             result_type = rest[:end]
             after = rest[end:].lstrip()
         else:
-            sm = re.match(r"([\w\[\]{},]+)\s+", rest)
+            # TPU layouts carry tiling and memory space: {0:T(1024)S(1)}
+            sm = re.match(r"([\w\[\]{},:()]+)\s+", rest)
             if not sm:
                 continue
             result_type = sm.group(1)
@@ -160,6 +166,34 @@ def _parse_computations(text: str) -> tuple[dict[str, _Computation], str]:
         operands = _OPERAND_RE.findall(paren)
         cur.instrs.append(_Instr(name, result_type, opcode, operands, rest))
     return comps, entry
+
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def collective_scopes(text: str) -> list[tuple[str, str]]:
+    """``(kind, op_name)`` for every collective of a compiled HLO module,
+    an async pair counted once (at its ``-start``).  A collective that
+    carries no ``op_name`` takes its reducer's (``to_apply``): the TPU
+    compiler rewrites a reduce-scatter into an all-reduce and a slice and
+    drops the new all-reduce's metadata, but not its reducer's.  ``""``
+    where neither has one."""
+    comps, _ = _parse_computations(text)
+    out = []
+    for comp in comps.values():
+        for ins in comp.instrs:
+            kind = ins.opcode.removesuffix("-start")
+            if kind not in _COLL_KINDS:
+                continue
+            m = _OP_NAME_RE.search(ins.raw)
+            if m is None:
+                cm = _CALLS_RE.search(ins.raw)
+                reducer = comps.get(cm.group(1)) if cm else None
+                root = reducer.instrs[-1].raw if reducer and reducer.instrs \
+                    else ""
+                m = _OP_NAME_RE.search(root)
+            out.append((kind, m.group(1) if m else ""))
+    return out
 
 
 def _matching_paren(s: str) -> int:

@@ -129,10 +129,12 @@ def main(argv=None) -> dict:
         if step >= args.steps:
             break
         t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state,
-                                             _place(batch, bspec, mesh))
-        loss = float(metrics["loss"])  # sync point
-        gnorm = float(metrics["grad_norm"])
+        # one step of XProf's step view: dispatch to the loss read
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            params, opt_state, metrics = step_fn(params, opt_state,
+                                                 _place(batch, bspec, mesh))
+            loss = float(metrics["loss"])  # sync point
+            gnorm = float(metrics["grad_norm"])
         dt = time.time() - t0
         action = monitor.observe(dt)
         if action != "ok":

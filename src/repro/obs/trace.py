@@ -35,6 +35,15 @@ nesting level.  Chrome's flame view reconstructs nesting from ts/dur
 alone; ``parent``/``sid``/``depth`` make the JSONL export queryable
 without interval arithmetic.
 
+**On the profiler's clock.**  When :meth:`Tracer.enable` runs in a process
+that has already imported ``jax``, every span also enters a
+``jax.profiler.TraceAnnotation`` of the span's name (attributes stay in
+the ring), and ``finish`` exits it.  While a ``jax.profiler`` trace is
+being taken each span then lies on the host plane of the ``.xplane.pb``,
+on the device trace's clock; with no profiler running an annotation is a
+no-op.  Instant events are not mirrored.  This module never imports jax
+itself: a process without it records to the ring alone.
+
 Enable programmatically (``trace.enable()``) or via ``REPRO_TRACE=1`` in
 the environment.  Exporters: :meth:`Tracer.export_jsonl` (one record per
 line) and :meth:`Tracer.export_chrome` (a ``{"traceEvents": [...]}``
@@ -45,6 +54,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -65,6 +75,13 @@ __all__ = [
 
 def _now_us() -> int:
     return time.perf_counter_ns() // 1000
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is already imported, else
+    None; never imports jax."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
 
 
 def json_default(obj: Any) -> Any:
@@ -94,7 +111,8 @@ class Span:
     writes a plain dict into the ring buffer.
     """
 
-    __slots__ = ("name", "ts", "sid", "parent", "depth", "attrs")
+    __slots__ = ("name", "ts", "sid", "parent", "depth", "attrs",
+                 "annotation")
 
     def __init__(self, name: str, ts: int, sid: int, parent: int | None,
                  depth: int, attrs: dict[str, Any]):
@@ -104,6 +122,7 @@ class Span:
         self.parent = parent
         self.depth = depth
         self.attrs = attrs
+        self.annotation = None  # the open profiler annotation, if mirrored
 
 
 class _NullCM:
@@ -137,6 +156,8 @@ class Tracer:
         self._next_sid = 0
         self._tls = threading.local()
         self._pid = os.getpid()
+        # jax.profiler.TraceAnnotation while spans are mirrored, else None
+        self._annotation = None
 
     # -- enable/disable ----------------------------------------------------
 
@@ -145,7 +166,8 @@ class Tracer:
 
     def enable(self, capacity: int | None = None) -> None:
         """Turn the tracer on.  ``capacity`` (if given) resizes and clears
-        the ring buffer; otherwise existing records are kept."""
+        the ring buffer; otherwise existing records are kept.  Spans are
+        mirrored into the profiler if jax is imported by now."""
         with self._lock:
             if capacity is not None and capacity != self._cap:
                 if capacity < 1:
@@ -154,6 +176,7 @@ class Tracer:
                 self._ring = [None] * capacity
                 self._idx = 0
                 self._total = 0
+            self._annotation = _profiler_annotation()
             self._enabled = True
 
     def disable(self) -> None:
@@ -183,13 +206,21 @@ class Tracer:
             sid = self._next_sid
             self._next_sid += 1
         sp = Span(name, _now_us(), sid, parent, len(st), attrs)
+        if self._annotation is not None:
+            sp.annotation = self._annotation(name)
+            sp.annotation.__enter__()
         st.append(sp)
         return sp
 
     def finish(self, sp: Span, **attrs: Any) -> None:
         """Close ``sp`` and record it.  Extra ``attrs`` merge over the
-        opening ones.  Tolerates out-of-order finishes (pops through)."""
+        opening ones.  Tolerates out-of-order finishes (pops through); a
+        mirrored annotation records its own end, so order does not matter
+        to the profiler either."""
         end = _now_us()
+        if sp.annotation is not None:
+            sp.annotation.__exit__(None, None, None)
+            sp.annotation = None
         st = self._stack()
         while st:
             top = st.pop()

@@ -210,7 +210,6 @@ class ServeEngine:
                             faults=faults),
         ]
         plans = api.plan_batch(reqs)
-        obs_metrics.counter("engine.collective_plans").inc(len(plans))
         if TRACER:
             TRACER.event("engine.plan_collectives",
                          mesh=(num_nodes, procs_per_node, k_lanes),

@@ -78,7 +78,6 @@ class DecodePlanner:
         # pin at construction: the full healthy race, cached thereafter
         self._plans = {pl.op: pl
                        for pl in self._plan_batch(self._requests(None, None))}
-        obs_metrics.counter("engine.plans_pinned").inc(len(self._plans))
         TRACER.event("engine.plans_pinned", mesh=self.mesh,
                      algs={op: pl.algorithm
                            for op, pl in self._plans.items()})

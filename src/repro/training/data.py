@@ -7,7 +7,10 @@ pure function of the step counter, so resuming from checkpoint step k
 reproduces the exact batch sequence, and a re-meshed job keeps data
 consistency by construction).
 
-A background prefetch thread keeps ``depth`` batches ready.
+A background prefetch thread keeps ``depth`` batches ready.  With the
+tracer on, the worker records a ``data.batch`` span round the production
+of each batch and the consumer a ``data.wait`` span round each wait for
+one.
 """
 
 from __future__ import annotations
@@ -19,6 +22,7 @@ from typing import Iterator
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.obs.trace import TRACER
 
 __all__ = ["SyntheticLM", "Prefetcher", "make_batch"]
 
@@ -65,6 +69,25 @@ class SyntheticLM:
         return out
 
 
+_END = object()
+
+
+def _produced(it):
+    """The items of ``it``, the production of each inside a ``data.batch``
+    span."""
+    it = iter(it)
+    while True:
+        sp = TRACER.start("data.batch") if TRACER else None
+        try:
+            item = next(it, _END)
+        finally:
+            if sp:
+                TRACER.finish(sp)
+        if item is _END:
+            return
+        yield item
+
+
 class Prefetcher:
     """Background-thread prefetch of an iterator (depth-bounded)."""
 
@@ -75,7 +98,7 @@ class Prefetcher:
 
         def work():
             try:
-                for item in it:
+                for item in _produced(it):
                     self._q.put(item)
             except Exception as e:
                 self._err = e
@@ -89,7 +112,12 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        item = self._q.get()
+        sp = TRACER.start("data.wait") if TRACER else None
+        try:
+            item = self._q.get()
+        finally:
+            if sp:
+                TRACER.finish(sp)
         if item is self._done:
             if self._err:
                 raise self._err

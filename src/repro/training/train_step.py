@@ -24,6 +24,12 @@ collective families.
 Both support gradient accumulation (``parallel.microbatches``) via
 ``lax.scan`` with fp32 accumulators; remat comes from the model's
 period-scan checkpoint policy.
+
+Each step's stages run under ``jax.named_scope``s: ``grad`` (forward,
+backward, the micro-batch scan), ``sync`` (the shard_map path's gradient
+and metric reductions) and ``optimizer`` (``adamw_update``), so a device
+op's HLO ``op_name`` names its stage; ops the partitioner inserts at the
+``shard_map`` boundary fall outside all three.
 """
 
 from __future__ import annotations
@@ -154,8 +160,12 @@ def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
     act = make_act_shard(cfg, mesh)
 
     def step(params, opt_state, batch):
-        grads, metrics = _grad_and_metrics(cfg, params, batch, act_shard=act)
-        params, opt_state, info = adamw_update(grads, opt_state, params, opt_cfg)
+        with jax.named_scope("grad"):
+            grads, metrics = _grad_and_metrics(cfg, params, batch,
+                                               act_shard=act)
+        with jax.named_scope("optimizer"):
+            params, opt_state, info = adamw_update(grads, opt_state, params,
+                                                   opt_cfg)
         return params, opt_state, {**metrics, **info}
 
     def jitted(batch_tree):
@@ -197,10 +207,15 @@ def make_train_step_shardmap(
         return jax.lax.psum(g, dp)
 
     def step(params, opt_state, batch):
-        grads, metrics = _grad_and_metrics(cfg, params, batch)
-        grads = jax.tree.map(lambda g: sync(g) / ndp, grads)
-        metrics = jax.tree.map(lambda v: jax.lax.psum(v, dp) / ndp, metrics)
-        params, opt_state, info = adamw_update(grads, opt_state, params, opt_cfg)
+        with jax.named_scope("grad"):
+            grads, metrics = _grad_and_metrics(cfg, params, batch)
+        with jax.named_scope("sync"):
+            grads = jax.tree.map(lambda g: sync(g) / ndp, grads)
+            metrics = jax.tree.map(lambda v: jax.lax.psum(v, dp) / ndp,
+                                   metrics)
+        with jax.named_scope("optimizer"):
+            params, opt_state, info = adamw_update(grads, opt_state, params,
+                                                   opt_cfg)
         return params, opt_state, {**metrics, **info}
 
     pspec = param_pspecs(cfg, mesh)  # model-axis sharding via outer jit

@@ -21,6 +21,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import collectives as C
+from repro.launch.hloanalysis import collective_scopes
 from repro.kernels.a2a_pack import a2a_pack_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
@@ -115,3 +116,29 @@ def test_fulllane_all_to_all_compiles(topo):
     assert "all-to-all" in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == 4 * MIB
+
+
+@pytest.mark.parametrize("fn,phases", [
+    ("fulllane_all_to_all", ("intra", "cross_pod")),
+    ("hierarchical_psum", ("reduce_scatter", "cross_pod", "all_gather")),
+])
+def test_collective_phases_scoped(topo, fn, phases):
+    """Each phase's named scope survives the v5e compiler on some
+    collective of the phase, so a device trace of the chip can split the
+    function's time by phase.  The v5e compiles the reduce-scatter phase to
+    an all-reduce that keeps the scope only on its reducer, which
+    ``collective_scopes`` reads in its place."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("pod", "lane"),
+                axis_types=(AxisType.Auto,) * 2)
+    p = mesh.size
+    d = 1024
+    blk = 4 * MIB // (p * d * 4)
+    spec = P(("pod", "lane"))
+    x = jax.ShapeDtypeStruct((p * p, blk, d), jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    f = jax.shard_map(lambda v: getattr(C, fn)(v, "pod", "lane"),
+                      mesh=mesh, in_specs=spec, out_specs=spec)
+    scopes = [name for _, name in
+              collective_scopes(jax.jit(f).lower(x).compile().as_text())]
+    for phase in phases:
+        assert any(f"{fn}/{phase}/" in s for s in scopes), (phase, scopes)
