@@ -7,6 +7,7 @@ problem-splitting* family becomes the hierarchical decomposition of cross-pod
 collectives:
 
     cross-pod allreduce  = reduce_scatter(intra) -> allreduce(pod) -> all_gather(intra)
+    cross-pod reduce-scatter = reduce_scatter(intra, along a dim) -> allreduce(pod)
     cross-pod broadcast  = [payload lane-sharded on root pod] -> psum(pod) -> all_gather(intra)
     cross-pod alltoall   = all_to_all(intra, regroup) -> all_to_all(pod)
 
@@ -39,6 +40,7 @@ from repro.core.topology import Topology
 __all__ = [
     "axis_size",
     "hierarchical_psum",
+    "hierarchical_reduce_scatter",
     "fulllane_psum",
     "fulllane_broadcast",
     "fulllane_all_to_all",
@@ -78,7 +80,14 @@ def hierarchical_psum(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
 
     Mathematically identical to ``psum(x, (outer, inner))``; the win is that
     the cross-pod traffic per chip drops from ``2*C`` to ``2*C/n``.
+
+    With ``inner_axis`` None there are no on-node phases: the cross-pod
+    all-reduce alone, under the caller's scope, as
+    ``hierarchical_reduce_scatter`` runs it on its tile.
     """
+    if inner_axis is None:
+        with jax.named_scope("cross_pod"):
+            return jax.lax.psum(x, outer_axis)
     with jax.named_scope("hierarchical_psum"):
         n = axis_size(inner_axis)
         shape = x.shape
@@ -94,6 +103,27 @@ def hierarchical_psum(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
         if pad:
             full = full[: flat.shape[0] - pad]
         return full.reshape(shape)
+
+
+def hierarchical_reduce_scatter(x: jax.Array, outer_axis, inner_axis,
+                                dim: int = 0) -> jax.Array:
+    """Sum over (outer x inner), leaving each chip its ``inner`` tile of
+    ``x`` along ``dim``: the first two phases of ``hierarchical_psum``,
+    reduce-scatter over ``inner`` then all-reduce of the tile over
+    ``outer``, without the flatten.
+
+    Equal to this chip's ``inner`` tile along ``dim`` of
+    ``psum(x, (outer, inner))``, up to the order of the sums;
+    ``x.shape[dim]`` must divide by the ``inner`` size.  Scattering along
+    a dim of the leaf compiles to a true reduce-scatter on the v5e, where
+    ``hierarchical_psum``'s flat scatter compiles to a full-size
+    all-reduce and a slice.
+    """
+    with jax.named_scope("hierarchical_reduce_scatter"):
+        with jax.named_scope("reduce_scatter"):
+            part = jax.lax.psum_scatter(x, inner_axis, scatter_dimension=dim,
+                                        tiled=True)
+        return hierarchical_psum(part, outer_axis, None)
 
 
 # The paper's name for the family:

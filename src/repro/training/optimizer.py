@@ -5,18 +5,21 @@ configurable (bf16 moments for the >=200B configs keep the optimizer under
 the v5e HBM budget; see DESIGN.md §5).  Sharding of the moments is applied
 by the caller via ``partition_specs(..., fsdp=True)`` — the moments always
 use the FSDP rules even when the params do not (that *is* ZeRO-1: optimizer
-state sharded over the data axis, with XLA inserting the gather around the
-update)."""
+state sharded over the data axis).  The pjit path leaves the gathers
+around the update to XLA; the shard_map step updates each chip's shard of
+the leaves and gathers the new parameters."""
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["OptConfig", "init_opt_state", "adamw_update", "global_norm"]
+__all__ = ["OptConfig", "init_opt_state", "adamw_update", "clip_factor",
+           "global_norm", "sum_squares"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,11 +44,13 @@ def init_opt_state(params, cfg: OptConfig) -> dict:
     }
 
 
+def sum_squares(leaves) -> jax.Array:
+    """Sum of the squares of every element of ``leaves``, in f32."""
+    return sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves)
+
+
 def global_norm(tree) -> jax.Array:
-    leaves = jax.tree.leaves(tree)
-    return jnp.sqrt(
-        sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves)
-    )
+    return jnp.sqrt(sum_squares(jax.tree.leaves(tree)))
 
 
 def _schedule(cfg: OptConfig, step: jax.Array) -> jax.Array:
@@ -53,11 +58,19 @@ def _schedule(cfg: OptConfig, step: jax.Array) -> jax.Array:
     return cfg.learning_rate * warm
 
 
+def clip_factor(cfg: OptConfig, gnorm: jax.Array) -> jax.Array:
+    """What scales a gradient of global norm ``gnorm`` to a norm of at
+    most ``cfg.grad_clip``."""
+    if cfg.grad_clip == math.inf:
+        return 1.0  # clips nothing, so nothing needs the norm
+    return jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9))
+
+
 def adamw_update(grads, state, params, cfg: OptConfig):
     """Returns (new_params, new_state, info)."""
     step = state["step"] + 1
     gnorm = global_norm(grads)
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9))
+    clip = clip_factor(cfg, gnorm)
     lr = _schedule(cfg, state["step"])
     b1, b2 = cfg.beta1, cfg.beta2
     bc1 = 1.0 - b1 ** step.astype(jnp.float32)

@@ -19,6 +19,11 @@ collective families.
                           Requires a multi-pod mesh; on a single pod it
                           coincides with the flat form (documented).
 
+  The AdamW moments stay sharded over ``data`` (ZeRO-1) through the step:
+  each gradient leaf is reduced down to the moment tile this chip owns
+  (``hierarchical_reduce_scatter`` on ``fulllane``), AdamW updates that
+  tile, and the new parameters are all-gathered.
+
   The dry-run lowers both and diffs collective bytes (EXPERIMENTS.md §Perf).
 
 Both support gradient accumulation (``parallel.microbatches``) via
@@ -27,14 +32,16 @@ period-scan checkpoint policy.
 
 Each step's stages run under ``jax.named_scope``s: ``grad`` (forward,
 backward, the micro-batch scan), ``sync`` (the shard_map path's gradient
-and metric reductions) and ``optimizer`` (``adamw_update``), so a device
-op's HLO ``op_name`` names its stage; ops the partitioner inserts at the
-``shard_map`` boundary fall outside all three.
+and metric reductions, and its ``param_gather``) and ``optimizer``
+(``adamw_update``), so a device op's HLO ``op_name`` names its stage; ops
+the partitioner inserts at the ``shard_map`` boundary fall outside all
+three.
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +50,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.core import collectives as C
 from repro.models import lm
-from repro.models.params import partition_specs
-from repro.training.optimizer import OptConfig, adamw_update, init_opt_state
+from repro.models.params import ParamMeta, partition_specs
+from repro.obs.trace import TRACER
+from repro.training.optimizer import (OptConfig, adamw_update, clip_factor,
+                                      init_opt_state, sum_squares)
 
 
 __all__ = [
@@ -185,18 +194,54 @@ def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
 # ---------------------------------------------------------------------------
 
 
+def _shard_dim(spec: P, axis: str | None) -> int | None:
+    """The dim that ``spec`` shards over mesh axis ``axis``, or None."""
+    if axis is not None:
+        for d, a in enumerate(spec):
+            if a == axis:
+                return d
+    return None
+
+
 def make_train_step_shardmap(
     cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig, *, backend: str = "fulllane"
 ):
-    """Explicit DP with backend-switched gradient sync.  Params/opt are
+    """Explicit DP with backend-switched gradient sync.  Params are
     replicated over the DP axes (TP over ``model`` still applies via the
-    outer jit shardings); requires ``cfg.parallel.fsdp == False``."""
+    outer jit shardings); requires ``cfg.parallel.fsdp == False``.
+
+    ZeRO-1 inside the step: a leaf whose moment spec shards a dim over
+    ``data`` keeps its moments sharded through the ``shard_map``; its
+    gradient is reduced only down to this chip's tile of that dim
+    (``hierarchical_reduce_scatter`` on ``fulllane``, ``psum`` then a
+    slice on ``xla``) and clipped by the whole gradient's norm, AdamW
+    updates the tile of the parameter, and the new bf16 tiles are
+    all-gathered over ``data`` (scope ``sync/param_gather``).  Any other
+    leaf is synced, updated and kept whole.  The build records
+    ``train_step.zero1`` with the leaf count, the leaves on the sharded
+    path and their share of parameter bytes as an event of the tracer."""
     if cfg.parallel.fsdp:
         raise ValueError("shard_map path requires fsdp=False (replicated DP params)")
     dp = dp_axes(mesh)
     ndp = 1
     for a in dp:
         ndp *= mesh_axis_sizes(mesh)[a]
+    pspec = param_pspecs(cfg, mesh)  # model-axis sharding via outer jit
+    ospec = opt_pspecs(cfg, mesh)
+    is_spec = lambda x: isinstance(x, P)
+    # The step clips by the whole gradient's norm, which tiles do not give.
+    no_clip = dataclasses.replace(opt_cfg, grad_clip=math.inf)
+    # Per leaf, the dim its moments shard over ``data`` (None: whole leaf).
+    shard_axis = "data" if "data" in dp else None
+    dims = [_shard_dim(s, shard_axis)
+            for s in jax.tree.leaves(ospec["m"], is_leaf=is_spec)]
+    sizes = [math.prod(m.shape) for m in jax.tree.leaves(
+        lm.model_meta(cfg), is_leaf=lambda x: isinstance(x, ParamMeta))]
+    TRACER.event("train_step.zero1", leaves=len(dims),
+                 sharded_leaves=sum(d is not None for d in dims),
+                 sharded_bytes_share=sum(
+                     n for n, d in zip(sizes, dims) if d is not None)
+                 / sum(sizes))
 
     def sync(g):
         if backend == "fulllane" and len(dp) == 2:
@@ -206,27 +251,71 @@ def make_train_step_shardmap(
             return jax.lax.psum(g, dp)
         return jax.lax.psum(g, dp)
 
+    def local(x, d):
+        """This chip's tile of a replicated leaf along dim ``d``."""
+        n = x.shape[d] // jax.lax.axis_size(shard_axis)
+        return jax.lax.dynamic_slice_in_dim(
+            x, jax.lax.axis_index(shard_axis) * n, n, axis=d)
+
+    def sync_to_shard(g, d):
+        if d is None:
+            return sync(g)
+        if backend == "fulllane" and len(dp) == 2:
+            return C.hierarchical_reduce_scatter(g, dp[0], dp[1], d)
+        if backend == "fulllane":
+            return jax.lax.psum_scatter(g, shard_axis, scatter_dimension=d,
+                                        tiled=True)
+        return local(jax.lax.psum(g, dp), d)
+
     def step(params, opt_state, batch):
         with jax.named_scope("grad"):
             grads, metrics = _grad_and_metrics(cfg, params, batch)
+        flat_p, treedef = jax.tree.flatten(params)
         with jax.named_scope("sync"):
-            grads = jax.tree.map(lambda g: sync(g) / ndp, grads)
+            flat_g = [sync_to_shard(g, d) / ndp
+                      for g, d in zip(jax.tree.leaves(grads), dims)]
             metrics = jax.tree.map(lambda v: jax.lax.psum(v, dp) / ndp,
                                    metrics)
         with jax.named_scope("optimizer"):
-            params, opt_state, info = adamw_update(grads, opt_state, params,
-                                                   opt_cfg)
+            flat_p = [p if d is None else local(p, d)
+                      for p, d in zip(flat_p, dims)]
+            # The clip's norm is the whole gradient's: a tile's squares are
+            # summed over ``data`` with the other tiles, a whole leaf's
+            # counted once.  The tiles are clipped here, and AdamW updates
+            # them with no clip of its own.
+            parts = []
+            tiles = [g for g, d in zip(flat_g, dims) if d is not None]
+            if tiles:
+                parts.append(jax.lax.psum(sum_squares(tiles), shard_axis))
+            whole = [g for g, d in zip(flat_g, dims) if d is None]
+            if whole:
+                parts.append(sum_squares(whole))
+            gnorm = jnp.sqrt(sum(parts))
+            clip = clip_factor(opt_cfg, gnorm)
+            flat_g = [g * clip for g in flat_g]
+            params, opt_state, info = adamw_update(
+                jax.tree.unflatten(treedef, flat_g), opt_state,
+                jax.tree.unflatten(treedef, flat_p), no_clip)
+            info = {**info, "grad_norm": gnorm}
+        with jax.named_scope("sync"), jax.named_scope("param_gather"):
+            params = jax.tree.unflatten(treedef, [
+                p if d is None else jax.lax.all_gather(
+                    p, shard_axis, axis=d, tiled=True)
+                for p, d in zip(jax.tree.leaves(params), dims)])
         return params, opt_state, {**metrics, **info}
 
-    pspec = param_pspecs(cfg, mesh)  # model-axis sharding via outer jit
-    ospec = opt_pspecs(cfg, mesh)
     ns = lambda t: jax.tree.map(
-        lambda s: NamedSharding(mesh, s), t, is_leaf=lambda x: isinstance(x, P)
+        lambda s: NamedSharding(mesh, s), t, is_leaf=is_spec
     )
 
     rep = lambda tree: jax.tree.map(
-        lambda s: P(), tree, is_leaf=lambda x: isinstance(x, P)
+        lambda s: P(), tree, is_leaf=is_spec
     )
+    # Inside the shard_map the moments keep their ``data`` dim sharded.
+    mom = jax.tree.unflatten(
+        jax.tree.structure(ospec["m"], is_leaf=is_spec),
+        [P() if d is None else P(*[None] * d, shard_axis) for d in dims])
+    ospec_in = {"m": mom, "v": mom, "step": P()}
     metric_spec = {k: P() for k in ("loss", "nll", "aux", "grad_norm", "lr")}
 
     def jitted(batch_tree):
@@ -234,8 +323,8 @@ def make_train_step_shardmap(
         inner = jax.shard_map(
             step,
             mesh=mesh,
-            in_specs=(rep(pspec), rep(ospec), bspec_in),
-            out_specs=(rep(pspec), rep(ospec), metric_spec),
+            in_specs=(rep(pspec), ospec_in, bspec_in),
+            out_specs=(rep(pspec), ospec_in, metric_spec),
             axis_names=set(dp),
             check_vma=False,
         )

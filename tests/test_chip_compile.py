@@ -10,8 +10,10 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +22,9 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_smoke_config
 from repro.core import collectives as C
-from repro.launch.hloanalysis import collective_scopes
+from repro.launch.hloanalysis import _parse_computations, collective_scopes
 from repro.kernels.a2a_pack import a2a_pack_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
@@ -142,3 +145,114 @@ def test_collective_phases_scoped(topo, fn, phases):
               collective_scopes(jax.jit(f).lower(x).compile().as_text())]
     for phase in phases:
         assert any(f"{fn}/{phase}/" in s for s in scopes), (phase, scopes)
+
+
+_COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather")
+
+
+def _ops(text: str, kinds, entry_only: bool = True):
+    """``(opcode, array type, replica groups)`` of the instructions of kind
+    in ``kinds``, in program order: the entry computation's, or every
+    computation's."""
+    comps, entry = _parse_computations(text)
+    out = []
+    for name, comp in comps.items():
+        if entry_only and name != entry:
+            continue
+        for ins in comp.instrs:
+            kind = ins.opcode
+            if kind.removesuffix("-start") in _COLLECTIVES:
+                kind = kind.removesuffix("-start")
+            if kind in kinds:
+                groups = re.search(r"replica_groups=(\{[{}0-9,]*\})", ins.raw)
+                # an async start's type is a tuple: its first array is the
+                # operand, which has the result's dtype
+                out.append((kind, re.search(r"\w+\[[0-9,]*\]",
+                                            ins.result_type).group(0),
+                            groups.group(1) if groups else None))
+    return out
+
+
+@pytest.fixture(scope="module")
+def pod_data(topo):
+    return Mesh(np.asarray(topo.devices).reshape(2, 2), ("pod", "data"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+# One stacked MLP leaf of h2o-danube: [layers, d_model, d_ff] in f32.
+_LEAF = (2, 3840, 10240)
+_SYNC_KINDS = ("all-reduce", "reduce-scatter", "all-gather", "copy",
+               "dynamic-slice")
+
+
+def _sync_ops(mesh, fn, out_spec):
+    x = jax.ShapeDtypeStruct(_LEAF, jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    f = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=out_spec,
+                      check_vma=False)
+    return _ops(jax.jit(f).lower(x).compile().as_text(), _SYNC_KINDS)
+
+
+def test_hierarchical_reduce_scatter_is_a_reduce_scatter(pod_data):
+    """Scattered along the leaf's ``d_model`` dim the reduce-scatter phase
+    compiles to one reduce-scatter of half the leaf, with no relayout copy
+    and no all-reduce over ``data``; the cross-pod all-reduce of the half
+    follows."""
+    ops = _sync_ops(pod_data,
+                    lambda v: C.hierarchical_reduce_scatter(v, "pod", "data",
+                                                            1),
+                    P(None, "data"))
+    data, pod = "{{0,1},{2,3}}", "{{0,2},{1,3}}"
+    assert ops == [("reduce-scatter", "f32[2,1920,10240]", data),
+                   ("all-reduce", "f32[2,1920,10240]", pod)], ops
+
+
+def test_hierarchical_psum_lowering_unchanged(pod_data):
+    """``hierarchical_psum`` (the a2a cell's and API users' path) keeps its
+    flat scatter's phases: a full-size all-reduce over ``data`` (the
+    compiler's form of the flat reduce-scatter), the cross-pod all-reduce
+    of the half, the gather over ``data``."""
+    x = jax.ShapeDtypeStruct(_LEAF, jnp.float32,
+                             sharding=NamedSharding(pod_data, P()))
+    f = jax.shard_map(lambda v: C.hierarchical_psum(v, "pod", "data"),
+                      mesh=pod_data, in_specs=P(), out_specs=P(),
+                      check_vma=False)
+    ops = _ops(jax.jit(f).lower(x).compile().as_text(), _COLLECTIVES)
+    data, pod = "{{0,1},{2,3}}", "{{0,2},{1,3}}"
+    assert ops == [("all-reduce", "f32[78643200]", data),
+                   ("all-reduce", "f32[39321600]", pod),
+                   ("all-gather", "f32[78643200]", data)], ops
+
+
+def test_train_step_gathers_no_moment(topo):
+    """The tiny h2o step on a 2x2 (pod x data) keeps the f32 moments
+    sharded through the step: every all-gather moves bf16 parameters."""
+    from repro.models import lm
+    from repro.training.optimizer import OptConfig, init_opt_state
+    from repro.training.train_step import make_train_step_shardmap
+
+    cfg = get_smoke_config("h2o_danube_3_4b")
+    cfg = dataclasses.replace(
+        cfg, parallel=dataclasses.replace(cfg.parallel, fsdp=False))
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2, 1),
+                ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
+    opt = OptConfig()
+    mk, (pspec, ospec) = make_train_step_shardmap(cfg, mesh, opt)
+
+    def place(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            tree, specs)
+
+    params = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: init_opt_state(params, opt))
+    batch = {k: jax.ShapeDtypeStruct((8, 32), jnp.int32,
+                                     sharding=NamedSharding(
+                                         mesh, P(("pod", "data"))))
+             for k in ("tokens", "labels")}
+    text = mk(batch).lower(place(params, pspec), place(state, ospec),
+                           batch).compile().as_text()
+    gathers = _ops(text, ("all-gather",), entry_only=False)
+    assert gathers and all(t.startswith("bf16[") for _, t, _ in gathers), (
+        gathers)

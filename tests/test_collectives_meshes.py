@@ -31,6 +31,24 @@ def test_hierarchical_psum_all_factorizations(shape):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_hierarchical_reduce_scatter_all_factorizations(shape, dim):
+    """Each chip keeps its ``lane`` tile along ``dim`` of the whole sum."""
+    mesh = _mesh(shape)
+    lanes = shape[1]
+    x = np.random.RandomState(3).randn(8, 8, 16).astype(np.float32)
+    got = jax.jit(shard_map(
+        lambda v: C.hierarchical_reduce_scatter(v[0], "pod", "lane",
+                                                dim)[None],
+        mesh=mesh, in_specs=P(("pod", "lane")),
+        out_specs=P(("pod", "lane"))))(x)
+    tiles = np.split(x.sum(0), lanes, axis=dim)
+    for chip in range(8):
+        np.testing.assert_allclose(got[chip], tiles[chip % lanes], rtol=1e-5,
+                                   atol=1e-5)
+
+
 @pytest.mark.parametrize("shape", [(2, 4), (4, 2)])
 def test_fulllane_a2a_all_factorizations(shape):
     mesh = _mesh(shape)

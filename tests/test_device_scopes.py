@@ -74,10 +74,13 @@ def test_train_step_stages_scoped(tiny_step):
 
 
 def test_train_step_sync_phases_scoped(tiny_step):
+    """Every h2o leaf takes the ZeRO-1 path: its gradient is reduced to the
+    moment tile by ``hierarchical_reduce_scatter`` and the new parameter
+    tiles are gathered under ``sync/param_gather``."""
     scopes = _scopes_of(tiny_step[0])
-    for phase in ("reduce_scatter", "cross_pod", "all_gather"):
-        assert any(f"/sync/hierarchical_psum/{phase}/" in s for s in scopes), (
-            phase, scopes)
+    for phase in ("hierarchical_reduce_scatter/reduce_scatter",
+                  "hierarchical_reduce_scatter/cross_pod", "param_gather"):
+        assert any(f"/sync/{phase}/" in s for s in scopes), (phase, scopes)
 
 
 def _compile(fn, mesh, shape):

@@ -7,12 +7,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import get_smoke_config
 from repro.launch.mesh import make_test_mesh
 from repro.models import lm
-from repro.training.optimizer import OptConfig, init_opt_state
+from repro.models.params import ParamMeta
+from repro.obs.trace import TRACER
+from repro.training.optimizer import OptConfig, adamw_update, init_opt_state
 from repro.training.train_step import (
+    _grad_and_metrics,
+    dp_axes,
     make_train_step_pjit,
     make_train_step_shardmap,
 )
@@ -57,6 +62,87 @@ def test_backends_agree(mesh):
                     jax.tree.leaves(results["fulllane"][0])):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=1e-5)
+
+
+def _replicated_step(cfg, mesh, opt):
+    """The shard_map step without ZeRO-1 inside it: moments replicated
+    through the step, the whole gradient summed over the DP axes, every
+    chip updating every element."""
+    dp = dp_axes(mesh)
+    ndp = int(np.prod([mesh.shape[a] for a in dp]))
+
+    def step(params, state, batch):
+        grads, _ = _grad_and_metrics(cfg, params, batch)
+        grads = jax.tree.map(lambda g: jax.lax.psum(g, dp) / ndp, grads)
+        return adamw_update(grads, state, params, opt)
+
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=(P(), P(), P(dp)),
+                                 out_specs=(P(), P(), P()),
+                                 axis_names=set(dp), check_vma=False))
+
+
+def _zero1_event(build):
+    """Run ``build`` with the tracer on; the attributes of the
+    ``train_step.zero1`` event it records."""
+    was = bool(TRACER)
+    TRACER.enable()
+    mark = TRACER.mark()
+    try:
+        out = build()
+    finally:
+        if not was:
+            TRACER.disable()
+    events = [r for r in TRACER.records_since(mark)
+              if r["name"] == "train_step.zero1"]
+    assert len(events) == 1
+    return out, events[0]["args"]
+
+
+@pytest.mark.parametrize("arch,shape,backend,whole_leaves", [
+    ("h2o_danube_3_4b", (2, 2, 1), "fulllane", 0),
+    ("h2o_danube_3_4b", (2, 2, 1), "xla", 0),
+    ("h2o_danube_3_4b", (1, 1, 1), "fulllane", 0),
+    # mamba's A_log, skip, conv, dt and x projections have no d_model dim
+    ("falcon_mamba_7b", (2, 2, 1), "fulllane", 7),
+])
+def test_zero1_step_matches_replicated(arch, shape, backend, whole_leaves):
+    """The ZeRO-1 step (moments sharded over ``data``, each gradient leaf
+    reduced to its moment tile, the tile updated, parameters gathered)
+    gives the replicated update's params, moments and grad norm over three
+    steps; leaves with no dim that ``data`` shards take the whole path."""
+    base = get_smoke_config(arch)
+    cfg = dataclasses.replace(
+        base, dtype="float32",
+        parallel=dataclasses.replace(base.parallel, fsdp=False))
+    n = int(np.prod(shape))
+    mesh = jax.make_mesh(shape, ("pod", "data", "model"),
+                         devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * 3)
+    (mk, (_, ospec)), counts = _zero1_event(
+        lambda: make_train_step_shardmap(cfg, mesh, OPT, backend=backend))
+    leaves = len(jax.tree.leaves(lm.model_meta(cfg),
+                                 is_leaf=lambda x: isinstance(x, ParamMeta)))
+    assert counts["leaves"] == leaves
+    assert counts["sharded_leaves"] == leaves - whole_leaves
+    assert (counts["sharded_bytes_share"] == 1.0) == (whole_leaves == 0)
+
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    state = init_opt_state(params, OPT)
+    batch = _batch(cfg)
+    fn, ref = mk(batch), _replicated_step(cfg, mesh, OPT)
+    got = (jax.tree.map(jnp.copy, params), jax.tree.map(jnp.copy, state))
+    want = (params, state)
+    for t in range(3):
+        *got, gm = fn(*got, _batch(cfg, seed=t))
+        *want, wm = ref(*want, _batch(cfg, seed=t))
+        np.testing.assert_allclose(gm["grad_norm"], wm["grad_norm"],
+                                   rtol=1e-5)
+    assert got[1]["m"]["head"]["lm_head"].sharding.spec == \
+        ospec["m"]["head"]["lm_head"]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
 
 
 @pytest.fixture
