@@ -22,6 +22,7 @@ from repro.configs.base import (
 
 ARCH_IDS = [
     "deepseek_v2_236b",
+    "deepseek_v2_lite",
     "dbrx_132b",
     "jamba_1_5_large_398b",
     "musicgen_large",

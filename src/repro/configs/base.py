@@ -13,6 +13,7 @@ import math
 from typing import Literal
 
 __all__ = [
+    "YarnScaling",
     "AttnConfig",
     "MoEConfig",
     "MambaConfig",
@@ -22,6 +23,22 @@ __all__ = [
     "ShapeSpec",
     "SHAPES",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN context extension of the rotary frequencies (a ``rope_scaling``
+    of type "yarn"): low frequencies interpolated by ``factor``, high ones
+    kept, a linear ramp between the dims that turn ``beta_fast`` and
+    ``beta_slow`` times over ``original_max_position``; the attention
+    scale grows by ``mscale(mscale_all_dim)**2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +56,7 @@ class AttnConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    yarn: YarnScaling | None = None  # MLA rope_scaling (deepseek-v2)
 
     @property
     def qk_head_dim(self) -> int:
@@ -59,6 +77,17 @@ class MoEConfig:
     num_shared_experts: int = 0  # deepseek: always-on experts
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    norm_topk_prob: bool = True  # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0  # scales the routed experts' output
+    seq_aux: bool = False  # balance loss per sequence over all k (deepseek)
+    # experts whose weights the model holds, 0..n-1 (None: all); routing
+    # stays over all ``num_experts``, the others' assignments are left out
+    num_experts_held: int | None = None
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts if self.num_experts_held is None else \
+            self.num_experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +119,10 @@ class ParallelConfig:
     optimizer_dtype: str = "float32"  # bf16 moments for >=200B models
     grad_dtype: str = "float32"  # accumulation dtype (bf16 saves HBM at scale)
     moe_groups: int = 1  # MoE dispatch groups (set to DP size by factories)
+    # mesh axes the experts are sharded over inside the shard_map step: set
+    # by make_train_step_shardmap alone (the pjit step refuses it); ()
+    # keeps every expert on every chip
+    ep_axes: tuple[str, ...] = ()
     attn_chunk_q: int = 512
     attn_chunk_kv: int = 512
     mamba_chunk: int = 256

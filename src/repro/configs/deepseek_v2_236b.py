@@ -2,7 +2,8 @@
 
 60L d_model=5120 128H MLA(kv_lora=512, q_lora=1536) vocab=102400;
 MoE: 160 routed experts top-6 + 2 shared, expert d_ff=1536, first layer dense
-(dense d_ff=12288).  Total params ~236B, active ~21B.
+(dense d_ff=12288), top-6 weights not renormalised and scaled by
+routed_scaling_factor 16.  Total params ~236B, active ~21B.
 """
 
 from repro.configs.base import (
@@ -29,7 +30,8 @@ CONFIG = ModelConfig(
         v_head_dim=128,
     ),
     moe=MoEConfig(
-        num_experts=160, top_k=6, d_ff_expert=1536, num_shared_experts=2
+        num_experts=160, top_k=6, d_ff_expert=1536, num_shared_experts=2,
+        norm_topk_prob=False, routed_scaling_factor=16.0,
     ),
     layer_pattern=(LayerSpec("attn", "moe"),),
     first_k_dense=1,

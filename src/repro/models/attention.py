@@ -13,6 +13,10 @@ Three compute paths:
   they are tiny for q_len = 1).
 * MLA decode uses the *absorbed* latent form: scores and values are taken
   directly against the compressed ``c_kv`` cache (the MLA serving win).
+
+MLA with a ``yarn`` rope scaling (DeepSeek-V2) takes YaRN's rotary
+frequencies and multiplies the softmax scale by ``mscale(mscale_all_dim)²``,
+as the published modeling code does.
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnConfig, ModelConfig
-from repro.models.layers import rope
+from repro.models.layers import rope, yarn_mscale
 from repro.models.params import ParamMeta
 
 __all__ = [
@@ -324,6 +328,9 @@ def _mla_attention(cfg, p, x, positions, cache, cache_pos, fill_cache):
     H = a.num_heads
     nope, rdim, vdim = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim
     scale = 1.0 / math.sqrt(a.qk_head_dim)
+    if a.yarn is not None and a.yarn.mscale_all_dim:
+        # YaRN's temperature, on the scores of every dim (DeepSeek-V2)
+        scale *= yarn_mscale(a.yarn.factor, a.yarn.mscale_all_dim) ** 2
 
     # --- queries ---
     if a.q_lora_rank:
@@ -334,7 +341,7 @@ def _mla_attention(cfg, p, x, positions, cache, cache_pos, fill_cache):
     else:
         qf = (x @ p["wq_b"]).reshape(B, S, H, nope + rdim)
     q_nope, q_rope = qf[..., :nope], qf[..., nope:]
-    q_rope = rope(q_rope, positions, a.rope_theta)
+    q_rope = rope(q_rope, positions, a.rope_theta, yarn=a.yarn)
 
     # --- compressed kv ---
     from repro.models.layers import rms_norm
@@ -342,7 +349,8 @@ def _mla_attention(cfg, p, x, positions, cache, cache_pos, fill_cache):
     kv_a = x @ p["wkv_a"]  # [B, S, kv_lora + rdim]
     ckv = rms_norm(kv_a[..., : a.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = rope(
-        kv_a[..., a.kv_lora_rank :][:, :, None, :], positions, a.rope_theta
+        kv_a[..., a.kv_lora_rank :][:, :, None, :], positions, a.rope_theta,
+        yarn=a.yarn,
     )[:, :, 0, :]  # [B, S, rdim] shared across heads
 
     wkv_b = p["wkv_b"].reshape(a.kv_lora_rank, H, nope + vdim)

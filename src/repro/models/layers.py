@@ -8,6 +8,8 @@ materialized (or abstract) parameter dict.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -25,6 +27,8 @@ __all__ = [
     "logits",
     "rope",
     "mrope_positions",
+    "yarn_inv_freq",
+    "yarn_mscale",
 ]
 
 
@@ -148,6 +152,34 @@ def _rope_freqs(head_dim: int, theta: float) -> jax.Array:
     )
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> jax.Array:
+    """YaRN rotary frequencies (DeepSeek-V2's ``rope_scaling``): each
+    frequency is the plain one divided by ``factor`` below the dim that
+    turns ``beta_slow`` times over the original context, kept above the
+    dim that turns ``beta_fast`` times, and blended linearly between."""
+    def turn_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(turn_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(turn_dim(yarn.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = _rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp  # 1: the plain frequency, 0: interpolated
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
 def mrope_positions(positions: jax.Array, sections: tuple[int, ...]) -> jax.Array:
     """Qwen2-VL multimodal RoPE: ``positions`` [B, S, 3] (t, h, w) ->
     per-frequency positions [B, S, head_dim/2] by section assignment."""
@@ -164,20 +196,31 @@ def rope(
     theta: float,
     *,
     sections: tuple[int, ...] | None = None,
+    yarn=None,
 ) -> jax.Array:
     """Apply rotary embedding.
 
     x: [B, S, H, head_dim]; positions: [B, S] (or [B, S, 3] with
     ``sections`` for M-RoPE).  Rotation uses the llama "rotate-half" layout.
+    ``yarn`` (a :class:`YarnScaling`) takes YaRN's frequencies, and its
+    cos/sin scale ``mscale(mscale) / mscale(mscale_all_dim)``.
     """
     head_dim = x.shape[-1]
-    freqs = _rope_freqs(head_dim, theta)  # [hd/2]
+    if yarn is None:
+        freqs = _rope_freqs(head_dim, theta)  # [hd/2]
+    else:
+        freqs = yarn_inv_freq(head_dim, theta, yarn)
     if sections is not None:
         pos = mrope_positions(positions, sections).astype(jnp.float32)  # [B,S,hd/2]
         angles = pos * freqs  # [B, S, hd/2]
     else:
         angles = positions.astype(jnp.float32)[..., None] * freqs  # [B,S,hd/2]
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)  # [B,S,1,hd/2]
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
+    cos = cos[..., None, :].astype(x.dtype)  # [B,S,1,hd/2]
+    sin = sin[..., None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -30,6 +30,7 @@ from repro.models.params import ParamMeta, abstract_params, init_params
 
 __all__ = [
     "model_meta",
+    "counter_names",
     "init_model",
     "abstract_model",
     "loss_fn",
@@ -135,11 +136,28 @@ def abstract_cache(cfg: ModelConfig, batch: int, capacity: int):
 # ---------------------------------------------------------------------------
 
 
+def counter_names(cfg: ModelConfig) -> tuple[str, ...]:
+    """The counts among the loss's metrics, beside its means: summed over
+    layers, and by the train steps over micro-batches and chips."""
+    if any(s.ffn == "moe" for s in cfg.layer_pattern):
+        return ("moe_dropped", "moe_routed")
+    return ()
+
+
+def _zero_stats(cfg: ModelConfig) -> dict:
+    return {k: jnp.zeros((), jnp.float32)
+            for k in ("aux",) + counter_names(cfg)}
+
+
+def _add_stats(total: dict, stats: dict) -> dict:
+    return {k: v + stats[k] if k in stats else v for k, v in total.items()}
+
+
 def _apply_slot(
     cfg, spec: LayerSpec, p, x, positions, *, cache=None, cache_pos=None,
     fill_cache=False, act_shard=None,
 ):
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     h = L.rms_norm(x, p["norm1"], cfg.norm_eps)
     if spec.mixer == "attn":
         res = attn_mod.attention(
@@ -157,17 +175,17 @@ def _apply_slot(
         if spec.ffn == "dense":
             f = L.mlp(p["ffn"], h2, cfg.act)
         else:
-            f, aux = moe_mod.moe(cfg, p["ffn"], h2, act_shard=act_shard)
+            f, stats = moe_mod.moe(cfg, p["ffn"], h2, act_shard=act_shard)
         x = x + f
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 def _period_body(cfg, positions, *, mode: str, cache_pos=None, remat=False,
                  act_shard=None):
-    """Returns a scan body over (carry=(x, aux), xs=(period_params[,cache]))."""
+    """Returns a scan body over (carry=(x, stats), xs=(period_params[,cache]))."""
 
     def body(carry, xs):
-        x, aux_sum = carry
+        x, stats_sum = carry
         if act_shard is not None:
             # re-pin the batch-dim DP sharding every period: GSPMD otherwise
             # drifts to feature-dim sharding inside the scan (observed as
@@ -180,19 +198,19 @@ def _period_body(cfg, positions, *, mode: str, cache_pos=None, remat=False,
         new_caches = {}
         for i, spec in enumerate(cfg.layer_pattern):
             slot = f"slot{i}"
-            x, nc, aux = _apply_slot(
+            x, nc, stats = _apply_slot(
                 cfg, spec, pp[slot], x, positions,
                 cache=caches.get(slot),
                 cache_pos=cache_pos,
                 fill_cache=(mode == "prefill"),
                 act_shard=act_shard,
             )
-            aux_sum = aux_sum + aux
+            stats_sum = _add_stats(stats_sum, stats)
             if nc is not None:
                 new_caches[slot] = nc
         if mode == "train":
-            return (x, aux_sum), None
-        return (x, aux_sum), new_caches
+            return (x, stats_sum), None
+        return (x, stats_sum), new_caches
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -201,8 +219,10 @@ def _period_body(cfg, positions, *, mode: str, cache_pos=None, remat=False,
 
 def _backbone(cfg: ModelConfig, params, x, positions, *, mode, cache=None,
               cache_pos=None, act_shard=None):
-    """Embed-to-final-norm trunk shared by all entry points."""
-    aux = jnp.zeros((), jnp.float32)
+    """Embed-to-final-norm trunk shared by all entry points; returns the
+    trunk's output, the layers' summed stats (``aux`` and the counters of
+    :func:`counter_names`) and the new cache."""
+    stats = _zero_stats(cfg)
     if act_shard is not None:
         x = act_shard(x)
     # prelude (unrolled, e.g. DeepSeek first dense layer)
@@ -217,7 +237,7 @@ def _backbone(cfg: ModelConfig, params, x, positions, *, mode, cache=None,
             fill_cache=(mode == "prefill"),
             act_shard=act_shard,
         )
-        aux = aux + a
+        stats = _add_stats(stats, a)
         if cache is not None and nc is not None:
             cache = {**cache, f"prelude{j}": nc}
 
@@ -227,7 +247,7 @@ def _backbone(cfg: ModelConfig, params, x, positions, *, mode, cache=None,
         act_shard=act_shard,
     )
     if mode == "train":
-        (x, aux), _ = jax.lax.scan(body, (x, aux), params["blocks"])
+        (x, stats), _ = jax.lax.scan(body, (x, stats), params["blocks"])
         new_cache = None
     elif mode == "decode":
         # Decode unrolls the period loop: a lax.scan would carry the whole
@@ -245,7 +265,7 @@ def _backbone(cfg: ModelConfig, params, x, positions, *, mode, cache=None,
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
                 block_caches,
             )
-            (x, aux), nc = body((x, aux), (pp, pc))
+            (x, stats), nc = body((x, stats), (pp, pc))
             block_caches = jax.tree.map(
                 lambda full, new: jax.lax.dynamic_update_index_in_dim(
                     full, new.astype(full.dtype), i, 0
@@ -255,13 +275,13 @@ def _backbone(cfg: ModelConfig, params, x, positions, *, mode, cache=None,
         new_cache = {**{k: v for k, v in cache.items() if k != "blocks"},
                      "blocks": block_caches}
     else:
-        (x, aux), block_caches = jax.lax.scan(
-            body, (x, aux), (params["blocks"], cache["blocks"])
+        (x, stats), block_caches = jax.lax.scan(
+            body, (x, stats), (params["blocks"], cache["blocks"])
         )
         new_cache = {**{k: v for k, v in cache.items() if k != "blocks"},
                      "blocks": block_caches}
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux, new_cache
+    return x, stats, new_cache
 
 
 def _default_positions(cfg: ModelConfig, B: int, S: int, offset=0):
@@ -296,8 +316,8 @@ def loss_fn(cfg: ModelConfig, params, batch, *, act_shard=None) -> tuple[jax.Arr
     ([B, S] int32, or [B, S, K] for multi-codebook).  ``act_shard`` is an
     optional x -> x hook pinning activation shardings (see train_step)."""
     x, positions = _embed_or_passthrough(cfg, params, batch)
-    x, aux, _ = _backbone(cfg, params, x, positions, mode="train",
-                          act_shard=act_shard)
+    x, stats, _ = _backbone(cfg, params, x, positions, mode="train",
+                            act_shard=act_shard)
     lg = L.logits(cfg, params, x)
     labels = batch["labels"]
     # lse in fp32 (logsumexp upcasts internally); label logit via one-hot
@@ -307,8 +327,10 @@ def loss_fn(cfg: ModelConfig, params, batch, *, act_shard=None) -> tuple[jax.Arr
     ll = jnp.einsum("...v,...v->...", lg, onehot,
                     preferred_element_type=jnp.float32)
     nll = (lse - ll).mean()
+    aux = stats["aux"]
     loss = nll + aux
-    return loss, {"loss": loss, "nll": nll, "aux": aux}
+    counts = {k: stats[k] for k in counter_names(cfg)}
+    return loss, {"loss": loss, "nll": nll, "aux": aux, **counts}
 
 
 def prefill(cfg: ModelConfig, params, batch, *, capacity: int | None = None,

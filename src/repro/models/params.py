@@ -8,7 +8,9 @@ initialization, consumed three ways:
 A parameter is described by :class:`ParamMeta` with per-dimension *logical
 axis* names; sharding rules map logical axes to mesh axes, first-come
 first-served (a mesh axis is used at most once per param) and only when the
-dimension is divisible by the mesh axis size.
+dimension is divisible by the mesh axis size.  A rule's mesh axis may be a
+tuple of axes, which shards the dim over their product (the expert-parallel
+rule: ``experts`` over the data-parallel axes, see :func:`partition_specs`).
 """
 
 from __future__ import annotations
@@ -91,6 +93,7 @@ TP_RULES: dict[str, tuple[str, ...]] = {
     "heads_flat": ("model",),  # flattened num_heads*head_dim projections
     "ff": ("model",),
     "experts": ("model",),
+    "router": ("model",),  # the router's per-expert outputs
     "d_inner": ("model",),
     "lora": (),
     "d_model": (),
@@ -106,14 +109,16 @@ FSDP_RULES: dict[str, tuple[str, ...]] = {
 
 def _spec_for(meta: ParamMeta, rules: dict, mesh_axis_sizes: dict) -> PartitionSpec:
     used: set[str] = set()
-    out: list[str | None] = []
+    out: list[str | tuple[str, ...] | None] = []
     for dim, axis in zip(meta.shape, meta.axes):
         chosen = None
         for mesh_axis in rules.get(axis, ()) if axis else ():
-            size = mesh_axis_sizes.get(mesh_axis)
-            if size and mesh_axis not in used and dim % size == 0:
+            names = mesh_axis if isinstance(mesh_axis, tuple) else (mesh_axis,)
+            sizes = [mesh_axis_sizes.get(a) for a in names]
+            if (all(sizes) and used.isdisjoint(names)
+                    and dim % math.prod(sizes) == 0):
                 chosen = mesh_axis
-                used.add(mesh_axis)
+                used.update(names)
                 break
         out.append(chosen)
     while out and out[-1] is None:
@@ -121,12 +126,18 @@ def _spec_for(meta: ParamMeta, rules: dict, mesh_axis_sizes: dict) -> PartitionS
     return PartitionSpec(*out)
 
 
-def partition_specs(meta_tree, mesh_axis_sizes: dict[str, int], *, fsdp: bool = True):
+def partition_specs(meta_tree, mesh_axis_sizes: dict[str, int], *,
+                    fsdp: bool = True, ep: tuple[str, ...] = ()):
     """PartitionSpec pytree for the parameter tree.
 
     ``mesh_axis_sizes`` maps mesh axis name -> size, e.g. {"data": 16,
     "model": 16} (the "pod" axis never shards parameters: pods are pure DP
     replicas, which is what makes the paper's cross-pod collectives the
-    interesting traffic)."""
+    interesting traffic).  ``ep`` names the data-parallel axes of an
+    expert-parallel step: the ``experts`` dim is then sharded over all of
+    them at once, in place of ``model``, and no other dim of an expert
+    leaf takes those axes."""
     rules = FSDP_RULES if fsdp else TP_RULES
+    if ep:
+        rules = {**rules, "experts": (ep[0] if len(ep) == 1 else tuple(ep),)}
     return _tree_map_meta(lambda m: _spec_for(m, rules, mesh_axis_sizes), meta_tree)

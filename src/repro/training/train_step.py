@@ -22,7 +22,10 @@ collective families.
   The AdamW moments stay sharded over ``data`` (ZeRO-1) through the step:
   each gradient leaf is reduced down to the moment tile this chip owns
   (``hierarchical_reduce_scatter`` on ``fulllane``), AdamW updates that
-  tile, and the new parameters are all-gathered.
+  tile, and the new parameters are all-gathered.  A model with MoE layers
+  runs expert-parallel over the DP axes: each chip owns a share of the
+  experts, the layers exchange tokens with ``fulllane_all_to_all``, and the
+  expert leaves skip the sync, the tiles and the gather.
 
   The dry-run lowers both and diffs collective bytes (EXPERIMENTS.md §Perf).
 
@@ -50,6 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.core import collectives as C
 from repro.models import lm
+from repro.models import moe as moe_mod
 from repro.models.params import ParamMeta, partition_specs
 from repro.obs.trace import TRACER
 from repro.training.optimizer import (OptConfig, adamw_update, clip_factor,
@@ -81,15 +85,18 @@ def batch_pspec(mesh: Mesh, batch_tree) -> dict:
     return jax.tree.map(lambda _: P(dp), batch_tree)
 
 
-def param_pspecs(cfg: ModelConfig, mesh: Mesh):
+def param_pspecs(cfg: ModelConfig, mesh: Mesh, ep: tuple[str, ...] = ()):
+    """``ep``: the expert-parallel axes the ``experts`` dim shards over."""
     sizes = mesh_axis_sizes(mesh)
-    return partition_specs(lm.model_meta(cfg), sizes, fsdp=cfg.parallel.fsdp)
+    return partition_specs(lm.model_meta(cfg), sizes, fsdp=cfg.parallel.fsdp,
+                           ep=ep)
 
 
-def opt_pspecs(cfg: ModelConfig, mesh: Mesh):
-    """ZeRO-1: moments always use the FSDP rules regardless of param FSDP."""
+def opt_pspecs(cfg: ModelConfig, mesh: Mesh, ep: tuple[str, ...] = ()):
+    """ZeRO-1: moments always use the FSDP rules regardless of param FSDP;
+    an expert leaf's moments follow its ``ep`` sharding."""
     sizes = mesh_axis_sizes(mesh)
-    mom = partition_specs(lm.model_meta(cfg), sizes, fsdp=True)
+    mom = partition_specs(lm.model_meta(cfg), sizes, fsdp=True, ep=ep)
     return {"m": mom, "v": mom, "step": P()}
 
 
@@ -137,14 +144,17 @@ def _grad_and_metrics(cfg: ModelConfig, params, batch, act_shard=None):
 
     mb = _micro_split(batch, n)
     g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, gdt), params)
-    m0 = {"loss": 0.0, "nll": 0.0, "aux": 0.0}
+    counts = lm.counter_names(cfg)
+    m0 = {"loss": 0.0, "nll": 0.0, "aux": 0.0, **{k: 0.0 for k in counts}}
     m0 = jax.tree.map(jnp.float32, m0)
 
     def body(carry, b):
         gacc, macc = carry
         (_, metrics), grads = gfn(params, b)
         gacc = jax.tree.map(lambda a, g: a + g.astype(gdt) / n, gacc, grads)
-        macc = jax.tree.map(lambda a, v: a + v / n, macc, metrics)
+        # means over the micro-batches; the counters are summed
+        macc = {k: macc[k] + (metrics[k] if k in counts else metrics[k] / n)
+                for k in sorted(macc)}
         return (gacc, macc), None
 
     (grads, metrics), _ = jax.lax.scan(body, (g0, m0), mb)
@@ -159,6 +169,9 @@ def _grad_and_metrics(cfg: ModelConfig, params, batch, act_shard=None):
 def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
     """Returns (step_fn, shardings) where step_fn is jit-with-shardings and
     ``shardings = (params, opt, batch_fn)`` for placing real data."""
+    if cfg.parallel.ep_axes:
+        raise ValueError("parallel.ep_axes is the shard_map step's own: "
+                         "the pjit step has no shard_map to exchange in")
     pspec = param_pspecs(cfg, mesh)
     ospec = opt_pspecs(cfg, mesh)
     ns = lambda spec_tree: jax.tree.map(
@@ -194,6 +207,22 @@ def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
 # ---------------------------------------------------------------------------
 
 
+def ep_axes(cfg: ModelConfig, mesh: Mesh) -> tuple[str, ...]:
+    """The DP axes a MoE model's experts shard over in the shard_map step:
+    all of them if the experts held divide over their chips, else ``data``
+    or ``pod`` alone if they do; () keeps every expert on every chip (a
+    dense model, one chip, or no DP axes that fit)."""
+    if cfg.moe is None:
+        return ()
+    sizes = mesh_axis_sizes(mesh)
+    dp = dp_axes(mesh)
+    for axes in (dp, *((a,) for a in reversed(dp))):
+        n = math.prod(sizes[a] for a in axes)
+        if n > 1 and cfg.moe.experts_held % n == 0:
+            return axes
+    return ()
+
+
 def _shard_dim(spec: P, axis: str | None) -> int | None:
     """The dim that ``spec`` shards over mesh axis ``axis``, or None."""
     if axis is not None:
@@ -219,22 +248,51 @@ def make_train_step_shardmap(
     all-gathered over ``data`` (scope ``sync/param_gather``).  Any other
     leaf is synced, updated and kept whole.  The build records
     ``train_step.zero1`` with the leaf count, the leaves on the sharded
-    path and their share of parameter bytes as an event of the tracer."""
+    path and their share of parameter bytes as an event of the tracer.
+
+    Expert parallelism, for a config with MoE layers: the ``experts`` dim
+    of every expert leaf is sharded over the EP axes of :func:`ep_axes`,
+    inside the ``shard_map`` and out, and the layers exchange their tokens
+    over those axes (:mod:`repro.models.moe`, through
+    ``fulllane_all_to_all`` on ``fulllane`` over two axes).  An expert
+    leaf's gradient is summed only over the DP axes left out of EP (none
+    when EP takes them all): the exchange's transpose has already brought
+    every EP chip's tokens to the chip that owns the expert.  Its moments
+    stay local, its squares join the clip norm through a ``psum`` over the
+    EP axes, and it is not gathered.  Where no EP axes fit, the experts
+    are replicated and take the path of every other leaf.  Building the
+    step for a batch records ``train_step.ep`` (``expert_leaves``,
+    ``experts_per_chip``, ``capacity`` and the ``dispatch_bytes`` of one
+    exchange per chip); ``train_step.zero1`` counts no expert leaf of the
+    EP path as sharded.  The step's metrics then carry the
+    layers' ``moe_dropped`` and ``moe_routed``, summed over the DP axes."""
     if cfg.parallel.fsdp:
         raise ValueError("shard_map path requires fsdp=False (replicated DP params)")
     dp = dp_axes(mesh)
     ndp = 1
     for a in dp:
         ndp *= mesh_axis_sizes(mesh)[a]
-    pspec = param_pspecs(cfg, mesh)  # model-axis sharding via outer jit
-    ospec = opt_pspecs(cfg, mesh)
+    ep = ep_axes(cfg, mesh)
+    # DP axes the experts are replicated over: their gradients sum there
+    rest = tuple(a for a in dp if a not in ep)
+    nep = math.prod(mesh_axis_sizes(mesh)[a] for a in ep)
+    cfg = dataclasses.replace(cfg, parallel=dataclasses.replace(
+        cfg.parallel, ep_axes=ep, collective_backend=backend))
+    pspec = param_pspecs(cfg, mesh, ep=ep)  # model-axis sharding via outer jit
+    ospec = opt_pspecs(cfg, mesh, ep=ep)
     is_spec = lambda x: isinstance(x, P)
     # The step clips by the whole gradient's norm, which tiles do not give.
     no_clip = dataclasses.replace(opt_cfg, grad_clip=math.inf)
-    # Per leaf, the dim its moments shard over ``data`` (None: whole leaf).
+    # Per leaf, the dim sharded over the EP axes (None: no expert leaf) and
+    # the dim its moments shard over ``data`` (None: whole leaf).
+    # a PartitionSpec entry: one axis by its name, several as a tuple
+    ep_entry = ep[0] if len(ep) == 1 else ep
+    ep_dims = [_shard_dim(s, ep_entry) if ep else None
+               for s in jax.tree.leaves(pspec, is_leaf=is_spec)]
     shard_axis = "data" if "data" in dp else None
-    dims = [_shard_dim(s, shard_axis)
-            for s in jax.tree.leaves(ospec["m"], is_leaf=is_spec)]
+    dims = [None if e is not None else _shard_dim(s, shard_axis)
+            for s, e in zip(jax.tree.leaves(ospec["m"], is_leaf=is_spec),
+                            ep_dims)]
     sizes = [math.prod(m.shape) for m in jax.tree.leaves(
         lm.model_meta(cfg), is_leaf=lambda x: isinstance(x, ParamMeta))]
     TRACER.event("train_step.zero1", leaves=len(dims),
@@ -242,6 +300,7 @@ def make_train_step_shardmap(
                  sharded_bytes_share=sum(
                      n for n, d in zip(sizes, dims) if d is not None)
                  / sum(sizes))
+    counts = lm.counter_names(cfg)
 
     def sync(g):
         if backend == "fulllane" and len(dp) == 2:
@@ -267,29 +326,41 @@ def make_train_step_shardmap(
                                         tiled=True)
         return local(jax.lax.psum(g, dp), d)
 
+    def sync_expert(g):
+        return jax.lax.psum(g, rest) if rest else g
+
     def step(params, opt_state, batch):
         with jax.named_scope("grad"):
             grads, metrics = _grad_and_metrics(cfg, params, batch)
         flat_p, treedef = jax.tree.flatten(params)
         with jax.named_scope("sync"):
-            flat_g = [sync_to_shard(g, d) / ndp
-                      for g, d in zip(jax.tree.leaves(grads), dims)]
+            flat_g = [(sync_expert(g) if e is not None
+                       else sync_to_shard(g, d)) / ndp
+                      for g, d, e in zip(jax.tree.leaves(grads), dims,
+                                         ep_dims)]
+            summed = {k: metrics.pop(k) for k in counts}
             metrics = jax.tree.map(lambda v: jax.lax.psum(v, dp) / ndp,
                                    metrics)
+            metrics.update({k: jax.lax.psum(v, dp) for k, v in summed.items()})
         with jax.named_scope("optimizer"):
             flat_p = [p if d is None else local(p, d)
                       for p, d in zip(flat_p, dims)]
             # The clip's norm is the whole gradient's: a tile's squares are
-            # summed over ``data`` with the other tiles, a whole leaf's
-            # counted once.  The tiles are clipped here, and AdamW updates
-            # them with no clip of its own.
+            # summed over ``data`` with the other tiles, an expert leaf's
+            # over the EP axes with the other chips' experts, a whole
+            # leaf's counted once.  The tiles are clipped here, and AdamW
+            # updates them with no clip of its own.
             parts = []
             tiles = [g for g, d in zip(flat_g, dims) if d is not None]
             if tiles:
                 parts.append(jax.lax.psum(sum_squares(tiles), shard_axis))
-            whole = [g for g, d in zip(flat_g, dims) if d is None]
+            whole = [g for g, d, e in zip(flat_g, dims, ep_dims)
+                     if d is None and e is None]
             if whole:
                 parts.append(sum_squares(whole))
+            experts = [g for g, e in zip(flat_g, ep_dims) if e is not None]
+            if experts:
+                parts.append(jax.lax.psum(sum_squares(experts), ep))
             gnorm = jnp.sqrt(sum(parts))
             clip = clip_factor(opt_cfg, gnorm)
             flat_g = [g * clip for g in flat_g]
@@ -308,23 +379,39 @@ def make_train_step_shardmap(
         lambda s: NamedSharding(mesh, s), t, is_leaf=is_spec
     )
 
-    rep = lambda tree: jax.tree.map(
-        lambda s: P(), tree, is_leaf=is_spec
-    )
-    # Inside the shard_map the moments keep their ``data`` dim sharded.
-    mom = jax.tree.unflatten(
-        jax.tree.structure(ospec["m"], is_leaf=is_spec),
-        [P() if d is None else P(*[None] * d, shard_axis) for d in dims])
+    def ep_spec(e):
+        return P(*[None] * e, ep_entry)
+
+    spec_tree = jax.tree.structure(ospec["m"], is_leaf=is_spec)
+    # Inside the shard_map the parameters are whole but for the expert
+    # leaves, and the moments keep their ``data`` or EP dim sharded.
+    pspec_in = jax.tree.unflatten(
+        spec_tree, [P() if e is None else ep_spec(e) for e in ep_dims])
+    mom = jax.tree.unflatten(spec_tree, [
+        ep_spec(e) if e is not None
+        else P() if d is None else P(*[None] * d, shard_axis)
+        for d, e in zip(dims, ep_dims)])
     ospec_in = {"m": mom, "v": mom, "step": P()}
-    metric_spec = {k: P() for k in ("loss", "nll", "aux", "grad_norm", "lr")}
+    metric_spec = {k: P() for k in ("loss", "nll", "aux", "grad_norm", "lr")
+                   + counts}
 
     def jitted(batch_tree):
+        if ep:
+            labels = batch_tree["labels"]
+            rows = labels.shape[0] // ndp // max(cfg.parallel.microbatches, 1)
+            cap = moe_mod.capacity(rows * labels.shape[1], cfg.moe)
+            TRACER.event(
+                "train_step.ep",
+                expert_leaves=sum(e is not None for e in ep_dims),
+                experts_per_chip=cfg.moe.experts_held // nep, capacity=cap,
+                dispatch_bytes=cfg.moe.experts_held * cap * cfg.d_model
+                * jnp.dtype(cfg.dtype).itemsize)
         bspec_in = jax.tree.map(lambda _: P(dp), batch_tree)
         inner = jax.shard_map(
             step,
             mesh=mesh,
-            in_specs=(rep(pspec), ospec_in, bspec_in),
-            out_specs=(rep(pspec), ospec_in, metric_spec),
+            in_specs=(pspec_in, ospec_in, bspec_in),
+            out_specs=(pspec_in, ospec_in, metric_spec),
             axis_names=set(dp),
             check_vma=False,
         )

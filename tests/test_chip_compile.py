@@ -256,3 +256,46 @@ def test_train_step_gathers_no_moment(topo):
     gathers = _ops(text, ("all-gather",), entry_only=False)
     assert gathers and all(t.startswith("bf16[") for _, t, _ in gathers), (
         gathers)
+
+
+def test_v2_lite_ep_step_fits_a_v5e(topo):
+    """The benchmark's DeepSeek-V2-Lite step at published widths (the dense
+    layer and 4 MoE layers, 16 experts a chip, 2 x 4096 tokens a chip) on
+    the 2x2: it compiles, its memory fits a 16 GB chip, and every
+    all-to-all is the experts' exchange, under the layers' dispatch and
+    combine scopes."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.training.optimizer import OptConfig, init_opt_state
+    from repro.training.train_step import make_train_step_shardmap
+
+    cfg = dataclasses.replace(get_config("deepseek_v2_lite"), num_layers=5,
+                              vocab_size=25600)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2, 1),
+                ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
+    opt = OptConfig()
+    mk, (pspec, ospec) = make_train_step_shardmap(cfg, mesh, opt)
+
+    def place(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            tree, specs)
+
+    params = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: init_opt_state(params, opt))
+    batch = {k: jax.ShapeDtypeStruct((8, 4096), jnp.int32,
+                                     sharding=NamedSharding(
+                                         mesh, P(("pod", "data"))))
+             for k in ("tokens", "labels")}
+    compiled = mk(batch).lower(place(params, pspec), place(state, ospec),
+                               batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    scopes = [n for k, n in collective_scopes(compiled.as_text())
+              if k == "all-to-all"]
+    # per MoE layer: dispatch and combine, each two phases, in the forward
+    # pass, its recomputation and its transpose
+    assert len(scopes) == 12
+    assert all(re.search(r"moe/(dispatch|combine)/fulllane_all_to_all/"
+                         r"(intra|cross_pod)/", n) for n in scopes), scopes

@@ -13,7 +13,8 @@ from repro.models import lm
 
 # published sizes (from the arch ids), 10% tolerance
 _PUBLISHED_B = {
-    "deepseek_v2_236b": 236, "dbrx_132b": 132, "jamba_1_5_large_398b": 398,
+    "deepseek_v2_236b": 236, "deepseek_v2_lite": 15.7,
+    "dbrx_132b": 132, "jamba_1_5_large_398b": 398,
     "musicgen_large": 2.4, "gemma_7b": 8.5, "yi_6b": 6.1, "minicpm3_4b": 4.3,
     "h2o_danube_3_4b": 4.0, "qwen2_vl_7b": 7.6, "falcon_mamba_7b": 7.3,
 }
@@ -85,7 +86,8 @@ def test_sub_quadratic_flags():
     assert get_config("falcon_mamba_7b").sub_quadratic
     assert get_config("jamba_1_5_large_398b").sub_quadratic
     assert get_config("h2o_danube_3_4b").sub_quadratic  # SWA
-    for a in ("deepseek_v2_236b", "dbrx_132b", "gemma_7b", "yi_6b",
+    for a in ("deepseek_v2_236b", "deepseek_v2_lite", "dbrx_132b",
+              "gemma_7b", "yi_6b",
               "minicpm3_4b", "qwen2_vl_7b", "musicgen_large"):
         assert not get_config(a).sub_quadratic, a
 

@@ -12,6 +12,7 @@ from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.launch.mesh import make_test_mesh
 from repro.models import lm
+from repro.models import moe as moe_mod
 from repro.models.params import ParamMeta
 from repro.obs.trace import TRACER
 from repro.training.optimizer import OptConfig, adamw_update, init_opt_state
@@ -145,6 +146,53 @@ def test_zero1_step_matches_replicated(arch, shape, backend, whole_leaves):
                                    atol=1e-6)
 
 
+@pytest.mark.parametrize("shape,ep", [
+    ((2, 2, 1), ("pod", "data")),
+    ((2, 4, 1), ("data",)),  # 4 experts fit over data alone; pod sums
+    ((1, 8, 1), ()),  # 4 experts do not divide over 8 chips: replicated
+    ((1, 1, 1), ()),
+])
+def test_moe_step_matches_replicated(shape, ep):
+    """A MoE model (dbrx smoke, 4 experts) through the fulllane shard_map
+    step: expert-parallel over the DP axes the experts divide over, over
+    some of them, or replicated where none fit; three steps give the
+    replicated step's params, moments and grad norm."""
+    from repro.training.train_step import ep_axes
+
+    base = get_smoke_config("dbrx_132b")
+    cfg = dataclasses.replace(
+        base, dtype="float32",
+        parallel=dataclasses.replace(base.parallel, fsdp=False))
+    n = int(np.prod(shape))
+    mesh = jax.make_mesh(shape, ("pod", "data", "model"),
+                         devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * 3)
+    assert ep_axes(cfg, mesh) == ep
+    mk, (pspec, _) = make_train_step_shardmap(cfg, mesh, OPT,
+                                              backend="fulllane")
+    if ep:  # [layers, experts, ...]
+        assert pspec["blocks"]["slot0"]["ffn"]["w_gate"][1] == (
+            ep if len(ep) > 1 else ep[0])
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    state = init_opt_state(params, OPT)
+    fn, ref = mk(_batch(cfg)), _replicated_step(cfg, mesh, OPT)
+    got = (jax.tree.map(jnp.copy, params), jax.tree.map(jnp.copy, state))
+    want = (params, state)
+    for t in range(3):
+        *got, gm = fn(*got, _batch(cfg, seed=t))
+        *want, wm = ref(*want, _batch(cfg, seed=t))
+        np.testing.assert_allclose(gm["grad_norm"], wm["grad_norm"],
+                                   rtol=1e-5)
+        assert gm["moe_routed"] == cfg.num_layers * 8 * 32 * cfg.moe.top_k
+    # the exchange sums an expert's gradient in another order than the
+    # psum: AdamW's normalisation lifts that to 2e-6 on a few of 49152
+    # elements of the expert leaves (leaving out the sum over ``pod`` on
+    # (2, 4, 1) reads 3e-3)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
 @pytest.fixture
 def compile_cache_restored():
     """train.main turns the persistent compile cache on for its process;
@@ -219,6 +267,15 @@ def test_fsdp_requires_pjit(mesh):
         make_train_step_shardmap(cfg, mesh, OPT)
 
 
+def test_pjit_refuses_ep_axes(mesh):
+    """EP axes name a shard_map's axes: the pjit step has none."""
+    base = get_smoke_config("dbrx_132b")
+    cfg = dataclasses.replace(base, parallel=dataclasses.replace(
+        base.parallel, ep_axes=("data",)))
+    with pytest.raises(ValueError, match="ep_axes"):
+        make_train_step_pjit(cfg, mesh, OPT)
+
+
 @pytest.mark.parametrize("arch", ["jamba_1_5_large_398b", "deepseek_v2_236b",
                                   "falcon_mamba_7b"])
 def test_pjit_step_other_families(mesh, arch):
@@ -230,3 +287,99 @@ def test_pjit_step_other_families(mesh, arch):
     p, o, m = mk(batch)(params, opt, batch)
     assert np.isfinite(m["loss"]) and np.isfinite(m["grad_norm"])
     assert int(o["step"]) == 1
+
+
+def test_v2_lite_ep_step_matches_reference():
+    """DeepSeek-V2-Lite (smoke widths, float32) through the expert-parallel
+    shard_map step on (pod 2, data 2): three steps read as the benchmark
+    reads them (losses, first gradient per leaf, change per leaf) match the
+    plain reference (chipbench/refs/moe_lm.py); the build records the EP
+    leaves, and the metrics carry the layers' counters."""
+    from jax.sharding import NamedSharding
+
+    from chipbench.entries.train_moe_step import published_widths
+    from chipbench.refs import dense_lm, moe_lm
+
+    base = get_smoke_config("deepseek_v2_lite")
+    cfg = dataclasses.replace(base, dtype="float32")
+    mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"),
+                         devices=jax.devices()[:4],
+                         axis_types=(AxisType.Auto,) * 3)
+    opt = dict(learning_rate=1e-3, beta1=0.9, beta2=0.95, eps=1e-8,
+               weight_decay=0.1, grad_clip=1.0, warmup_steps=2)
+    was = bool(TRACER)
+    TRACER.enable()
+    mark = TRACER.mark()
+    try:
+        mk, (pspec, ospec) = make_train_step_shardmap(
+            cfg, mesh, OptConfig(**opt), backend="fulllane")
+        seed, rows, seq = 2**31 + 5, 8, 32
+        batches = [dense_lm.batch(seed, t, rows, seq, cfg.vocab_size)
+                   for t in range(3)]
+        fn = mk({"tokens": batches[0][0], "labels": batches[0][1]})
+    finally:
+        if not was:
+            TRACER.disable()
+    events = {r["name"]: r["args"] for r in TRACER.records_since(mark)}
+    held = cfg.moe.num_experts
+    assert events["train_step.ep"] == {
+        "expert_leaves": 3, "experts_per_chip": held // 4,
+        "capacity": moe_mod.capacity(2 * seq, cfg.moe),
+        "dispatch_bytes": held * moe_mod.capacity(2 * seq, cfg.moe)
+        * cfg.d_model * 4}
+    leaves = len(jax.tree.leaves(lm.model_meta(cfg),
+                                 is_leaf=lambda x: isinstance(x, ParamMeta)))
+    assert events["train_step.zero1"]["leaves"] == leaves
+    assert events["train_step.zero1"]["sharded_leaves"] == leaves - 3
+    assert pspec["blocks"]["slot0"]["ffn"]["w_gate"][1] == ("pod", "data")
+
+    shapes = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    ns = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: NamedSharding(mesh, s), t, is_leaf=lambda x: isinstance(x, P))
+    kd = dense_lm.key_data(seed)
+    params = jax.jit(lambda: moe_lm.init_weights(shapes, kd, jnp.float32),
+                     out_shardings=ns(pspec))()
+    zero = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32), shapes)
+    state = jax.jit(lambda: {"m": zero, "v": zero,
+                             "step": jnp.zeros((), jnp.int32)},
+                    out_shardings=ns(ospec))()
+    losses = []
+    for t, (tokens, labels) in enumerate(batches):
+        params, state, met = fn(params, state,
+                                {"tokens": tokens, "labels": labels})
+        losses.append(float(met["loss"]))
+        # summed over the 2 MoE layers and the 4 chips
+        assert 0 < met["moe_dropped"] < met["moe_routed"] == 2 * rows * seq * 6
+        if t == 0:
+            clip = min(1.0, 1.0 / float(met["grad_norm"]))
+            grads = np.asarray(dense_lm.leaf_norms(state["m"])) / (0.1 * clip)
+    change = np.asarray(dense_lm.leaf_norms(jax.tree.map(
+        jnp.subtract, params, moe_lm.init_weights(shapes, kd, jnp.float32))))
+    got = {"losses": losses, "grad_norms": grads, "change_norms": change}
+    want = moe_lm.Reference(published_widths(cfg), opt, shapes,
+                            jax.devices()[:4]).run(seed, batches)
+    gaps = dense_lm.gaps(got, want)
+    # f32 on both sides: the gaps read 1e-7 to 5e-6; an exchange that moves
+    # nothing reads 4e-3, 0.11 and 0.01
+    assert gaps["loss_gap"] < 1e-5 and gaps["grad_gap"] < 1e-4 and \
+        gaps["change_gap"] < 1e-4, gaps
+
+
+@pytest.mark.parametrize("backend", ["fulllane", "xla"])
+def test_train_main_v2_lite(compile_cache_restored, backend):
+    """The entry point trains DeepSeek-V2-Lite (smoke) on (2, 2, 1); on
+    fulllane every MoE layer's tokens cross the mesh in all-to-alls under
+    the layer's dispatch and combine scopes."""
+    from repro.launch import hloanalysis, train
+
+    out = train.main(["--arch", "deepseek_v2_lite", "--smoke", "--mesh",
+                      "2,2,1", "--steps", "2", "--seq", "32",
+                      "--backend", backend])
+    assert out["steps"] == 2
+    assert np.all(np.isfinite(out["losses"] + out["grad_norms"]))
+    if backend == "fulllane":
+        scopes = [n for k, n in hloanalysis.collective_scopes(
+            out["compiled"].as_text()) if k == "all-to-all"]
+        assert scopes and all("moe/dispatch/fulllane_all_to_all/" in n
+                              or "moe/combine/fulllane_all_to_all/" in n
+                              for n in scopes), scopes
