@@ -3,8 +3,9 @@
 Runs on 8 CPU devices (mesh 2 pods x 4 lanes):
 
 1. routes a batch of tokens to experts with the *flat* XLA all-to-all and
-   with ``fulllane_all_to_all`` (paper §2.2: on-node combine, then
-   node-level exchange) inside shard_map — results must be identical;
+   with ``fulllane_all_to_all`` (paper §2.2: own-pod blocks on-node
+   while the rest cross pods, each forwarded on-node as it lands) inside
+   shard_map — results must be identical;
 2. compares the collective bytes in the two compiled HLO modules.
 
   PYTHONPATH=src python examples/moe_ep_demo.py
@@ -55,10 +56,9 @@ def main():
         print(f"{name:9s} collective bytes/device: "
               f"{ {k: v for k, v in sorted(cost.collective_bytes.items())} }")
     print("""
-On this toy mesh both phases are ICI; on the production 2-pod mesh the
-hierarchical form combines each pod's cross-pod traffic into one large
-message per destination pod with every chip driving a lane concurrently —
-the paper's full-lane argument.  See EXPERIMENTS.md §Perf (deepseek EP).
+On this toy mesh both phases are ICI; on a multi-pod mesh every chip of a
+pod drives its own cross-pod stream while its on-node link carries the
+own-pod and forwarded blocks — the paper's full-lane argument.
 """)
 
 

@@ -9,7 +9,9 @@ collectives:
     cross-pod allreduce  = reduce_scatter(intra) -> allreduce(pod) -> all_gather(intra)
     cross-pod reduce-scatter = reduce_scatter(intra, along a dim) -> allreduce(pod)
     cross-pod broadcast  = [payload lane-sharded on root pod] -> psum(pod) -> all_gather(intra)
-    cross-pod alltoall   = all_to_all(intra, regroup) -> all_to_all(pod)
+    cross-pod alltoall   = own-pod blocks sent on-node (intra) beside the
+                           rest sent across pods (cross_pod), each
+                           forwarded on-node as it lands
 
 Each function opens a ``jax.named_scope`` of its own name, and each phase
 a scope inside it (``reduce_scatter`` / ``cross_pod`` / ``all_gather``,
@@ -36,6 +38,7 @@ import numpy as np
 
 from repro.core import schedule as sched
 from repro.core.topology import Topology
+from repro.obs.trace import TRACER
 
 __all__ = [
     "axis_size",
@@ -151,6 +154,12 @@ def fulllane_broadcast(x: jax.Array, outer_axis, inner_axis, *, root: int = 0) -
             return jax.lax.all_gather(seeded, inner_axis, axis=0, tiled=True)
 
 
+def _shift(x: jax.Array, axis, n: int, s: int) -> jax.Array:
+    """``x`` of every chip to the chip ``s`` places on along ``axis``."""
+    return jax.lax.ppermute(x, axis, perm=[(a, (a + s) % n) for a in range(n)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def fulllane_all_to_all(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
     """Hierarchical all-to-all over the merged (outer, inner) axis.
 
@@ -159,40 +168,105 @@ def fulllane_all_to_all(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
     ordered destination-major ``dest = o * Ni + i``:  block ``x[d]`` on device
     ``s`` ends up as output block ``s`` on device ``d``.
 
-    Paper §2.2: phase A combines blocks by destination *inner* rank with an
-    on-node (intra-pod) all-to-all; phase B delivers node-combined blocks
-    with ``Ni`` concurrent pod-level all-to-alls.  All data moves twice, but
-    the cross-pod stream count per pod is ``Ni`` (all lanes busy) and the
-    per-pod cross-pod traffic is combined into ``No`` large messages.
+    Paper §2.2 as block moves on chip ``(w, j)`` (pod ``w``, lane ``j``).
+    Block ``(w + t, j + u)`` (mod ``No``, ``Ni``) of its input goes:
+
+    * ``t = 0``: on-node, straight to lane ``j + u`` (``intra`` shift
+      round ``u``).  These blocks wait for nothing, so they start with the
+      first cross-pod send and the lane link runs beside the pod link.
+    * ``t > 0``: across pods to ``(w + t, j)`` (``cross_pod`` shift round
+      ``t``), which keeps it (``u = 0``) or forwards it on its own lane
+      link (``intra`` shift ``u``) as soon as it lands.  Every chip of a
+      pod drives its own cross-pod stream (all ``Ni`` lanes busy).  A
+      round's blocks go one after the other, those the receiver forwards
+      first, so each forward runs under the round's later sends.
+
+    Each block sent is one whole block of ``x``, and the blocks of each
+    link's first send are cut before the others; each block received,
+    and the chip's own block, lands in place at its source's index in an
+    uninitialised output.  No whole buffer is transposed, filled or
+    copied: a chip writes ``P - 1`` send slices and ``P`` landed blocks.
+    Building it records ``fulllane_all_to_all.schedule``
+    (``cross_pod_rounds``, ``intra_rounds``, ``overlapped_blocks``: lane
+    sends under a cross-pod send in flight, ``forwarded_blocks``,
+    ``local_move_bytes``) as an event of the tracer.
+
+    Its own transpose: the VJP is the same all-to-all of the cotangent.
     """
+    return _fulllane_all_to_all(x, outer_axis, inner_axis)
+
+
+def _fulllane_all_to_all(x: jax.Array, outer_axis, inner_axis) -> jax.Array:
     No = axis_size(outer_axis)
     Ni = axis_size(inner_axis)
     P = No * Ni
     if x.shape[0] != P:
         raise ValueError(f"leading dim {x.shape[0]} != mesh size {P}")
-    blk = x.shape[1:]
+    block_bytes = x.nbytes // P
+    forwarded = (No - 1) * (Ni - 1)
+    TRACER.event("fulllane_all_to_all.schedule", cross_pod_rounds=No - 1,
+                 intra_rounds=Ni - 1,
+                 overlapped_blocks=No * (Ni - 1) if No > 1 else 0,
+                 forwarded_blocks=forwarded,
+                 local_move_bytes=(2 * P - 1) * block_bytes)
 
-    # The reshapes stay outside the phase scopes, so the regrouping is the
-    # executor's and not a phase's (the compiler may still give a layout
-    # copy the op_name of the collective it follows).
     with jax.named_scope("fulllane_all_to_all"):
-        # [No, Ni, *blk], indexed by (dest_outer, dest_inner).
-        y = x.reshape((No, Ni) + blk)
-        # Phase A (on-node): exchange over inner so that device (v, l) holds
-        # the blocks of all (v, j) destined to inner rank l: split
-        # dest_inner, concat a new source_inner dimension.
+        w = _axis_linear_index(outer_axis)
+        j = _axis_linear_index(inner_axis)
+
+        # each link's first send is cut first; the other blocks are cut
+        # while those sends run
+        first = {(0, u) for u in range(1, Ni)} | {
+            (t, 1 % Ni) for t in range(1, No)}
+        rest = jax.lax.optimization_barrier(x)
+
+        def block(t, u):
+            """Block of ``x`` bound ``t`` pods and ``u`` lanes on."""
+            k = (w + t) % No * Ni + (j + u) % Ni
+            return jax.lax.dynamic_slice_in_dim(
+                x if (t, u) in first else rest, k, 1)
+
+        def source(t, u):
+            """Output index of a block from ``t`` pods and ``u`` lanes back."""
+            return (w - t) % No * Ni + (j - u) % Ni
+
+        # (source index, block), in the order the blocks should land
+        landed = [(source(0, 0), block(0, 0))]
         with jax.named_scope("intra"):
-            y = jax.lax.all_to_all(y, inner_axis, split_axis=1, concat_axis=1,
-                                   tiled=False)
-        # y: [No, Ni_src, *blk] — y[o, j] = block from (v, j) destined to
-        # (o, l).  Phase B (cross-pod): deliver node-combined blocks; split
-        # dest_outer, concat source_outer.
-        with jax.named_scope("cross_pod"):
-            y = jax.lax.all_to_all(y, outer_axis, split_axis=0, concat_axis=0,
-                                   tiled=False)
-        # y: [No_src, Ni_src, *blk] — y[w, j] = block from (w, j) destined
-        # (v, l).
-        return y.reshape((P,) + blk)
+            landed += [(source(0, u), _shift(block(0, u), inner_axis, Ni, u))
+                       for u in range(1, Ni)]
+        for t in range(1, No):
+            prev, prev_u = None, 0
+            for u in list(range(1, Ni)) + [0]:
+                b = block(t, u)
+                if prev is not None:
+                    # the send waits for the round's previous block to land
+                    prev, b = jax.lax.optimization_barrier((prev, b))
+                    if prev_u:
+                        with jax.named_scope("intra"):
+                            landed.append((source(t, prev_u),
+                                           _shift(prev, inner_axis, Ni,
+                                                  prev_u)))
+                with jax.named_scope("cross_pod"):
+                    prev, prev_u = _shift(b, outer_axis, No, t), u
+            landed.append((source(t, 0), prev))
+
+        out = jax.lax.empty(x.shape, x.dtype)
+        for k, b in landed:
+            out = jax.lax.dynamic_update_slice_in_dim(out, b, k, 0)
+        return out
+
+
+def _fulllane_all_to_all_fwd(x, outer_axis, inner_axis):
+    return _fulllane_all_to_all(x, outer_axis, inner_axis), None
+
+
+def _fulllane_all_to_all_bwd(outer_axis, inner_axis, _, g):
+    # block (s, d) went to (d, s): the transpose is the same exchange
+    return (_fulllane_all_to_all(g, outer_axis, inner_axis),)
+
+
+fulllane_all_to_all.defvjp(_fulllane_all_to_all_fwd, _fulllane_all_to_all_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -2,14 +2,13 @@
 
 The paper's on-node phase of the full-lane alltoall regroups each
 processor's blocks by destination *lane* before the cross-node exchange.
-On TPU this is the local ``[No, Ni, blk, d] -> [Ni, No, blk, d]`` block
-transpose that sits on either side of the two ``lax.all_to_all`` phases in
-``repro.core.collectives.fulllane_all_to_all``.  XLA usually fuses this
-copy; the kernel exists to make the data movement explicit and VMEM-tiled
-(one ``(tb, td)`` tile of a ``(blk, d)`` block per grid step, so arbitrary
-No*Ni fan-outs and block sizes stream through VMEM instead of
-materializing a transposed HBM temp).  ``fulllane_all_to_all`` itself
-still leaves the transpose to XLA: no program path calls this kernel yet.
+As a whole-buffer step that is the local ``[No, Ni, blk, d] -> [Ni, No,
+blk, d]`` block transpose this kernel performs, VMEM-tiled (one ``(tb,
+td)`` tile of a ``(blk, d)`` block per grid step, so arbitrary No*Ni
+fan-outs and block sizes stream through VMEM instead of materializing a
+transposed HBM temp).  ``repro.core.collectives.fulllane_all_to_all`` needs
+no such transpose: it sends whole blocks of its input and lands each block
+in place in its output, so no program path calls this kernel.
 
 Tiles are capped at ``_TILE_BYTES``: the pipeline double-buffers the input
 and the output tile, so a step holds four tiles, which must fit the scoped

@@ -24,7 +24,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_smoke_config
 from repro.core import collectives as C
-from repro.launch.hloanalysis import _parse_computations, collective_scopes
+from repro.launch.hloanalysis import (
+    _parse_computations,
+    _type_bytes,
+    collective_scopes,
+)
 from repro.kernels.a2a_pack import a2a_pack_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
@@ -116,9 +120,80 @@ def test_fulllane_all_to_all_compiles(topo):
                       mesh=mesh, in_specs=spec, out_specs=spec)
     compiled = jax.jit(f).lower(x).compile()
     hlo = compiled.as_text()
-    assert "all-to-all" in hlo
+    assert "collective-permute-start" in hlo and "all-to-all" not in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == 4 * MIB
+
+
+_NOT_WRITES = {"parameter", "constant", "bitcast", "get-tuple-element",
+               "tuple", "partition-id", "replica-id"}
+
+
+def _fusion_writes(comps, fused) -> int:
+    """Bytes a fusion writes: each output whole, except that a
+    dynamic-update-slice into a fusion parameter or an uninitialised
+    buffer writes its update in place."""
+    by_name = {ins.name: ins for ins in fused.instrs}
+    root = fused.instrs[-1]
+    outs = ([by_name[o] for o in root.operands] if root.opcode == "tuple"
+            else [root])
+    total = 0
+    for out in outs:
+        if out.opcode == "dynamic-update-slice":
+            base, update = (by_name[o] for o in out.operands[:2])
+            if base.opcode == "parameter" or "AllocateBuffer" in base.raw:
+                total += _type_bytes(update.result_type)
+                continue
+        total += _type_bytes(out.result_type)
+    return total
+
+
+def _entry_writes(text: str) -> int:
+    """Bytes the entry computation's non-collective ops write into
+    arrays (the scalar index arithmetic left out)."""
+    comps, entry = _parse_computations(text)
+    total = 0
+    for ins in comps[entry].instrs:
+        kind = ins.opcode.removesuffix("-start").removesuffix("-done")
+        if re.match(r"\w+\[\]", ins.result_type):
+            continue
+        if ins.opcode in _NOT_WRITES or kind in (
+                "all-to-all", "collective-permute") + _COLLECTIVES:
+            continue
+        calls = re.search(r"calls=%?([\w.\-]+)", ins.raw)
+        if ins.opcode == "fusion" and calls:
+            total += _fusion_writes(comps, comps[calls.group(1)])
+        else:
+            total += _type_bytes(ins.result_type)
+    return total
+
+
+def test_fulllane_all_to_all_schedule(topo):
+    """At the a2a cell's shape ([4, 6144, 5120] bf16 a chip) the exchange
+    copies no whole buffer, its local moves write at most 1.75 output
+    buffers (the three send slices and the four landed blocks), and an
+    on-node send starts before the first cross-pod block lands."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("pod", "lane"),
+                axis_types=(AxisType.Auto,) * 2)
+    spec = P(("pod", "lane"))
+    x = jax.ShapeDtypeStruct((16, 6144, 5120), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, spec))
+    f = jax.shard_map(lambda v: C.fulllane_all_to_all(v, "pod", "lane"),
+                      mesh=mesh, in_specs=spec, out_specs=spec)
+    text = jax.jit(f).lower(x).compile().as_text()
+    comps, entry = _parse_computations(text)
+    ops = comps[entry].instrs
+    assert not [ins.name for ins in ops if ins.opcode == "copy" and re.match(
+        r"bf16\[(4|2,2),6144,5120\]", ins.result_type)]
+    out_bytes = 4 * 6144 * 5120 * 2
+    assert _entry_writes(text) <= 1.75 * out_bytes
+
+    def first(opcode, phase):
+        return next(k for k, ins in enumerate(ops) if ins.opcode == opcode
+                    and f"fulllane_all_to_all/{phase}/" in ins.raw)
+
+    assert first("collective-permute-start", "intra") < first(
+        "collective-permute-done", "cross_pod")
 
 
 @pytest.mark.parametrize("fn,phases", [
@@ -262,8 +337,8 @@ def test_v2_lite_ep_step_fits_a_v5e(topo):
     """The benchmark's DeepSeek-V2-Lite step at published widths (the dense
     layer and 4 MoE layers, 16 experts a chip, 2 x 4096 tokens a chip) on
     the 2x2: it compiles, its memory fits a 16 GB chip, and every
-    all-to-all is the experts' exchange, under the layers' dispatch and
-    combine scopes."""
+    all-to-all or scoped collective-permute is the experts' exchange,
+    under the layers' dispatch and combine scopes."""
     from repro.configs import get_config
     from repro.models import lm
     from repro.training.optimizer import OptConfig, init_opt_state
@@ -292,10 +367,13 @@ def test_v2_lite_ep_step_fits_a_v5e(topo):
                                batch).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # the compiler's own collective-permutes (two small f32 sends of the
+    # sync) carry no scope
     scopes = [n for k, n in collective_scopes(compiled.as_text())
-              if k == "all-to-all"]
-    # per MoE layer: dispatch and combine, each two phases, in the forward
-    # pass, its recomputation and its transpose
-    assert len(scopes) == 12
+              if k in ("all-to-all", "collective-permute") and n]
+    # per MoE layer: dispatch and combine, each four block sends on the
+    # 2x2 (two on-node, two cross-pod), in the forward pass, its
+    # recomputation and its transpose
+    assert len(scopes) == 24
     assert all(re.search(r"moe/(dispatch|combine)/fulllane_all_to_all/"
                          r"(intra|cross_pod)/", n) for n in scopes), scopes

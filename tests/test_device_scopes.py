@@ -118,3 +118,35 @@ def test_kported_rounds_scoped(fn, make, k):
     for r in range(rounds):
         assert any(f"{fn}/round{r}/" in s for s in scopes), (r, scopes)
     assert not any(f"{fn}/round{rounds}/" in s for s in scopes)
+
+
+@pytest.mark.parametrize("shape", [(2, 2), (2, 4)])
+def test_fulllane_all_to_all_schedule_event(shape):
+    """Building the exchange records its block schedule: one cross-pod
+    and ``Ni - 1`` intra rounds, the own-pod and forwarded blocks on the
+    lane links under a cross-pod send, ``P - 1`` send slices and ``P``
+    landed blocks written."""
+    from repro.obs.trace import TRACER
+
+    no, ni = shape
+    p = no * ni
+    mesh = _mesh(shape, ("pod", "lane"))
+    was = bool(TRACER)
+    TRACER.enable()
+    mark = TRACER.mark()
+    try:
+        _compile(lambda v: C.fulllane_all_to_all(v, "pod", "lane"), mesh,
+                 (p * p, 16))
+    finally:
+        if not was:
+            TRACER.disable()
+    events = [r["args"] for r in TRACER.records_since(mark)
+              if r["name"] == "fulllane_all_to_all.schedule"]
+    assert len(events) == 1
+    block_bytes = 16 * 4
+    assert events[0] == {
+        "cross_pod_rounds": no - 1, "intra_rounds": ni - 1,
+        "overlapped_blocks": no * (ni - 1),
+        "forwarded_blocks": (no - 1) * (ni - 1),
+        "local_move_bytes": (2 * p - 1) * block_bytes}
+    assert events[0]["overlapped_blocks"] > 0

@@ -368,7 +368,7 @@ def test_v2_lite_ep_step_matches_reference():
 @pytest.mark.parametrize("backend", ["fulllane", "xla"])
 def test_train_main_v2_lite(compile_cache_restored, backend):
     """The entry point trains DeepSeek-V2-Lite (smoke) on (2, 2, 1); on
-    fulllane every MoE layer's tokens cross the mesh in all-to-alls under
+    fulllane every MoE layer's tokens cross the mesh in block sends under
     the layer's dispatch and combine scopes."""
     from repro.launch import hloanalysis, train
 
@@ -379,7 +379,8 @@ def test_train_main_v2_lite(compile_cache_restored, backend):
     assert np.all(np.isfinite(out["losses"] + out["grad_norms"]))
     if backend == "fulllane":
         scopes = [n for k, n in hloanalysis.collective_scopes(
-            out["compiled"].as_text()) if k == "all-to-all"]
+            out["compiled"].as_text())
+            if k in ("all-to-all", "collective-permute")]
         assert scopes and all("moe/dispatch/fulllane_all_to_all/" in n
                               or "moe/combine/fulllane_all_to_all/" in n
                               for n in scopes), scopes
