@@ -12,23 +12,18 @@ the paper) and ``wall_s`` is the wall-clock cost of producing the cell
 (schedule generation + simulation) — the perf trajectory tracked by
 ``benchmarks.run --json``.  ``csv_row`` renders the legacy CSV line.
 
-``table_optimizer_deltas`` adds the beyond-paper OPT cells: each paper
-algorithm rerun through the schedule optimizer (oracle-validated round
-compaction + coalescing), with the unoptimized baseline and the per-pass
-trajectory attached for the optimized-vs-paper delta table
-(``render_optimizer_deltas``).  ``table_optimizer_deltas2`` (OPT2) runs
-the ISSUE 3 scheduling-pass suite — non-adjacent round reordering and
-k-lane payload splitting under the fixpoint lexicographic PassManager.
-``table_optimizer_deltas3`` (OPT3, ISSUE 4/5) races the conflict-graph
-coloring packer — at the single budget rung the cost-aware chooser picks
-(ISSUE 5), with tree-aware byte caps in the bandwidth regime — against
-the first-fit baseline, over the paper-scale (p=1152) alltoall families
-(klane, fulllane, kported) and broadcast trees; all three trajectories
-are what ``tools/bench_gate.py`` gates in CI.  ``table_paper_opt_smoke``
-(``--only paper-opt``) reruns one of those alltoall cells as the CI
-fast-job scalability smoke.  OPT cells carry ``opt_wall_s`` — the
-optimizer's own wall-clock — so pass-pipeline speed is on the trajectory
-too (the gate stays on ``sim_us``).
+``table_optimizer_deltas3`` adds the beyond-paper OPT3 cells: the
+planner's optimizer pipeline (``compiled_schedule(..., optimize="color")``,
+the rewrite behind every ``opt:`` candidate: recipe replay of the
+structural coloring packer, oracle-checked) over the paper-scale (p=1152)
+alltoall families (klane, fulllane, kported) and broadcast trees, each
+cell with its unoptimized base for the optimized-vs-paper delta table
+(``render_optimizer_deltas``) — priced on the selector's port model, as
+the selector races the two.  ``tools/bench_gate.py`` gates the trajectory
+in CI.  ``table_paper_opt_smoke`` (``--only paper-opt``) reruns one of the
+alltoall cells as the CI fast-job scalability smoke.  OPT3 cells carry
+``opt_wall_s`` — the optimizer's own wall-clock — so pipeline speed is on
+the trajectory too (the gate stays on ``sim_us``).
 
 All cells run on the compiled schedule IR (``repro.core.schedule_ir``):
 the alltoall families are generated array-natively and every schedule is
@@ -41,14 +36,6 @@ from __future__ import annotations
 
 import time
 
-from repro.core.passes import (
-    CoalesceMessages,
-    ColorRounds,
-    CompactRounds,
-    PassManager,
-    ReorderRounds,
-    SplitPayloads,
-)
 from repro.core.schedule_ir import compiled_schedule
 from repro.core.simulate import simulate
 from repro.core.topology import Machine, Topology, hydra_machine
@@ -181,50 +168,36 @@ def table_alltoall():
     return rows
 
 
-def _pass_walls(records, mark=None) -> str:
+def _pass_walls(mark) -> str:
     """Per-pass wall-time breakdown for the rendered delta table (ISSUE 7
-    satellite).  Sourced from the flight recorder when tracing is enabled:
-    the ``pass:{name}`` spans emitted since ``mark`` by this cell's
-    PassManager run, with the PassRecord wall clocks as the untraced
-    fallback — both sum repeat visits of a pass across fixpoint sweeps.
-    Pass names are truncated at the first ``[`` (the parameter brackets
-    carry commas) and pairs are ``;``-joined, so the breakdown stays one
-    CSV-safe column in the comma-separated delta lines."""
+    satellite), from the flight recorder: the ``pass:{name}`` spans
+    emitted since ``mark`` (empty when tracing is off, or when the cell
+    replayed a recorded recipe and ran no pass).  Pass names are truncated
+    at the first ``[`` (the parameter brackets carry commas) and pairs are
+    ``;``-joined, so the breakdown stays one CSV-safe column in the
+    comma-separated delta lines."""
     walls: dict[str, float] = {}
-    order: list[str] = []
-
-    def add(name: str, secs: float) -> None:
-        name = name.split("[", 1)[0]
-        if name not in walls:
-            order.append(name)
-            walls[name] = 0.0
-        walls[name] += secs
-
     if TRACER and mark is not None:
         for rec in TRACER.records_since(mark):
             if rec.get("ph") == "X" and rec["name"].startswith("pass:"):
-                add(rec["name"][len("pass:"):], rec.get("dur", 0) / 1e6)
-    if not walls:
-        for r in records:
-            add(r.name, r.wall_s)
-    return ";".join(f"{n}={walls[n]:.3f}" for n in order)
+                name = rec["name"][len("pass:"):].split("[", 1)[0]
+                walls[name] = walls.get(name, 0.0) + rec.get("dur", 0) / 1e6
+    return ";".join(f"{n}={w:.3f}" for n, w in walls.items())
 
 
 #: (impl, k, c) -> simulated time of the optimized schedule, recorded by
-#: the optimizer tables as they run; ``table_lower_bounds`` (ordered after
-#: them in ``ALL_TABLES``) turns each entry into an LB certificate cell.
+#: the OPT3 table as it runs; ``table_lower_bounds`` (ordered after
+#: it in ``ALL_TABLES``) turns each entry into an LB certificate cell.
 #: A dict so re-running a table in-process overwrites instead of
 #: duplicating.
 _LB_PENDING: dict[tuple, dict] = {}
 
 
-def _note_lb(impl, op, gen_k, c, opt_us, ported):
+def _note_lb(impl, op, gen_k, c, opt_us):
     """Record one optimized alltoall cell for the LB certificate table."""
     if op != "alltoall":
         return
-    _LB_PENDING[(impl, gen_k, c)] = {
-        "op": op, "opt_us": opt_us, "ported": ported,
-    }
+    _LB_PENDING[(impl, gen_k, c)] = {"op": op, "opt_us": opt_us}
 
 
 def table_lower_bounds():
@@ -233,8 +206,7 @@ def table_lower_bounds():
     ROADMAP's "certify the packer" item asks for, without needing a SAT
     solver.
 
-    Each optimizer table (OPT/OPT2/OPT3) notes its alltoall cells as it
-    runs; this table (ordered after them) prices the analytic bound
+    The OPT3 table notes its alltoall cells as it runs; this table (ordered after them) prices the analytic bound
     (:func:`repro.core.analyze.lower_bound` — the ``ceil(log_{k+1} p)``
     round bound and the per-proc/per-node bandwidth bounds, each valid
     for *any* correct schedule under either port model) and emits one
@@ -247,7 +219,7 @@ def table_lower_bounds():
     rows = []
     for (impl, gen_k, c), note in sorted(_LB_PENDING.items()):
         t0 = time.perf_counter()
-        lb = lower_bound(note["op"], M, gen_k, c, ported=note["ported"])
+        lb = lower_bound(note["op"], M, gen_k, c)
         gap = note["opt_us"] / lb["time_us"] if lb["time_us"] > 0 else None
         rows.append({
             "table": "LB",
@@ -265,193 +237,39 @@ def table_lower_bounds():
     return rows
 
 
-def table_optimizer_deltas():
-    """Beyond-paper: the schedule optimizer (``core.passes``) applied to
-    the paper's algorithms at paper scale — round compaction up to port
-    width k plus keep-if-improved message coalescing, every rewrite
-    machine-checked by the ``core.validate`` oracle.  Each cell's
-    ``sim_us`` is the *optimized* time; ``base_us``/``rounds_before`` hold
-    the paper-verbatim schedule for the delta, and ``passes`` carries the
-    per-pass trajectory for ``benchmarks.run --json``."""
-    cases = [
-        # (impl, op, alg, gen_k, payloads) — paper table impls, opt:-ified
-        ("opt:klane_a2a", "alltoall", "klane", 32, [1, 869]),
-        ("opt:kported_a2a", "alltoall", "kported", 6, [1, 869]),
-        ("opt:fulllane_a2a", "alltoall", "fulllane", 6, [1, 869]),
-        ("opt:bruck_a2a", "alltoall", "bruck", 6, [1, 869]),
-        ("opt:klane_bcast", "broadcast", "klane", 2, [10_000]),
-        ("opt:klane_scatter", "scatter", "klane", 2, [869]),
-    ]
-    rows = []
-    for impl, op, alg, gen_k, payloads in cases:
-        for c in payloads:
-            t0 = time.perf_counter()
-            base = compiled_schedule(op, alg, TOPO, gen_k, c)
-            base_us = simulate(base, M).time_us
-            pm = PassManager(
-                [CompactRounds(limit=None), CoalesceMessages()],
-                machine=M,
-                policy="improved",
-                validate=True,
-            )
-            mark = TRACER.mark() if TRACER else None
-            t_opt = time.perf_counter()
-            opt, records = pm.run(base)
-            opt_wall = time.perf_counter() - t_opt
-            opt_us = simulate(opt, M).time_us
-            _note_lb(impl, op, gen_k, c, opt_us, False)
-            rows.append(
-                {
-                    "table": "OPT",
-                    "impl": impl,
-                    "k": gen_k,
-                    "c": c,
-                    "sim_us": opt_us,
-                    "paper_us": PAPER.get((impl[4:], gen_k, c), ""),
-                    "wall_s": time.perf_counter() - t0,
-                    "opt_wall_s": opt_wall,
-                    "pass_walls": _pass_walls(records, mark),
-                    "base_us": base_us,
-                    "rounds_before": base.num_rounds,
-                    "rounds_after": opt.num_rounds,
-                    "passes": [r.as_dict() for r in records],
-                }
-            )
-    return rows
-
-
-def table_optimizer_deltas2():
-    """ISSUE 3: the scheduling-pass suite at paper scale — non-adjacent
-    round reordering (``ReorderRounds`` at the lane budget k and at 2k,
-    the double-buffered non-blocking depth) plus k-lane payload splitting
-    (``SplitPayloads``) and coalescing, fixpoint-iterated under the
-    ``(time, rounds, msgs)`` lexicographic policy with every kept rewrite
-    oracle-checked.
-
-    The alltoall rows run the paper's 1-ported port model (sim default);
-    the broadcast/scatter rows run ``ported=True`` — the k-ported machine
-    is where lane payload splitting pays (a lone sender's port term drops
-    to ``beta*E/k``), which is exactly Träff's decomposition argument.
-    Bruck is omitted: its phases are fully dependency-chained, so every
-    scheduling pass is a proven no-op on it (see the OPT table).
-    """
-    n = TOPO.procs_per_node
-    cases = [
-        # (impl, op, alg, gen_k, payloads, ported-sim)
-        ("opt2:klane_a2a", "alltoall", "klane", 32, [1, 869], False),
-        ("opt2:kported_a2a", "alltoall", "kported", 6, [1, 869], False),
-        ("opt2:fulllane_a2a", "alltoall", "fulllane", 6, [1, 869], False),
-        ("opt2:klane_bcast", "broadcast", "klane", 2, [10_000, 1_000_000], True),
-        ("opt2:fulllane_bcast", "broadcast", "fulllane", 6, [1_000_000], True),
-        ("opt2:klane_scatter", "scatter", "klane", 2, [869], True),
-    ]
-    rows = []
-    for impl, op, alg, gen_k, payloads, ported in cases:
-        for c in payloads:
-            t0 = time.perf_counter()
-            base = compiled_schedule(op, alg, TOPO, gen_k, c)
-            pm = PassManager(
-                [
-                    ReorderRounds(limit=None, procs_per_node=n),
-                    ReorderRounds(limit=2 * base.k, procs_per_node=n),
-                    SplitPayloads(parts=TOPO.k_lanes),
-                    CoalesceMessages(),
-                ],
-                machine=M,
-                ported=ported,
-                policy="lex",
-                validate=True,
-                fixpoint=True,
-            )
-            mark = TRACER.mark() if TRACER else None
-            t_opt = time.perf_counter()
-            opt, records = pm.run(base)
-            opt_wall = time.perf_counter() - t_opt
-            # the lex PassManager already timed both endpoints (bit-exact:
-            # same simulate() under the same machine/port model)
-            base_us = records[0].time_before_us
-            last = records[-1]
-            opt_us = last.time_after_us if last.applied else last.time_before_us
-            _note_lb(impl, op, gen_k, c, opt_us, ported)
-            rows.append(
-                {
-                    "table": "OPT2",
-                    "impl": impl,
-                    "k": gen_k,
-                    "c": c,
-                    "sim_us": opt_us,
-                    "paper_us": PAPER.get((impl[5:], gen_k, c), ""),
-                    "wall_s": time.perf_counter() - t0,
-                    "opt_wall_s": opt_wall,
-                    "pass_walls": _pass_walls(records, mark),
-                    "base_us": base_us,
-                    "rounds_before": base.num_rounds,
-                    "rounds_after": opt.num_rounds,
-                    "ported": ported,
-                    "passes": [r.as_dict() for r in records],
-                }
-            )
-    return rows
-
-
 #: OPT3 cases (ISSUE 5): the paper-scale (p=1152) alltoall families —
-#: klane (the PR 3/4 headline), **fulllane and kported** (newly tractable
-#: at message granularity) — plus the broadcast trees.  Shared with the
+#: klane, fulllane and kported — plus the broadcast trees.  Shared with the
 #: ``--only paper-opt`` CI smoke, which runs exactly one of these cells.
 OPT3_CASES = [
-    # (impl, op, alg, gen_k, payloads, ported-sim)
-    ("opt3:klane_a2a", "alltoall", "klane", 32, [1, 869], False),
-    ("opt3:fulllane_a2a", "alltoall", "fulllane", 6, [1, 869], False),
-    ("opt3:kported_a2a", "alltoall", "kported", 6, [1, 869], False),
-    ("opt3:kported_bcast", "broadcast", "kported", 2, [10_000], True),
-    ("opt3:kported_bcast", "broadcast", "kported", 6,
-     [10_000, 1_000_000], True),
-    ("opt3:klane_bcast", "broadcast", "klane", 2,
-     [10_000, 1_000_000], True),
-    ("opt3:fulllane_bcast", "broadcast", "fulllane", 6, [1_000_000], True),
+    # (impl, op, alg, gen_k, payloads)
+    ("opt3:klane_a2a", "alltoall", "klane", 32, [1, 869]),
+    ("opt3:fulllane_a2a", "alltoall", "fulllane", 6, [1, 869]),
+    ("opt3:kported_a2a", "alltoall", "kported", 6, [1, 869]),
+    ("opt3:kported_bcast", "broadcast", "kported", 2, [10_000]),
+    ("opt3:kported_bcast", "broadcast", "kported", 6, [10_000, 1_000_000]),
+    ("opt3:klane_bcast", "broadcast", "klane", 2, [10_000, 1_000_000]),
+    ("opt3:fulllane_bcast", "broadcast", "fulllane", 6, [1_000_000]),
 ]
 
 
-def _opt3_cell(impl, op, alg, gen_k, c, ported, table="OPT3"):
-    """One OPT3 cell: first-fit baseline + cost-aware splitting, then the
-    coloring packer at the budget rung the cost-aware chooser picks
-    (``ColorRounds(mult=None, machine=...)`` — ISSUE 5: one chooser-priced
-    rung instead of racing the {2k, 4k} ladder), all under the lex policy
-    with every kept rewrite oracle-checked (incrementally where the
-    rewrite window allows).  ``opt_wall_s`` records the optimizer's own
-    wall-clock (the PassManager run only — generation and the surrounding
-    bookkeeping excluded), putting pass-pipeline speed itself on the
-    trajectory; the gate stays on ``sim_us``."""
-    n = TOPO.procs_per_node
+def _opt3_cell(impl, op, alg, gen_k, c, table="OPT3"):
+    """One OPT3 cell: the planner's ``"color"`` pipeline against its
+    unoptimized base, both priced by ``simulate`` on the paper machine in
+    the selector's (1-ported) model — the two candidates the selector
+    races for ``opt:{alg}`` and ``{alg}``.  ``opt_wall_s`` records the
+    optimizer's own wall-clock (the optimized ``compiled_schedule`` call
+    only, the base already cached: a coloring and its oracle check at the
+    first payload of a structure, a recipe replay at the others)."""
     t0 = time.perf_counter()
     base = compiled_schedule(op, alg, TOPO, gen_k, c)
-    pm = PassManager(
-        [
-            ReorderRounds(limit=None, procs_per_node=n),
-            ReorderRounds(limit=2 * base.k, procs_per_node=n),
-            SplitPayloads(machine=M, ported=ported),
-            ColorRounds(
-                limit=None, procs_per_node=n, mult=None,
-                machine=M, ported=ported,
-            ),
-            CoalesceMessages(),
-        ],
-        machine=M,
-        ported=ported,
-        policy="lex",
-        validate=True,
-        fixpoint=True,
-        max_iters=2,
-    )
     mark = TRACER.mark() if TRACER else None
     t_opt = time.perf_counter()
-    opt, records = pm.run(base)
+    opt = compiled_schedule(op, alg, TOPO, gen_k, c, optimize="color")
     opt_wall = time.perf_counter() - t_opt
-    base_us = records[0].time_before_us
-    last = records[-1]
-    opt_us = last.time_after_us if last.applied else last.time_before_us
+    base_us = simulate(base, M).time_us
+    opt_us = simulate(opt, M).time_us
     if table == "OPT3":  # the smoke rerun must not retitle a blessed LB key
-        _note_lb(impl, op, gen_k, c, opt_us, ported)
+        _note_lb(impl, op, gen_k, c, opt_us)
     return {
         "table": table,
         "impl": impl,
@@ -461,42 +279,27 @@ def _opt3_cell(impl, op, alg, gen_k, c, ported, table="OPT3"):
         "paper_us": PAPER.get((impl.split(":", 1)[-1], gen_k, c), ""),
         "wall_s": time.perf_counter() - t0,
         "opt_wall_s": opt_wall,
-        "pass_walls": _pass_walls(records, mark),
+        "pass_walls": _pass_walls(mark),
         "base_us": base_us,
         "rounds_before": base.num_rounds,
         "rounds_after": opt.num_rounds,
-        "ported": ported,
-        "passes": [r.as_dict() for r in records],
     }
 
 
 def table_optimizer_deltas3():
-    """ISSUE 4/5: the conflict-graph coloring packer at paper scale.  Each
-    cell runs the first-fit ``ReorderRounds`` baseline and cost-aware lane
-    splitting (``SplitPayloads(machine=...)`` — per-message factors priced
-    by the simulator's own alpha/beta formulas), then races the
-    ``ColorRounds`` rung picked by the cost-aware budget chooser (ISSUE 5
-    — one priced rung instead of the full {2k, 4k} ladder race, with the
-    tree-aware byte caps active in the bandwidth regime) against that
-    never-slower baseline under the ``(time, rounds, msgs)`` lexicographic
-    policy, every kept rewrite oracle-checked.  Splitting runs *before*
-    the coloring rung on purpose: a colored schedule concentrates sender
-    bytes, so split-then-color reaches strictly better fixpoints on the
-    ported broadcast cells (and the fixpoint sweep retries each pass on
-    the other's output anyway).
+    """The planner's optimizer pipeline at paper scale.  Each cell runs
+    ``compiled_schedule(..., optimize="color")`` — the structural coloring
+    packer (:class:`repro.core.passes.ColorRounds`) recorded once per
+    structure and replayed at the other payload, oracle-checked — and
+    prices it against the unoptimized base.
 
-    Rows (``OPT3_CASES``): the paper-scale alltoall families — klane (the
-    cell PR 3 packed to 288 first-fit rounds and PR 4's ladder to 144; the
-    chooser's deeper rung lands 72), plus **fulllane and kported at
-    p=1152** (ISSUE 5: the ~1.3M-message direct family the per-color
-    packer could not batch) — and the broadcast trees at p=1152.
-    Broadcast rows simulate ``ported=True`` (where lane splitting pays);
-    cells where coloring loses to first-fit record the lex-rejected
-    attempt in ``passes`` — the trajectory shows the race, not just the
-    winner."""
+    Rows (``OPT3_CASES``): the paper-scale alltoall families (klane,
+    fulllane, kported at p=1152) and the broadcast trees.  A cell where
+    the coloring loses to its base (``sim_us > base_us``) is one where the
+    selector's race picks the base."""
     return [
-        _opt3_cell(impl, op, alg, gen_k, c, ported)
-        for impl, op, alg, gen_k, payloads, ported in OPT3_CASES
+        _opt3_cell(impl, op, alg, gen_k, c)
+        for impl, op, alg, gen_k, payloads in OPT3_CASES
         for c in payloads
     ]
 
@@ -510,7 +313,7 @@ def table_paper_opt_smoke():
     heaviest oracle + packer combination."""
     return [
         _opt3_cell(
-            "opt3s:fulllane_a2a", "alltoall", "fulllane", 6, 1, False,
+            "opt3s:fulllane_a2a", "alltoall", "fulllane", 6, 1,
             table="OPT3-SMOKE",
         )
     ]
@@ -580,8 +383,8 @@ def table_degraded():
 
 
 def render_optimizer_deltas(rows) -> list[str]:
-    """Human-readable optimized-vs-paper delta lines for the OPT/OPT2/OPT3
-    cells (plus the CI paper-opt smoke when present).  ``pass_walls`` is
+    """Human-readable optimized-vs-paper delta lines for the OPT3 cells
+    (plus the CI paper-opt smoke when present).  ``pass_walls`` is
     the per-pass wall-time breakdown (ISSUE 7 satellite, flight-recorder
     sourced under ``--deltas``; ``name=secs`` ``;``-joined, last column so
     the lines stay naively comma-splittable) — it replaces the rendered
@@ -592,7 +395,7 @@ def render_optimizer_deltas(rows) -> list[str]:
         "speedup,paper_us,pass_walls"
     ]
     for r in rows:
-        if r.get("table") not in ("OPT", "OPT2", "OPT3", "OPT3-SMOKE"):
+        if r.get("table") not in ("OPT3", "OPT3-SMOKE"):
             continue
         speedup = r["base_us"] / r["sim_us"] if r["sim_us"] else float("inf")
         out.append(
@@ -664,11 +467,9 @@ ALL_TABLES = [
     table_broadcast,
     table_scatter,
     table_alltoall,
-    table_optimizer_deltas,
-    table_optimizer_deltas2,
     table_optimizer_deltas3,
-    # after the optimizer tables: prices the analytic bound for every
-    # optimized alltoall cell they noted (ISSUE 9)
+    # after the optimizer table: prices the analytic bound for every
+    # optimized alltoall cell it noted
     table_lower_bounds,
     table_degraded,
     # LAST two: both clear the process caches (see docstrings)

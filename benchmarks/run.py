@@ -6,10 +6,9 @@ Prints ``name,impl,k,c,sim_us,paper_us`` CSV rows (and roofline rows from
 the dry-run artifacts when present); the paper section ends with the
 ``# optimizer:`` optimized-vs-paper delta lines.  ``--json FILE``
 additionally writes every simulator cell as machine-readable
-``{table, impl, k, c, sim_us, wall_s}`` records — OPT cells (adjacent
-compaction, PR 2) and OPT2 cells (reordering + payload splitting, ISSUE 3)
-carry ``{base_us, rounds_before, rounds_after, ported, passes}``, the
-schedule optimizer's trajectory — so the perf story is tracked across PRs
+``{table, impl, k, c, sim_us, wall_s}`` records — OPT3 cells (the
+planner's ``"color"`` optimizer pipeline) carry ``{base_us, rounds_before,
+rounds_after, opt_wall_s}`` — so the perf story is tracked across PRs
 (``BENCH_schedules.json`` by convention).  ``tools/bench_gate.py``
 compares a fresh ``--json`` dump against the committed baseline and fails
 CI on any >5% ``sim_us`` regression or disappeared cell.
@@ -41,7 +40,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="FILE", default=None,
                     help="write per-cell {table,impl,k,c,sim_us,wall_s} JSON")
     ap.add_argument("--deltas", metavar="FILE", default=None,
-                    help="also write the OPT/OPT2/OPT3 optimized-vs-paper "
+                    help="also write the OPT3 optimized-vs-paper "
                     "delta table to FILE (CI uploads it as an artifact); "
                     "enables the tracer so the per-pass breakdown column "
                     "is flight-recorder sourced")
@@ -135,19 +134,17 @@ def main() -> None:
         print(f"# no simulator cells in this selection; {args.json} not written",
               flush=True)
     elif args.json:
-        # OPT/OPT2/OPT3 cells additionally carry the optimizer trajectory:
-        # the unoptimized baseline, the round delta, the port model the
-        # cell was timed under, the optimizer's own wall-clock
-        # (opt_wall_s — ISSUE 5 satellite; the gate stays on sim_us), and
-        # the per-pass records.
+        # OPT3 cells additionally carry the optimizer trajectory: the
+        # unoptimized baseline, the round delta and the optimizer's own
+        # wall-clock (opt_wall_s; the gate stays on sim_us).
         # DEG cells (ISSUE 6) carry the graceful-degradation context: the
         # healthy-machine time, the natively regenerated fallback where one
         # exists, and the fault fingerprint that keyed the repaired entry.
         # LB cells (ISSUE 9) carry the certificate context: the analytic
         # bound, the optimized time it certifies, and the round bound
         # (sim_us on an LB cell IS gap_vs_lb — the gated ratio).
-        opt_keys = ("base_us", "rounds_before", "rounds_after", "ported",
-                    "opt_wall_s", "passes",
+        opt_keys = ("base_us", "rounds_before", "rounds_after",
+                    "opt_wall_s",
                     "healthy_us", "native_us", "scenario", "fingerprint",
                     "lb_us", "opt_us", "rounds_lb", "gap_vs_lb")
         payload = {

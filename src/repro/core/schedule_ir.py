@@ -24,9 +24,9 @@ The IR is the *compile* stage of the schedule pipeline
     generate (core.schedule) -> compile (here) -> optimize (core.passes)
                              -> validate (core.validate) -> simulate
 
-``compiled_schedule(..., optimize="lane"|"ported")`` hands the cached IR to
-the optimizer's round-compaction pipeline and caches the (oracle-validated)
-rewrite under its own key.
+``compiled_schedule(..., optimize="color")`` hands the cached IR to the
+optimizer's coloring packer and caches the (oracle-validated) rewrite under
+its own key.
 
 Block-metadata ownership rules
 ------------------------------
@@ -37,8 +37,8 @@ legacy ``tuple(sorted(blocks))`` convention).  Block metadata is what makes
 a schedule *checkable*: the array-native validity oracle
 (:mod:`repro.core.validate`) replays data-flow over these arrays with two
 sorts instead of per-message set updates, and the optimizer passes
-(:mod:`repro.core.passes`) consult them to keep round merges causally
-legal.  Rules:
+(:mod:`repro.core.passes`) consult them to keep their re-rounding
+causally legal.  Rules:
 
 * the ``*_ir`` generators always attach blocks (array-natively — no Msg
   objects); ``compile_schedule(..., with_blocks=True)`` flattens legacy
@@ -46,7 +46,7 @@ legal.  Rules:
 * ``compile_schedule`` without ``with_blocks`` still drops the metadata
   (cheapest path when only the cost model is needed); schedules without
   blocks cannot be validated or safely rewritten — ``validate`` and the
-  compaction pass refuse them rather than trust them;
+  coloring pass refuse them rather than trust them;
 * ppermute compilation in ``core.collectives`` remains on the legacy
   ``Msg`` path (it needs per-message python tuples anyway).
 
@@ -84,8 +84,6 @@ __all__ = [
     "compile_schedule",
     "segmented_arange",
     "gather_block_csr",
-    "split_messages",
-    "merge_messages",
     "relay_messages",
     "kported_alltoall_ir",
     "bruck_alltoall_ir",
@@ -321,11 +319,8 @@ def compile_schedule(
 
 
 # ---------------------------------------------------------------------------
-# Message split / merge primitives (array surgery on the CSR block arrays).
-# These are the payload-rewrite building blocks of the optimizer passes:
-# ``SplitPayloads`` splits via :func:`split_messages`, ``CoalesceMessages``
-# fuses via :func:`merge_messages`, and the two are (multiset-)inverses, so
-# the validity oracle sees bit-identical block delivery either way.
+# CSR surgery primitives (array surgery on the block arrays), shared by the
+# optimizer and repair passes.
 # ---------------------------------------------------------------------------
 
 
@@ -333,7 +328,7 @@ def segmented_arange(counts: np.ndarray) -> np.ndarray:
     """``concatenate([arange(c) for c in counts])`` without the Python loop:
     the within-segment offset of every element of a ragged array described
     by per-segment ``counts``.  The CSR-surgery workhorse shared by the
-    block-gather/split primitives here and the optimizer passes."""
+    block-gather/relay primitives here and the optimizer passes."""
     counts = np.asarray(counts, dtype=np.int64)
     total = int(counts.sum())
     return np.arange(total, dtype=np.int64) - np.repeat(
@@ -354,110 +349,6 @@ def gather_block_csr(
     new_ptr = np.zeros(order.size + 1, dtype=np.int64)
     np.cumsum(g_counts, out=new_ptr[1:])
     return new_ptr, blk_ids[base + off]
-
-
-def split_messages(
-    cs: CompiledSchedule, factors: np.ndarray
-) -> CompiledSchedule:
-    """Split message ``i`` into ``factors[i]`` parallel same-round parts.
-
-    Each part keeps the original ``(src, dst)`` and lands in the original
-    round, directly after its siblings; ``elems`` is divided as evenly as
-    possible (every part nonempty — factors are clamped to ``elems``) and
-    the message's block slice is *partitioned* contiguously across the
-    parts (parts beyond the block count carry zero blocks).  Because the
-    parts partition both the payload and the block set, the per-round
-    (src, dst, blk) hop multiset — what the validity oracle replays — is
-    exactly that of the input, and :func:`merge_messages` is an inverse up
-    to message order within a round.
-    """
-    factors = np.asarray(factors, dtype=np.int64)
-    if factors.shape != (cs.num_msgs,):
-        raise ValueError(
-            f"factors must have shape ({cs.num_msgs},), got {factors.shape}"
-        )
-    if cs.num_msgs == 0:
-        return cs
-    f = np.clip(factors, 1, np.maximum(cs.elems, 1))
-    if int(f.max()) <= 1:
-        return cs
-    total = int(f.sum())
-    mid = np.repeat(np.arange(cs.num_msgs, dtype=np.int64), f)
-    part = segmented_arange(f)
-    base, rem = cs.elems // f, cs.elems % f
-    new_elems = base[mid] + (part < rem[mid])
-    new_ptr = np.zeros(cs.num_rounds + 1, dtype=np.int64)
-    np.cumsum(
-        np.bincount(cs.round_ids(), weights=f.astype(np.float64),
-                    minlength=cs.num_rounds).astype(np.int64),
-        out=new_ptr[1:],
-    )
-    blk_ptr = blk_ids = None
-    if cs.has_blocks:
-        nblk = np.diff(cs.blk_ptr)
-        bbase, brem = nblk // f, nblk % f
-        part_counts = bbase[mid] + (part < brem[mid])
-        blk_ptr = np.zeros(total + 1, dtype=np.int64)
-        np.cumsum(part_counts, out=blk_ptr[1:])
-        # contiguous in-order partition: the flat block array is unchanged
-        blk_ids = cs.blk_ids
-    return dataclasses.replace(
-        cs,
-        src=cs.src[mid],
-        dst=cs.dst[mid],
-        elems=new_elems,
-        round_ptr=new_ptr,
-        blk_ptr=blk_ptr,
-        blk_ids=blk_ids,
-        _stats={},
-    )
-
-
-def merge_messages(cs: CompiledSchedule) -> CompiledSchedule:
-    """Fuse same-``(round, src, dst)`` messages into one message with the
-    summed element count and the concatenated (canonically re-sorted) block
-    set.  Returns ``cs`` itself when there is nothing to fuse."""
-    if cs.num_msgs == 0:
-        return cs
-    p = cs.p
-    rid = cs.round_ids()
-    key = (rid * p + cs.src) * p + cs.dst
-    order = np.argsort(key, kind="stable")
-    sk = key[order]
-    first = np.ones(sk.size, dtype=bool)
-    first[1:] = sk[1:] != sk[:-1]
-    starts = np.flatnonzero(first)
-    if starts.size == cs.num_msgs:
-        return cs  # nothing to fuse
-    new_src = cs.src[order][starts]
-    new_dst = cs.dst[order][starts]
-    new_rid = rid[order][starts]
-    new_elems = np.add.reduceat(cs.elems[order], starts)
-    new_ptr = np.zeros(cs.num_rounds + 1, dtype=np.int64)
-    np.cumsum(
-        np.bincount(new_rid, minlength=cs.num_rounds), out=new_ptr[1:]
-    )
-    blk_ptr = blk_ids = None
-    if cs.has_blocks:
-        gptr, flat = gather_block_csr(cs.blk_ptr, cs.blk_ids, order)
-        fused_counts = np.add.reduceat(np.diff(gptr), starts)
-        seg_id = np.repeat(
-            np.arange(fused_counts.size, dtype=np.int64), fused_counts
-        )
-        flat = flat[np.lexsort((flat, seg_id))]  # canonical: ascending/msg
-        blk_ptr = np.zeros(fused_counts.size + 1, dtype=np.int64)
-        np.cumsum(fused_counts, out=blk_ptr[1:])
-        blk_ids = flat
-    return dataclasses.replace(
-        cs,
-        src=new_src,
-        dst=new_dst,
-        elems=new_elems,
-        round_ptr=new_ptr,
-        blk_ptr=blk_ptr,
-        blk_ids=blk_ids,
-        _stats={},
-    )
 
 
 def relay_messages(
@@ -775,23 +666,23 @@ IR_GENERATORS: dict[tuple[str, str], Callable] = {
 # ---------------------------------------------------------------------------
 # Process-wide schedule cache (thread-safe; optimized entries fingerprinted).
 #
-# ISSUE 5: optimized schedules are keyed on ``(op, algorithm, topo, k, c,
-# root, opt_mode, pipeline_fingerprint)`` — the fingerprint
-# (:func:`repro.core.passes.pipeline_fingerprint`) hashes the pass names +
-# a version salt, so changing a pipeline's composition or semantics
-# invalidates exactly the entries it produced.  On top of the per-``c``
-# entries sits a **recipe cache**: a pipeline whose passes are all
-# ``recipe_safe`` (payload-independent message permutations / re-roundings
-# — reorder, color without a machine, compaction) produces the *same*
-# rewrite at every payload size, so the pipeline runs once on a
-# tagged-payload copy (``elems = arange(M)`` — the output's elems array IS
-# the permutation) and every other payload replays the recorded
-# ``(morder, round_ptr)`` with one gather.  That is what stops the
-# selector's ``opt:`` candidates from re-running the whole pass pipeline
-# on every ``crossover_table`` probe: probes differ only in ``c``.  The
-# first recipe application is still oracle-validated; replays at other
-# payloads are not re-checked — block structure and round assignment are
-# identical and the oracle never reads ``elems``.
+# Entries are keyed on ``(op, algorithm, topo, k, c, root, optimize,
+# pipeline_fingerprint, fault_fingerprint)``.  ``optimize`` is None (the
+# generator's output) or ``"color"`` (the planner's one optimizer
+# pipeline); the fingerprint (:func:`repro.core.passes.pipeline_fingerprint`)
+# hashes the pass names + a version salt, so a change to the pipeline's
+# composition or semantics invalidates exactly the entries it produced.
+# On top of the per-``c`` entries sits a **recipe cache**: the color
+# pipeline is payload-independent (a message permutation plus a
+# re-rounding), so it runs once per structure on a tagged-payload copy
+# (``elems = arange(M)`` — the output's elems array IS the permutation)
+# and every other payload replays the recorded ``(morder, round_ptr)``
+# with one gather.  That is what keeps the pass off the plan request path:
+# requests and ``crossover_table`` probes differ only in ``c``.  The first
+# recipe application is oracle-validated; replays at other payloads are
+# not re-checked — block structure and round assignment are identical and
+# the oracle never reads ``elems``.  Repairs (faulted keys) are never
+# recipes: they duplicate payloads across relay hops.
 # ---------------------------------------------------------------------------
 
 _LOCK = threading.RLock()
@@ -876,21 +767,15 @@ def compiled_schedule(
     (the ISSUE 6 cache-invalidation rule: a tuned schedule cached for a
     healthy topology is silently wrong the moment the topology degrades).
 
-    ``optimize`` selects an optimizer pipeline from
-    :data:`repro.core.passes.OPT_MODES` (``"lane"`` keeps strict
-    lane-legality, ``"ported"`` compacts adjacent rounds up to port width k,
-    ``"reorder"`` list-schedules messages into the earliest dependency- and
-    budget-legal round regardless of adjacency, ``"split"`` splits payloads
-    across the k lanes, ``"color"`` runs the conflict-graph coloring packer
-    at the auto-chosen budget); the optimized schedule is validated by the
-    array-native oracle before it enters the cache.  Optimized entries are
-    keyed on the pass pipeline's fingerprint as well, and pipelines whose
-    passes are all payload-independent (``recipe_safe``) run once per
-    structure and replay as a recorded permutation recipe at every other
-    payload size — see the cache notes above.  Split factors clamp to
-    ``elems``, so optimized entries are piecewise-affine in ``c`` — the
-    selector's piecewise fits (``selector.piecewise_cost``) handle any
-    regime flip the rewrites cause.
+    ``optimize`` is None (the generator's output) or ``"color"``: the
+    conflict-graph coloring packer (:class:`repro.core.passes.ColorRounds`)
+    at the structural budget rung, the rewrite behind every ``opt:``
+    candidate; any other value raises ``ValueError``.  The packer is
+    payload-independent, so it runs once per structure and replays as a
+    recorded permutation recipe at every other payload size, and its first
+    application is validated by the array-native oracle before it enters
+    the cache — see the cache notes above.  Optimized entries are keyed on
+    the pipeline's fingerprint as well.
     """
     global _CACHE_HITS, _CACHE_MISSES, _RECIPE_HITS, _RECIPE_MISSES
     global _STORE_RECOMPILES
@@ -921,16 +806,9 @@ def compiled_schedule(
     fingerprint = None
     passes = None
     if optimize is not None:
-        from repro.core.passes import OPT_MODES, pipeline_fingerprint
+        from repro.core.passes import optimize_pipeline, pipeline_fingerprint
 
-        try:
-            factory = OPT_MODES[optimize]
-        except KeyError:
-            raise ValueError(
-                f"unknown optimize mode {optimize!r}; expected one of "
-                f"{sorted(OPT_MODES)}"
-            ) from None
-        passes = factory(topo)
+        passes = optimize_pipeline(optimize, topo)
         fingerprint = pipeline_fingerprint(passes)
     fault_fp = None
     if faults is not None and not faults.is_healthy:
@@ -1007,12 +885,7 @@ def _build_entry(op, algorithm, topo, k, c, root, *, optimize, faults,
         return cs, "repair"
     if optimize is not None:
         base = compiled_schedule(op, algorithm, topo, k, c, root)
-        if all(getattr(ps, "recipe_safe", False) for ps in passes):
-            return _optimize_via_recipe(base, key[:6] + key[7:], passes), "recipe"
-        from repro.core.passes import optimize_schedule
-
-        cs, _ = optimize_schedule(base, optimize, topo=topo, validate=True)
-        return cs, "optimize"
+        return _optimize_via_recipe(base, key[:6] + key[7:], passes), "recipe"
     gen = IR_GENERATORS.get((op, algorithm))
     if gen is not None:
         return gen(topo, k, c), "generate"
@@ -1024,16 +897,16 @@ def _optimize_via_recipe(
     base: CompiledSchedule, recipe_key: tuple, passes: list
 ) -> CompiledSchedule:
     """Optimize ``base`` through a payload-independent pipeline, running the
-    passes at most once per structure: the pipeline is replayed on a
+    passes at most once per structure: the pipeline is run on a
     tagged-payload copy whose ``elems`` are the message indices, so the
     output's ``elems`` array *is* the message permutation; every subsequent
     payload size applies the recorded ``(morder, round_ptr)`` with one
     gather.  The first materialized application is machine-checked by the
-    validity oracle (raising on corruption, exactly like the non-recipe
-    path); replays at other payloads reuse that verdict — the oracle never
-    reads ``elems`` and the block structure is identical by construction."""
+    validity oracle (raising on corruption); replays at other payloads
+    reuse that verdict — the oracle never reads ``elems`` and the block
+    structure is identical by construction."""
     global _RECIPE_HITS, _RECIPE_MISSES
-    from repro.core.passes import PassManager
+    from repro.core.passes import run_passes
     from repro.core.validate import validate_schedule
 
     # counter updates stay inside _LOCK: plain += on module globals is a
@@ -1054,7 +927,7 @@ def _optimize_via_recipe(
             elems=np.arange(base.num_msgs, dtype=np.int64),
             _stats={},
         )
-        out, _ = PassManager(passes).run(tagged)
+        out, _ = run_passes(tagged, passes)
         rec = (
             {"identity": True, "validated": True}
             if out is tagged

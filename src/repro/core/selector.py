@@ -30,12 +30,11 @@ Hot-path design (the serving/training loop calls this online):
   conflict-graph coloring packer, validated by the ``core.validate``
   oracle) — so the table reflects what a tuned library could actually run,
   not just the paper's verbatim schedules.  The coloring packer is not
-  provably never-slower (unlike the PR 3 first-fit it replaces here), but
-  the base family is always in the same race, so a losing rewrite ranks
-  behind rather than ships; it *can* change which cost term dominates
-  mid-sweep (packed rounds trade alphas against serialized port bytes),
-  and payload splitting clamps its factors to ``c`` — so ``opt:``
-  candidates are only *piecewise* affine in ``c``.
+  provably never-slower, but the base family is always in the same race,
+  so a losing rewrite ranks behind rather than ships; it *can* change
+  which cost term dominates mid-sweep (packed rounds trade alphas against
+  serialized port bytes) — so ``opt:`` candidates are only *piecewise*
+  affine in ``c``.
   ``piecewise_cost`` therefore fits **3 probes** (endpoints + geometric
   midpoint) into two affine segments; families that regime-flip mid-sweep
   select correctly where a single 2-probe fit would misrank the interior.
@@ -185,7 +184,7 @@ def _machine_for(num_nodes: int, procs_per_node: int, k_lanes: int) -> Machine:
 
 def _candidate_algs(op: str, topo: Topology) -> list[str]:
     """Base families plus their ``opt:``-prefixed rewrites (the schedule
-    optimizer's round-compacted variants, which can flip the paper's
+    optimizer's color-packed variants, which can flip the paper's
     crossover points in the latency regime)."""
     from repro.core.schedule import ALGORITHMS
 
@@ -202,11 +201,10 @@ def _candidate_algs(op: str, topo: Topology) -> list[str]:
 
 def _parse_alg(alg: str) -> tuple[str, str | None]:
     """``"opt:klane"`` -> ``("klane", "color")``; plain names pass through.
-    ``"color"`` (the ISSUE 4 conflict-graph coloring packer) supersedes the
-    PR 3 ``"reorder"`` first-fit as the opt: pipeline.  Unlike reorder it
-    is not provably never slower — but the selector *races* every opt:
-    candidate against its unoptimized base, so a cell where eager coloring
-    loses (bandwidth-bound trees) simply ranks behind the base instead of
+    ``"color"`` is the conflict-graph coloring packer.  It is not provably
+    never slower — but the selector *races* every opt: candidate against
+    its unoptimized base, so a cell where eager coloring loses
+    (bandwidth-bound trees) simply ranks behind the base instead of
     shipping."""
     if alg.startswith("opt:"):
         return alg[4:], "color"
@@ -628,7 +626,7 @@ def piecewise_cost(
     (``A1 + B1*c``) covers ``c <= c_mid``, segment 2 the rest.  Exact at
     all three probes, so the two-segment fit catches a family whose
     dominating cost term flips somewhere inside the sweep — the ``opt:``
-    rewrites and payload splitting do exactly that — where the 2-probe
+    rewrites do exactly that — where the 2-probe
     affine fit would silently misprice the whole interior.
 
     **Adaptive probe placement** (ISSUE 5 satellite): when the two

@@ -64,17 +64,11 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# Costing hooks: THE per-round cost formulas, shared between the simulator
-# and the cost-aware optimizer passes (ISSUE 4/5).  ``repro.core.passes``
-# evaluates ``port_time`` to price a rewrite (per-message split factors
-# from the alpha/beta trade-off per traffic class) with exactly the
-# arithmetic the simulator will charge — no second, drifting copy of the
-# model.  ``lane_time`` is consumed on the same terms by the ISSUE 5
-# budget chooser (``passes.choose_color_budget``): its packed-time proxy
-# prices each coloring rung's node rail term with this exact formula, so
-# the rung it picks is the rung the lex race would have kept.
-# Every expression is written operation-for-operation like the per-``Msg``
-# reference so the floats stay bit-exact.
+# Costing hooks: THE per-round cost formulas, shared by the IR simulator
+# and its payload-scaled variant (a ``repro.core.faults`` degraded machine
+# prices through them too) — one copy of the model.  Every expression is
+# written operation-for-operation like the per-``Msg`` reference so the
+# floats stay bit-exact.
 # ---------------------------------------------------------------------------
 
 

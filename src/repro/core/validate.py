@@ -38,8 +38,8 @@ Besides the pass/fail oracle this module also *exports* the data-flow
 structure it computes along the way: :func:`block_dependencies` turns the
 block-hop events into a message-level dependency DAG (message -> the
 earliest messages that deliver the blocks it forwards), which is what the
-``ReorderRounds`` list scheduler in :mod:`repro.core.passes` consumes to
-re-pack messages into earlier rounds without breaking causality.
+``ColorRounds`` packer in :mod:`repro.core.passes` consumes to re-pack
+messages into other rounds without breaking causality.
 """
 
 from __future__ import annotations
@@ -56,9 +56,6 @@ __all__ = [
     "validate_schedule",
     "check_schedule",
     "block_dependencies",
-    "rewrite_window",
-    "window_hop_fraction",
-    "revalidate_schedule",
 ]
 
 
@@ -140,9 +137,9 @@ def block_dependencies(
     block) is sound for earliest-round packing — providers are processed
     first in original round order — and keeps the graph O(hops).
 
-    **Zero-block messages** (ISSUE 4): ``schedule_ir.split_messages`` with a
-    factor above the block count emits parts that carry payload bytes but
-    *no* block ids — their bytes belong to a block attributed to a
+    **Zero-block messages**: a payload split into parallel parts
+    beyond its block count leaves parts that carry payload bytes but *no*
+    block ids — their bytes belong to a block attributed to a
     co-``(round, src, dst)`` sibling, so they have no block-hop events and,
     naively, no causality edges.  A message-granularity packer would be free
     to hoist such a part ahead of its payload's producer (or strand it
@@ -152,10 +149,9 @@ def block_dependencies(
     the dependency set of its block-carrying co-``(round, src, dst)``
     siblings, and every consumer of a block additionally depends on the
     zero-block siblings of its provider (a block is usable only strictly
-    after *all* parts of the delivering payload).  Round-granularity passes
-    (``ReorderRounds``/``CompactRounds``) never separate co-round siblings,
-    so they are safe either way; the lift is what makes message-granularity
-    packing (``ColorRounds``) sound on split schedules.
+    after *all* parts of the delivering payload).  The lift is what makes
+    message-granularity packing (``ColorRounds``) sound on such
+    schedules.
 
     Raises ``ValueError`` if the schedule has no block metadata and
     ``AssertionError`` if some requirement has no provider at all (the
@@ -261,15 +257,6 @@ def block_dependencies(
     return dep_ptr, dep_ids
 
 
-def _membership(sorted_vals: np.ndarray, x: np.ndarray) -> np.ndarray:
-    """Boolean mask: which entries of ``x`` appear in the sorted unique
-    array ``sorted_vals`` (vectorized searchsorted membership test)."""
-    if sorted_vals.size == 0:
-        return np.zeros(x.shape, dtype=bool)
-    idx = np.minimum(np.searchsorted(sorted_vals, x), sorted_vals.size - 1)
-    return sorted_vals[idx] == x
-
-
 def validate_schedule(
     cs: CompiledSchedule, *, raise_on_error: bool = False
 ) -> ValidationReport:
@@ -278,7 +265,7 @@ def validate_schedule(
     Requires block metadata on the IR (``cs.has_blocks``); schedules
     compiled without blocks cannot be validated and raise ``ValueError``.
     """
-    report = _validate(cs, None)
+    report = _validate(cs)
     if raise_on_error:
         report.raise_if_invalid()
     return report
@@ -294,12 +281,8 @@ def check_schedule(
     return validate_schedule(cs, raise_on_error=raise_on_error)
 
 
-def _validate(
-    cs: CompiledSchedule, affected: np.ndarray | None
-) -> ValidationReport:
-    """The oracle, optionally restricted to the hop chains of the sorted
-    unique block ids in ``affected`` (the incremental path — see
-    :func:`revalidate_schedule` for the soundness argument)."""
+def _validate(cs: CompiledSchedule) -> ValidationReport:
+    """The oracle behind :func:`validate_schedule`."""
     if not cs.has_blocks:
         raise ValueError(
             "schedule carries no block metadata; regenerate with "
@@ -307,9 +290,6 @@ def _validate(
         )
     p = cs.p
     rid, src, dst, blk = _events(cs)
-    if affected is not None:
-        keep = _membership(affected, blk)
-        rid, src, dst, blk = rid[keep], src[keep], dst[keep], blk[keep]
     hops = int(blk.size)
 
     if hops:
@@ -366,9 +346,6 @@ def _validate(
         owners, need = b, a * p + b
     else:  # pragma: no cover - initial_holds already rejects
         raise ValueError(f"unknown op {cs.op!r}")
-    if affected is not None:
-        fkeep = _membership(affected, need)
-        owners, need = owners[fkeep], need[fkeep]
     fin0 = initial_holds(cs.op, p, owners, need)
     if uniq_keys.size:
         in_span = (need >= bmin) & (need < bmin + bspan)
@@ -397,153 +374,3 @@ def _validate(
         missing_final=missing,
         first_missing=first_missing,
     )
-
-
-# ---------------------------------------------------------------------------
-# Incremental revalidation (ISSUE 5 tentpole): a rewrite that only touches a
-# round window needs only its affected blocks' hop chains rechecked.
-# ---------------------------------------------------------------------------
-
-
-def rewrite_window(
-    prev: CompiledSchedule, new: CompiledSchedule
-) -> tuple[int, int, int] | None:
-    """Minimal differing round window between two schedules, as a half-open
-    triple ``(a, b_prev, b_new)``: rounds ``[0, a)`` are identical in both,
-    rounds ``[b_prev, R_prev)`` of ``prev`` equal rounds ``[b_new, R_new)``
-    of ``new`` round-for-round, and every difference lives in
-    ``prev[a:b_prev]`` vs ``new[a:b_new]``.
-
-    "Identical" is in the oracle's terms — same per-round ``(src, dst,
-    blocks)`` message sequences (``elems`` is ignored: data-flow validity
-    does not depend on payload sizes).  Identical schedules yield an empty
-    window (``a == b_prev == b_new``).  Returns ``None`` when the two
-    schedules are not diffable (different op/p, or missing block
-    metadata) — callers must fall back to a full oracle run.
-
-    Cost: O(M + hops) array comparisons, no sorting.
-    """
-    if (
-        prev.op != new.op
-        or prev.p != new.p
-        or not (prev.has_blocks and new.has_blocks)
-    ):
-        return None
-    Rp, Rn = prev.num_rounds, new.num_rounds
-    Mp, Mn = prev.num_msgs, new.num_msgs
-    nb_p, nb_n = np.diff(prev.blk_ptr), np.diff(new.blk_ptr)
-
-    # --- longest common message prefix (src, dst, block slice) ------------
-    L = min(Mp, Mn)
-    diff = (
-        (prev.src[:L] != new.src[:L])
-        | (prev.dst[:L] != new.dst[:L])
-        | (nb_p[:L] != nb_n[:L])
-    )
-    m0 = int(np.argmax(diff)) if bool(diff.any()) else L
-    Lb = min(prev.blk_ids.size, new.blk_ids.size)
-    bdiff = prev.blk_ids[:Lb] != new.blk_ids[:Lb]
-    b0 = int(np.argmax(bdiff)) if bool(bdiff.any()) else Lb
-    # the first message whose block slice reaches past the common block
-    # prefix caps the message prefix (counts agree up to m0, so prev's
-    # blk_ptr is the shared offset table there)
-    m0 = min(m0, int(np.searchsorted(prev.blk_ptr, b0, side="right")) - 1)
-
-    # --- longest common message suffix ------------------------------------
-    diff_s = (
-        (prev.src[Mp - L:][::-1] != new.src[Mn - L:][::-1])
-        | (prev.dst[Mp - L:][::-1] != new.dst[Mn - L:][::-1])
-        | (nb_p[Mp - L:][::-1] != nb_n[Mn - L:][::-1])
-    )
-    t = int(np.argmax(diff_s)) if bool(diff_s.any()) else L
-    bdiff_s = prev.blk_ids[prev.blk_ids.size - Lb:][::-1] != new.blk_ids[
-        new.blk_ids.size - Lb:
-    ][::-1]
-    bt = int(np.argmax(bdiff_s)) if bool(bdiff_s.any()) else Lb
-    if t:
-        tail_cum = np.cumsum(nb_p[::-1][:t])
-        t = int(np.searchsorted(tail_cum, bt, side="right"))
-
-    # --- round-align the prefix -------------------------------------------
-    Rm = min(Rp, Rn)
-    pref_ok = (
-        prev.round_ptr[: Rm + 1] == new.round_ptr[: Rm + 1]
-    ) & (new.round_ptr[: Rm + 1] <= m0)
-    a = (int(np.argmin(pref_ok)) if not bool(pref_ok.all()) else Rm + 1) - 1
-
-    # --- round-align the suffix -------------------------------------------
-    suf_ok = (
-        prev.round_ptr[Rp - Rm:][::-1] - Mp
-        == new.round_ptr[Rn - Rm:][::-1] - Mn
-    ) & (Mp - prev.round_ptr[Rp - Rm:][::-1] <= t)
-    rs = (int(np.argmin(suf_ok)) if not bool(suf_ok.all()) else Rm + 1) - 1
-    rs = min(rs, Rp - a, Rn - a)
-    return a, Rp - rs, Rn - rs
-
-
-def window_hop_fraction(
-    prev: CompiledSchedule,
-    new: CompiledSchedule,
-    window: tuple[int, int, int],
-) -> float:
-    """Fraction of the two schedules' block-hop events that fall inside a
-    :func:`rewrite_window` — the cheap proxy callers use to decide between
-    the incremental and the full oracle."""
-    a, bp, bn = window
-    hp = int(
-        prev.blk_ptr[prev.round_ptr[bp]] - prev.blk_ptr[prev.round_ptr[a]]
-    )
-    hn = int(new.blk_ptr[new.round_ptr[bn]] - new.blk_ptr[new.round_ptr[a]])
-    total = int(prev.blk_ids.size + new.blk_ids.size)
-    return (hp + hn) / total if total else 0.0
-
-
-def revalidate_schedule(
-    new: CompiledSchedule,
-    *,
-    prev: CompiledSchedule,
-    window: tuple[int, int, int] | None = None,
-    raise_on_error: bool = False,
-) -> ValidationReport:
-    """Incrementally validate ``new``, given that ``prev`` is oracle-valid
-    and differs from ``new`` only inside ``window`` (computed via
-    :func:`rewrite_window` when not supplied).
-
-    Only the hop chains of the *affected blocks* — blocks with at least one
-    hop inside either schedule's window — are rechecked, against the whole
-    of ``new`` (an affected block's earliest acquisition may sit outside
-    the window).  Soundness of skipping the rest: an unaffected block's
-    hops all live in the common prefix/suffix, where round ids are
-    unchanged (prefix) or uniformly shifted (suffix), so the strict
-    earliest-acquisition-before-requirement order the full oracle checks is
-    preserved verbatim from ``prev``; its final delivery likewise.  The
-    verdict therefore equals the full oracle's whenever the precondition
-    holds (``prev`` valid + window-confined rewrite) — pinned by the
-    incremental ≡ full property test.  The report's violation/hop counts
-    cover the checked subset only.
-
-    Falls back to the full oracle when the schedules are not diffable.
-    """
-    if window is None:
-        window = rewrite_window(prev, new)
-    if window is None:
-        return validate_schedule(new, raise_on_error=raise_on_error)
-    a, bp, bn = window
-    affected = np.unique(
-        np.concatenate(
-            [
-                prev.blk_ids[
-                    prev.blk_ptr[prev.round_ptr[a]]:
-                    prev.blk_ptr[prev.round_ptr[bp]]
-                ],
-                new.blk_ids[
-                    new.blk_ptr[new.round_ptr[a]]:
-                    new.blk_ptr[new.round_ptr[bn]]
-                ],
-            ]
-        )
-    )
-    report = _validate(new, affected)
-    if raise_on_error:
-        report.raise_if_invalid()
-    return report

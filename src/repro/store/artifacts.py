@@ -19,8 +19,8 @@ store can never hold two copies (or a torn half) of an artifact.  The
 ``c-regime`` directory level (latency/mixed/bandwidth, from the payload)
 groups entries the way the selector's piecewise fits reason about them.
 
-Recipe artifacts hold the ``(morder, round_ptr)`` permutation a
-``recipe_safe`` pipeline recorded — payload-independent, so one recipe
+Recipe artifacts hold the ``(morder, round_ptr)`` permutation the
+``"color"`` pipeline recorded — payload-independent, so one recipe
 replays at every payload size; their key is the schedule key minus ``c``.
 
 **Versioning and eviction.**  Every artifact header records the store
@@ -37,8 +37,9 @@ silently wrong to serve; disk is the wrong place to keep it.
 persist under their fault fingerprint — part of the key, hence the file
 name — and warm-start back under the same faulted key.  They are never
 read back as healthy entries, because the healthy key hashes to a
-different file.  Recipes never exist for repairs (repair is not
-``recipe_safe``), so no recipe can smuggle a degraded rewrite either.
+different file.  Recipes never exist for repairs (a repair duplicates
+payloads across relay hops, so it is never recorded as one), so no recipe
+can smuggle a degraded rewrite either.
 
 **Resilience (ISSUE 10).**  On a shared filesystem another process may
 evict, re-publish, or bound the store underneath a reader, so every

@@ -1,9 +1,8 @@
 """ISSUE 4: the conflict-graph coloring round packer (``ColorRounds``),
-cost-aware k-lane payload splitting (``SplitPayloads(machine=...)``), the
-zero-block split-part causality lift in ``validate.block_dependencies``,
-the shared simulator costing hooks, and the ``merge(split(...))``
-round-trip property on all four alltoall families and both machine
-models."""
+the zero-block split-part causality lift in
+``validate.block_dependencies``, the shared simulator costing hooks, and
+the recipe replay of the ``"color"`` pipeline on the plan cell's
+meshes."""
 
 import dataclasses
 
@@ -13,27 +12,12 @@ import pytest
 from repro.core import schedule as S
 from repro.core import schedule_ir as IR
 from repro.core import selector
-from repro.core.passes import (
-    ColorRounds,
-    PassManager,
-    ReorderRounds,
-    SplitPayloads,
-    optimize_schedule,
-)
+from repro.core.passes import ColorRounds
 from repro.core.simulate import lane_time, port_time, simulate
-from repro.core.topology import (
-    Machine,
-    Topology,
-    hydra_machine,
-    nvlink_ib_machine,
-)
+from repro.core.topology import Topology, hydra_machine
 from repro.core.validate import block_dependencies, validate_schedule
 
 HYDRA = hydra_machine()
-
-
-def _machine(topo: Topology) -> Machine:
-    return Machine(topo=topo, cost=HYDRA.cost)
 
 
 # ---------------------------------------------------------------------------
@@ -52,7 +36,8 @@ def test_color_requires_blocks_and_divisible_nodes():
 
 def test_color_identity_when_input_already_packed():
     """A schedule the coloring reproduces exactly comes back as the same
-    object (so PassManager records it as not-applied)."""
+    object (so run_passes records it as not-applied and the recipe cache
+    stores an identity recipe)."""
     cs = IR.kported_alltoall_ir(8, 2, 3)  # ceil(7/2)=4 saturated rounds
     assert ColorRounds(limit=2, procs_per_node=4).apply(cs) is cs
 
@@ -64,7 +49,7 @@ def test_color_respects_dependency_chains():
     phase count — no more, no less."""
     cs = IR.bruck_alltoall_ir(27, 2, 5)
     nonempty = int((np.diff(cs.round_ptr) > 0).sum())
-    col = ColorRounds(limit=None, procs_per_node=9, mult=4).apply(cs)
+    col = ColorRounds(limit=4 * cs.k, procs_per_node=9).apply(cs)
     assert col.num_rounds == nonempty
     assert validate_schedule(col).ok
 
@@ -78,60 +63,57 @@ def test_color_budget_ladder_on_klane_alltoall():
     N, n = 4, 6
     for mult in (1, 2, 4):
         L = mult * cs.k
-        col = ColorRounds(limit=None, procs_per_node=n, mult=mult).apply(cs)
+        col = ColorRounds(limit=L, procs_per_node=n).apply(cs)
         assert col.num_rounds == -(-(N - 1) * n // L) + -(-(n - 1) // L)
         assert validate_schedule(col).ok
         assert col.total_elems() == cs.total_elems()
 
 
 def test_color_splits_rounds_first_fit_cannot():
-    """Message granularity: the broadcast tree's sender-side waves pack
-    below what whole-round first-fit reaches (the k-lane broadcast at the
-    paper topology: first-fit stops at 23 rounds, coloring reaches <= 12)."""
+    """Message granularity: the coloring splits input rounds apart — some
+    input round's messages land in different colors, which no packer that
+    moves whole rounds can do — and so packs the k-lane broadcast at the
+    paper topology below the 23 rounds whole-round first-fit stopped at
+    (coloring reaches <= 12), faster than the base in the k-ported model."""
     topo = Topology(36, 32, 2)
     base = IR.compiled_schedule("broadcast", "klane", topo, 2, 10_000)
-    ff = ReorderRounds(limit=None, procs_per_node=32).apply(base)
-    ff = ReorderRounds(limit=2 * base.k, procs_per_node=32).apply(ff)
-    col = ColorRounds(limit=None, procs_per_node=32, mult=4).apply(base)
-    assert col.num_rounds < ff.num_rounds < base.num_rounds
+    col = ColorRounds(limit=4 * base.k, procs_per_node=32).apply(base)
+    assert col.num_rounds <= 12 < 23 < base.num_rounds
     assert validate_schedule(col).ok
+    # the packer's message permutation, read off a tagged-payload copy
+    tagged = dataclasses.replace(
+        base, elems=np.arange(base.num_msgs, dtype=np.int64), _stats={}
+    )
+    morder = ColorRounds(limit=4 * base.k, procs_per_node=32).apply(
+        tagged
+    ).elems
+    in_round = base.round_ids()[morder]
+    out_round = col.round_ids()
+    colors_per_input_round = [
+        np.unique(out_round[in_round == r]).size
+        for r in range(base.num_rounds)
+    ]
+    assert max(colors_per_input_round) > 1
     assert (
         simulate(col, HYDRA, ported=True).time_us
-        < simulate(ff, HYDRA, ported=True).time_us
+        < simulate(base, HYDRA, ported=True).time_us
     )
 
 
 @pytest.mark.parametrize("op_alg", sorted(S.ALGORITHMS))
 def test_color_valid_and_lex_raced_never_worse(op_alg):
     """ColorRounds is not provably never-slower, so the contract is: every
-    coloring is oracle-valid and volume-preserving, and under the lex
-    policy (raced against the first-fit baseline) the pipeline result is
-    never slower than the input on either port model."""
+    coloring — the port-budget rung the repair uses and the structural
+    rung the planner uses — is oracle-valid and volume-preserving (the
+    selector races it against its base for speed)."""
     op, alg = op_alg
     topo = Topology(3, 4, 2)
-    machine = _machine(topo)
     cs = IR.compiled_schedule(op, alg, topo, 2, 13)
-    for mult in (1, 4):
-        col = ColorRounds(limit=None, procs_per_node=4, mult=mult).apply(cs)
+    for limit in (cs.k, None):
+        col = ColorRounds(limit=limit, procs_per_node=4).apply(cs)
         assert validate_schedule(col).ok
         assert col.total_elems() == cs.total_elems()
-    for ported in (False, True):
-        pm = PassManager(
-            [
-                ReorderRounds(limit=None, procs_per_node=4),
-                ColorRounds(limit=None, procs_per_node=4, mult=4),
-            ],
-            machine=machine,
-            ported=ported,
-            policy="lex",
-            validate=True,
-        )
-        opt, _ = pm.run(cs)
-        assert validate_schedule(opt).ok
-        assert (
-            simulate(opt, machine, ported=ported).time_us
-            <= simulate(cs, machine, ported=ported).time_us + 1e-9
-        )
+        assert col.num_msgs == cs.num_msgs
 
 
 def test_color_headline_klane_alltoall_paper_scale():
@@ -140,16 +122,11 @@ def test_color_headline_klane_alltoall_paper_scale():
     (target <= 260) with >= 4.2x simulated at c=1, oracle-valid."""
     topo = Topology(36, 32, 2)
     base = IR.klane_alltoall_ir(topo, 1)
-    ff = ReorderRounds(limit=None, procs_per_node=32).apply(base)
-    ff = ReorderRounds(limit=2 * base.k, procs_per_node=32).apply(ff)
-    assert ff.num_rounds == 288  # PR 3's first-fit plateau
-    col = ColorRounds(limit=None, procs_per_node=32, mult=4).apply(base)
-    assert col.num_rounds < ff.num_rounds
-    assert col.num_rounds <= 260
+    col = ColorRounds(limit=4 * base.k, procs_per_node=32).apply(base)
+    assert col.num_rounds <= 260 < 288  # whole-round first-fit's plateau
     base_us = simulate(base, HYDRA).time_us
     col_us = simulate(col, HYDRA).time_us
     assert base_us / col_us >= 4.2
-    assert col_us < simulate(ff, HYDRA).time_us
     assert validate_schedule(col).ok
     assert col.total_elems() == base.total_elems()
 
@@ -164,8 +141,10 @@ def test_optimize_mode_color_via_cache_and_selector_parse():
         is opt
     )
     assert selector._parse_alg("opt:klane") == ("klane", "color")
-    with pytest.raises(ValueError, match="topology"):
-        optimize_schedule(base, "color")  # mode needs topo= or machine=
+    assert selector._parse_alg("klane") == ("klane", None)
+    with pytest.raises(ValueError, match="unknown optimize mode"):
+        IR.compiled_schedule("alltoall", "klane", topo, 2, 7,
+                             optimize="reorder")
 
 
 # ---------------------------------------------------------------------------
@@ -175,21 +154,21 @@ def test_optimize_mode_color_via_cache_and_selector_parse():
 
 def _forward_chain_split():
     """p=6 alltoall fragment: 0 -> 1 delivers block (0->2), 1 -> 2 forwards
-    it (split into 4 parts, 3 of them zero-block), 2 -> 3 forwards on."""
-    p = 6
-    sch = S.Schedule(
+    it as one payload in 4 parallel parts (3 of them zero-block), 2 -> 3
+    forwards on."""
+    i64 = np.int64
+    sp = IR.CompiledSchedule(
         op="alltoall",
         algorithm="toy",
-        p=p,
+        p=6,
         k=1,
-        rounds=(
-            S.Round((S.Msg(0, 1, 8, (2,)),)),
-            S.Round((S.Msg(1, 2, 8, (2,)),)),
-            S.Round((S.Msg(2, 3, 8, (2,)),)),
-        ),
+        src=np.array([0, 1, 1, 1, 1, 2], dtype=i64),
+        dst=np.array([1, 2, 2, 2, 2, 3], dtype=i64),
+        elems=np.array([8, 2, 2, 2, 2, 8], dtype=i64),
+        round_ptr=np.array([0, 1, 5, 6], dtype=i64),
+        blk_ptr=np.array([0, 1, 2, 2, 2, 2, 3], dtype=i64),
+        blk_ids=np.array([2, 2, 2], dtype=i64),
     )
-    cs = IR.compile_schedule(sch, with_blocks=True)
-    sp = IR.split_messages(cs, np.array([1, 4, 1], dtype=np.int64))
     assert np.diff(sp.blk_ptr).tolist() == [1, 1, 0, 0, 0, 1]
     return sp
 
@@ -239,7 +218,7 @@ def test_color_does_not_hoist_zero_block_parts():
 
 
 # ---------------------------------------------------------------------------
-# cost-aware SplitPayloads + the shared costing hooks
+# the shared costing hooks
 # ---------------------------------------------------------------------------
 
 
@@ -260,104 +239,55 @@ def test_costing_hooks_match_simulator_reference():
     assert t == pytest.approx(cost.alpha_inter + cost.beta_inter * 1000.0 / 2)
 
 
-def test_cost_split_skips_zero_gain_splits():
-    """klane alltoall in the 1-ported model: every node already drives more
-    streams than lanes and the port term ignores the message count, so the
-    model prices every split at zero — the cost-aware pass must be an
-    identity where the uniform pass doubles the message count."""
-    topo = Topology(4, 6, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    uniform = SplitPayloads(parts=2).apply(cs)
-    assert uniform.num_msgs == 2 * cs.num_msgs  # the junk the lex policy
-    # previously had to reject wholesale
-    assert SplitPayloads(machine=_machine(topo), ported=False).apply(cs) is cs
-
-
-def test_cost_split_matches_uniform_where_the_model_pays():
-    """k-ported model, lone senders: the alpha/beta trade-off predicts the
-    same lane-filling factors the uniform pass uses — same simulated time,
-    and never more messages."""
-    topo = Topology(4, 6, 2)
-    machine = _machine(topo)
-    cs = IR.compiled_schedule("broadcast", "klane", topo, 2, 10_000)
-    uniform = SplitPayloads(parts=topo.k_lanes).apply(cs)
-    costed = SplitPayloads(machine=machine, ported=True).apply(cs)
-    assert costed.num_msgs <= uniform.num_msgs
-    assert simulate(costed, machine, ported=True).time_us == pytest.approx(
-        simulate(uniform, machine, ported=True).time_us, rel=1e-12
-    )
-    assert (
-        simulate(costed, machine, ported=True).time_us
-        < simulate(cs, machine, ported=True).time_us - 1e-9
-    )
-    assert validate_schedule(costed).ok
-
-
-def test_cost_split_identity_in_one_ported_model_is_not_a_forgone_gain():
-    """In the 1-ported model no split can pay: the sender's port serializes
-    its bytes regardless of message count, and in a lane-starved round the
-    worst port term already dominates the node lane term.  The cost-aware
-    pass is an identity there — and the uniform split on the same schedule
-    indeed buys nothing (same simulated time, more messages), confirming
-    the identity forgoes no gain even on a 1-stream-per-node broadcast."""
-    topo = Topology(4, 4, 4)
-    machine = _machine(topo)
-    cs = IR.compiled_schedule("broadcast", "kported", topo, 1, 100_000)
-    assert SplitPayloads(machine=machine, ported=False).apply(cs) is cs
-    uniform = SplitPayloads(parts=topo.k_lanes).apply(cs)
-    assert uniform.num_msgs > cs.num_msgs
-    assert simulate(uniform, machine).time_us == pytest.approx(
-        simulate(cs, machine).time_us, rel=1e-12
-    )
-
-
 # ---------------------------------------------------------------------------
-# merge(split(...)) round-trip property (ISSUE 4 test-coverage satellite)
+# recipe replay of the "color" pipeline on the plan cell's meshes
 # ---------------------------------------------------------------------------
 
+#: the plan cell's meshes (nodes, procs_per_node, k_lanes) and its dispatch
+#: payload: T tokens a chip x top-6 x hidden 5120, split over the group
+_PLAN_MESHES = [(4, 8, 8), (18, 8, 8)]
+_PLAN_TOKENS = [1, 64, 4096]
 
-def _canon(cs):
-    """Messages sorted by (round, src, dst) — merge_messages' output order."""
-    rid = cs.round_ids()
-    key = (rid * cs.p + cs.src) * cs.p + cs.dst
-    order = np.argsort(key, kind="stable")
-    blk_ptr, blk_ids = IR.gather_block_csr(cs.blk_ptr, cs.blk_ids, order)
-    return dataclasses.replace(
-        cs,
-        src=cs.src[order],
-        dst=cs.dst[order],
-        elems=cs.elems[order],
-        blk_ptr=blk_ptr,
-        blk_ids=blk_ids,
-        _stats={},
+
+def _plan_cell_pairs():
+    pairs = []
+    for nn, ppn, kl in _PLAN_MESHES:
+        topo = Topology(nn, ppn, kl)
+        for op in ("broadcast", "scatter", "alltoall"):
+            for alg in selector._candidate_algs(op, topo):
+                if not alg.startswith("opt:"):
+                    pairs.append((nn, ppn, kl, op, alg))
+    return pairs
+
+
+@pytest.mark.parametrize("tokens", _PLAN_TOKENS)
+@pytest.mark.parametrize(
+    "mesh_op_alg", _plan_cell_pairs(),
+    ids=lambda t: f"{t[0]}x{t[1]}-{t[3]}-{t[4]}",
+)
+def test_color_recipe_replay_on_plan_meshes(mesh_op_alg, tokens):
+    """Every family the selector races on the plan cell's meshes: the
+    ``optimize="color"`` schedule served from a recorded recipe equals
+    ``ColorRounds`` run directly on that payload's base, passes the oracle
+    and keeps the payload — the production path never re-checks a
+    replay."""
+    nn, ppn, kl, op, alg = mesh_op_alg
+    topo = Topology(nn, ppn, kl)
+    k = min(kl, ppn)
+    c = max(1, tokens * 6 * 5120 // topo.p)
+    # record the structure's recipe at another payload, so c replays it
+    IR.compiled_schedule(op, alg, topo, k, c + 1, optimize="color")
+    before = IR.schedule_cache_info()
+    replay = IR.compiled_schedule(op, alg, topo, k, c, optimize="color")
+    after = IR.schedule_cache_info()
+    # a replay now, or one served from the cache since it was replayed
+    assert (after["recipe_hits"], after["hits"]) in (
+        (before["recipe_hits"] + 1, before["hits"]),
+        (before["recipe_hits"], before["hits"] + 1),
     )
-
-
-_A2A_FAMILIES = ["kported", "bruck", "klane", "fulllane"]
-
-
-@pytest.mark.parametrize("alg", _A2A_FAMILIES)
-@pytest.mark.parametrize("seed", [0, 1])
-def test_merge_split_roundtrip_bit_exact(alg, seed):
-    """merge_messages(split_messages(cs, f)) is bit-exact (up to the
-    canonical in-round message order) for random factor vectors including
-    f > elems and f > nblk, on all four alltoall families; the simulated
-    cost is unchanged on both machine models and both port models."""
-    topo = Topology(3, 4, 2)
-    cs = IR.compiled_schedule("alltoall", alg, topo, 2, 3)
-    assert IR.merge_messages(cs) is cs  # no same-(round,src,dst) duplicates
-    rng = np.random.default_rng(seed * 7919 + len(alg))
-    hi = int(max(cs.elems.max(), np.diff(cs.blk_ptr).max())) * 2 + 2
-    factors = rng.integers(1, hi, size=cs.num_msgs)
-    sp = IR.split_messages(cs, factors)
-    assert sp.total_elems() == cs.total_elems()
-    assert validate_schedule(sp).ok
-    rt = IR.merge_messages(sp)
-    canon = _canon(cs)
+    base = IR.compiled_schedule(op, alg, topo, k, c)
+    direct = ColorRounds(limit=None, procs_per_node=ppn).apply(base)
     for f in ("src", "dst", "elems", "round_ptr", "blk_ptr", "blk_ids"):
-        assert np.array_equal(getattr(rt, f), getattr(canon, f)), (alg, f)
-    for machine in (_machine(topo), Machine(topo=topo, cost=nvlink_ib_machine().cost)):
-        for ported in (False, True):
-            assert simulate(rt, machine, ported=ported).time_us == pytest.approx(
-                simulate(cs, machine, ported=ported).time_us, rel=1e-12
-            )
+        assert np.array_equal(getattr(replay, f), getattr(direct, f)), f
+    assert validate_schedule(replay).ok
+    assert replay.total_elems() == base.total_elems()

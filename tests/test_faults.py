@@ -22,7 +22,7 @@ from repro.core.faults import (
     degradation_of,
     sample_faults,
 )
-from repro.core.passes import RepairSchedule, optimize_schedule, repair_schedule
+from repro.core.passes import RepairSchedule, repair_schedule
 from repro.core.schedule_ir import (
     compiled_schedule,
     relay_messages,
@@ -213,6 +213,49 @@ def test_repair_matrix(topo, family, cost_name):
             assert t >= t_h * (1 - 1e-9), (name, family)
 
 
+TREE_FAMILIES = [(op, alg) for op in ("broadcast", "scatter")
+                 for alg in ("kported", "klane", "fulllane")]
+
+
+@pytest.mark.parametrize("scenario", ["dead_rail", "dead_port"])
+@pytest.mark.parametrize("topo", SMALL_TOPOS, ids=lambda t: f"{t.num_nodes}x{t.procs_per_node}")
+@pytest.mark.parametrize("op_alg", TREE_FAMILIES, ids=lambda t: "-".join(t))
+def test_repair_matrix_tree_families(op_alg, topo, scenario):
+    """The repair matrix for the broadcast and scatter families, on the
+    schedules a faulted ``opt:`` candidate repairs (the color-packed
+    ones): repaired and oracle-valid within the surviving budget, or
+    reverted where the spec leaves a node the schedule routes through
+    with no live network port."""
+    op, alg = op_alg
+    spec = _scenarios(topo)[scenario]
+    packed = compiled_schedule(op, alg, topo, topo.k_lanes, 13,
+                               optimize="color")
+    try:
+        RepairSchedule(spec, topo=topo).apply(packed)
+    except UnrepairableFaultError:
+        deg = degradation_of(spec, topo)
+        n = topo.procs_per_node
+        live = (~deg.dead_port).reshape(topo.num_nodes, n).any(axis=1)
+        assert not live.all(), (op, alg, scenario)
+        out, recs = repair_schedule(packed, spec, topo=topo)
+        assert out is packed and recs[0].applied is False
+        return
+    repaired, recs = repair_schedule(packed, spec, topo=topo)
+    assert check_schedule(repaired).ok, (op, alg, scenario)
+    assert recs[0].oracle_ok in (True, None)
+    assert repaired.total_elems() >= packed.total_elems()
+    n = topo.procs_per_node
+    inter = (repaired.src // n) != (repaired.dst // n)
+    if scenario == "dead_rail":
+        assert repaired.max_port_width() <= topo.k_lanes - 1
+    else:
+        dead = spec.dead_ranks[0]
+        assert not ((repaired.src == dead) & inter).any()
+        assert not ((repaired.dst == dead) & inter).any()
+    t = simulate(repaired, apply_faults(_machine(topo), spec)).time_us
+    assert math.isfinite(t), (op, alg, scenario)
+
+
 def test_repair_dead_port_relays_not_regenerates():
     """Dead-NIC repair is a rewrite: the repaired schedule contains every
     healthy payload (same total elems through the relay) and only the
@@ -238,8 +281,8 @@ def test_repair_repacks_overpacked_schedule():
     budget must be re-packed down to it — the cache-invalidation story:
     healthy opt: recipes are not runnable under a dead rail."""
     topo = Topology(4, 6, 2)
-    base = compiled_schedule("alltoall", "klane", topo, 2, 3)
-    packed, _ = optimize_schedule(base, "color", topo=topo, machine=_machine(topo))
+    packed = compiled_schedule("alltoall", "klane", topo, 2, 3,
+                               optimize="color")
     if packed.max_port_width() <= 1:
         pytest.skip("packer found no width-2 packing to repair")
     repaired, recs = repair_schedule(packed, FaultSpec(dead_rails=1), topo=topo)

@@ -1,12 +1,8 @@
-"""ISSUE 5: the bitset conflict-coloring packer (windowed uint64 batch
-selection), the cost-aware budget chooser and tree-aware caps, the
-incremental data-flow oracle (``rewrite_window``/``revalidate_schedule``,
-pinned incremental == full on all four alltoall families and both machine
-models), the fingerprinted/recipe'd optimized-schedule cache (hit/miss,
+"""The structural budget chooser of the bitset conflict-coloring
+packer, the fingerprinted/recipe'd optimized-schedule cache (hit/miss,
 fingerprint invalidation, thread-safety smoke), the selector's adaptive
 fourth probe, and the bench gate's report-everything-in-one-run fix."""
 
-import dataclasses
 import json
 import sys
 from concurrent.futures import ThreadPoolExecutor
@@ -19,224 +15,26 @@ from repro.core import schedule_ir as IR
 from repro.core import selector
 from repro.core.passes import (
     ColorRounds,
-    CompactRounds,
-    PassManager,
-    ReorderRounds,
-    SplitPayloads,
     choose_color_budget,
     pipeline_fingerprint,
 )
-from repro.core.simulate import simulate
-from repro.core.topology import (
-    Machine,
-    Topology,
-    hydra_machine,
-    nvlink_ib_machine,
-)
-from repro.core.validate import (
-    revalidate_schedule,
-    rewrite_window,
-    validate_schedule,
-    window_hop_fraction,
-)
-
-HYDRA = hydra_machine()
-NVLINK = nvlink_ib_machine()
-_A2A = ["kported", "bruck", "klane", "fulllane"]
-
-
-def _machine(topo, cost_src):
-    return Machine(topo=topo, cost=cost_src.cost)
+from repro.core.topology import Topology
+from repro.core.validate import validate_schedule
 
 
 # ---------------------------------------------------------------------------
-# incremental oracle: rewrite_window + revalidate_schedule
-# ---------------------------------------------------------------------------
-
-
-def test_rewrite_window_identical_and_disjoint():
-    topo = Topology(3, 4, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 5)
-    assert rewrite_window(cs, cs) == (cs.num_rounds, cs.num_rounds,
-                                      cs.num_rounds)
-    # merging two interior rounds confines the window to exactly them
-    merged_ptr = np.delete(cs.round_ptr, 3)
-    new = dataclasses.replace(cs, round_ptr=merged_ptr, _stats={})
-    a, bp, bn = rewrite_window(cs, new)
-    assert (a, bp, bn) == (2, 4, 3)
-    assert window_hop_fraction(cs, new, (a, bp, bn)) < 0.5
-    other = IR.compiled_schedule("alltoall", "bruck", topo, 2, 5)
-    assert rewrite_window(
-        dataclasses.replace(cs, op="scatter"), cs
-    ) is None
-    assert rewrite_window(other, cs) is not None  # same op/p: diffable
-
-
-@pytest.mark.parametrize("alg", _A2A)
-@pytest.mark.parametrize("mach", ["hydra", "nvlink"])
-def test_incremental_equals_full_oracle(alg, mach):
-    """ISSUE 5 acceptance: incremental == full oracle verdict on every
-    window-confined rewrite of all four alltoall families, on both machine
-    models (the machine drives the cost-aware rewrites being checked)."""
-    topo = Topology(3, 4, 2)
-    machine = _machine(topo, HYDRA if mach == "hydra" else NVLINK)
-    base = IR.compiled_schedule("alltoall", alg, topo, 2, 5)
-    assert validate_schedule(base).ok
-    rng = np.random.default_rng(len(alg))
-    rewrites = [
-        CompactRounds(limit=None).apply(base),
-        ReorderRounds(limit=None, procs_per_node=4).apply(base),
-        ColorRounds(limit=None, procs_per_node=4, mult=4).apply(base),
-        ColorRounds(
-            limit=None, procs_per_node=4, mult=None,
-            machine=machine, ported=True,
-        ).apply(base),
-        SplitPayloads(machine=machine, ported=True).apply(base),
-        IR.split_messages(
-            base, rng.integers(1, 4, size=base.num_msgs)
-        ),
-    ]
-    for new in rewrites:
-        inc = revalidate_schedule(new, prev=base)
-        full = validate_schedule(new)
-        assert inc.ok and full.ok
-    # corrupt *inside* a window: merge the first two rounds, creating
-    # same-round forwarding on every dependency-chained family
-    bad = dataclasses.replace(
-        base, round_ptr=np.delete(base.round_ptr, 1), _stats={}
-    )
-    inc = revalidate_schedule(bad, prev=base)
-    full = validate_schedule(bad)
-    assert inc.ok == full.ok
-    if alg == "bruck":  # phases fully chained: the merge is illegal
-        assert not inc.ok
-
-
-def test_incremental_checks_only_affected_blocks():
-    """The subset report covers the affected chains only — fewer hops than
-    the full oracle — while agreeing on the verdict."""
-    topo = Topology(3, 4, 2)
-    base = IR.compiled_schedule("alltoall", "fulllane", topo, 2, 5)
-    merged_ptr = np.delete(base.round_ptr, 2)
-    new = dataclasses.replace(base, round_ptr=merged_ptr, _stats={})
-    inc = revalidate_schedule(new, prev=base)
-    full = validate_schedule(new)
-    assert inc.ok == full.ok
-    assert inc.num_block_hops < full.num_block_hops
-
-
-def test_passmanager_incremental_matches_full():
-    """The manager's incremental path (default) keeps exactly the rewrites
-    the full path keeps, with identical oracle verdicts."""
-    topo = Topology(3, 4, 2)
-    machine = _machine(topo, HYDRA)
-    base = IR.compiled_schedule("alltoall", "fulllane", topo, 2, 5)
-    pipeline = [
-        ReorderRounds(limit=None, procs_per_node=4),
-        SplitPayloads(machine=machine, ported=True),
-        CompactRounds(limit=None),
-    ]
-    opt_inc, rec_inc = PassManager(
-        pipeline, machine=machine, ported=True, policy="lex",
-        validate=True, incremental=True,
-    ).run(base)
-    opt_full, rec_full = PassManager(
-        pipeline, machine=machine, ported=True, policy="lex",
-        validate=True, incremental=False,
-    ).run(base)
-    assert [r.applied for r in rec_inc] == [r.applied for r in rec_full]
-    assert [r.oracle_ok for r in rec_inc] == [r.oracle_ok for r in rec_full]
-    assert opt_inc.num_rounds == opt_full.num_rounds
-    assert validate_schedule(opt_inc).ok
-
-
-def test_passmanager_check_reverts_corrupt_rewrite_incrementally():
-    """A corrupt rewrite whose diff is window-confined is caught by the
-    incremental oracle and reverted under check=True."""
-
-    class MergeFirstRounds:
-        name = "corrupt_merge"
-
-        def apply(self, cs):
-            return dataclasses.replace(
-                cs, round_ptr=np.delete(cs.round_ptr, 1), _stats={}
-            )
-
-    topo = Topology(3, 4, 2)
-    base = IR.compiled_schedule("alltoall", "bruck", topo, 2, 5)
-    pm = PassManager([MergeFirstRounds()], check=True, incremental=True)
-    out, records = pm.run(base)
-    assert out is base
-    assert records[0].oracle_ok is False and not records[0].applied
-
-
-# ---------------------------------------------------------------------------
-# budget chooser + tree-aware caps
+# budget chooser
 # ---------------------------------------------------------------------------
 
 
 def test_choose_color_budget_structural_prefers_deepest_useful():
     topo = Topology(4, 6, 2)
     cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    mult, limit = choose_color_budget(cs, procs_per_node=6)
+    mult, limit = choose_color_budget(cs)
     assert (mult, limit) == (8, 16)  # every rung still shrinks the bound
-    col = ColorRounds(limit=None, procs_per_node=6, mult=None).apply(cs)
+    col = ColorRounds(limit=None, procs_per_node=6).apply(cs)
     assert col.num_rounds == -(-18 // 16) + -(-5 // 16)
     assert validate_schedule(col).ok
-
-
-def test_choose_color_budget_cost_priced_beats_fixed_ladder():
-    """Hydra, klane alltoall at c=1 (alpha regime): the chooser must pick a
-    rung at least as deep as PR 4's fixed 4k — packing to no more rounds,
-    no slower — without racing the ladder."""
-    topo = Topology(36, 32, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 32, 1)
-    mult, limit = choose_color_budget(
-        cs, procs_per_node=32, machine=HYDRA, ported=False
-    )
-    assert limit >= 4 * cs.k
-    auto = ColorRounds(
-        limit=None, procs_per_node=32, mult=None,
-        machine=HYDRA, ported=False,
-    ).apply(cs)
-    fixed = ColorRounds(limit=None, procs_per_node=32, mult=4).apply(cs)
-    assert auto.num_rounds <= fixed.num_rounds
-    assert (
-        simulate(auto, HYDRA).time_us <= simulate(fixed, HYDRA).time_us + 1e-9
-    )
-    assert validate_schedule(auto).ok
-
-
-def test_tree_aware_caps_bandwidth_regime():
-    """kported/fulllane broadcast at c=1e6 (the families where PR 4's eager
-    coloring lost the race by concentrating root bytes): the tree-aware
-    caps must price-protect the packing — no slower than the uncapped
-    packer, and oracle-valid."""
-    topo = Topology(36, 32, 2)
-    for alg, k in (("kported", 6), ("fulllane", 6)):
-        base = IR.compiled_schedule("broadcast", alg, topo, k, 1_000_000)
-        nocap = ColorRounds(limit=None, procs_per_node=32, mult=4).apply(base)
-        cap = ColorRounds(
-            limit=None, procs_per_node=32, mult=4,
-            machine=HYDRA, ported=True,
-        ).apply(base)
-        assert validate_schedule(cap).ok
-        assert (
-            simulate(cap, HYDRA, ported=True).time_us
-            < simulate(nocap, HYDRA, ported=True).time_us
-        ), alg
-
-
-def test_tree_aware_caps_inactive_in_alpha_regime():
-    """At c=1 a message costs less than a latency: the caps must not
-    restrict packing (machine= output == machine-free output)."""
-    topo = Topology(36, 32, 2)
-    base = IR.compiled_schedule("alltoall", "klane", topo, 32, 1)
-    plain = ColorRounds(limit=None, procs_per_node=32, mult=4).apply(base)
-    costed = ColorRounds(
-        limit=None, procs_per_node=32, mult=4, machine=HYDRA, ported=False
-    ).apply(base)
-    assert costed.num_rounds == plain.num_rounds
 
 
 # ---------------------------------------------------------------------------
@@ -245,22 +43,19 @@ def test_tree_aware_caps_inactive_in_alpha_regime():
 
 
 def test_opt_cache_hit_miss_across_modes():
+    """The two optimize values (None, "color") key distinct entries, and
+    repeats of each hit."""
     IR.schedule_cache_clear()
     topo = Topology(4, 6, 2)
     a = IR.compiled_schedule("alltoall", "klane", topo, 2, 7,
                              optimize="color")
-    b = IR.compiled_schedule("alltoall", "klane", topo, 2, 7,
-                             optimize="reorder")
     plain = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    assert a is not b and a is not plain
-    # repeats hit, per mode
+    assert a is not plain
     before = IR.schedule_cache_info()
     assert IR.compiled_schedule(
         "alltoall", "klane", topo, 2, 7, optimize="color"
     ) is a
-    assert IR.compiled_schedule(
-        "alltoall", "klane", topo, 2, 7, optimize="reorder"
-    ) is b
+    assert IR.compiled_schedule("alltoall", "klane", topo, 2, 7) is plain
     after = IR.schedule_cache_info()
     assert after["hits"] == before["hits"] + 2
     assert after["misses"] == before["misses"]
@@ -283,7 +78,7 @@ def test_opt_cache_recipe_replays_across_payloads():
     assert b.num_rounds == a.num_rounds
     # recipe replay == running the pipeline directly on the c=869 base
     base = IR.compiled_schedule("alltoall", "klane", topo, 2, 869)
-    direct, _ = P.optimize_schedule(base, "color", topo=topo)
+    direct = ColorRounds(limit=None, procs_per_node=6).apply(base)
     for f in ("src", "dst", "elems", "round_ptr", "blk_ptr", "blk_ids"):
         assert np.array_equal(getattr(b, f), getattr(direct, f)), f
     assert validate_schedule(b).ok
@@ -303,8 +98,8 @@ def test_opt_cache_fingerprint_invalidation(monkeypatch):
 
 
 def test_pipeline_fingerprint_covers_names_and_version(monkeypatch):
-    p1 = [ReorderRounds(limit=None, procs_per_node=4)]
-    p2 = [ReorderRounds(limit=2, procs_per_node=4)]
+    p1 = [ColorRounds(limit=None, procs_per_node=4)]
+    p2 = [ColorRounds(limit=2, procs_per_node=4)]
     assert pipeline_fingerprint(p1) != pipeline_fingerprint(p2)
     f1 = pipeline_fingerprint(p1)
     monkeypatch.setattr(P, "PASS_PIPELINE_VERSION", "test-bump")
@@ -457,7 +252,7 @@ def test_paper_opt_smoke_wiring():
 
     assert any(
         op == "alltoall" and alg in ("fulllane", "kported")
-        for _, op, alg, _, _, _ in OPT3_CASES
+        for _, op, alg, _, _ in OPT3_CASES
     )
     assert table_paper_opt_smoke.__doc__
     # argparse accepts the new selection without running it

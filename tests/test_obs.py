@@ -14,9 +14,9 @@ import pytest
 
 from repro.core import schedule_ir as IR
 from repro.core.passes import (
-    CompactRounds,
-    PassManager,
+    ColorRounds,
     repair_schedule,
+    run_passes,
 )
 from repro.core.faults import FaultSpec
 from repro.core.selector import last_decision
@@ -327,32 +327,35 @@ def test_compile_span_nesting_and_cache_events():
     TRACER.enable()
     topo = Topology(3, 4, 2)
     mark = TRACER.mark()
-    IR.compiled_schedule("alltoall", "klane", topo, 2, 7, optimize="split")
+    IR.compiled_schedule("alltoall", "klane", topo, 2, 7, optimize="color")
     recs = TRACER.records_since(mark)
     by_sid = {r["sid"]: r for r in recs if r["ph"] == "X"}
     compiles = [r for r in by_sid.values() if r["name"] == "compile"]
     assert len(compiles) == 2  # optimized entry + its unoptimized base
     outer = [r for r in compiles if r["parent"] is None]
-    assert len(outer) == 1 and outer[0]["args"]["path"] == "optimize"
-    # oracle span nested under a pass span nested under optimize
+    assert len(outer) == 1 and outer[0]["args"]["path"] == "recipe"
+    # a pass span nested under optimize, both under the outer compile;
+    # the oracle checks the recorded rewrite under the same compile
+    passes = [r for r in by_sid.values() if r["name"].startswith("pass:")]
+    assert len(passes) == 1
+    opt = by_sid[passes[0]["parent"]]
+    assert opt["name"] == "optimize" and opt["parent"] == outer[0]["sid"]
     oracles = [r for r in by_sid.values() if r["name"] == "oracle"]
-    assert any(
-        by_sid.get(o["parent"], {}).get("name", "").startswith("pass:")
-        for o in oracles
-    )
+    assert [o["parent"] for o in oracles] == [outer[0]["sid"]]
     # a cache hit emits the instant event, no compile span
     mark = TRACER.mark()
-    IR.compiled_schedule("alltoall", "klane", topo, 2, 7, optimize="split")
+    IR.compiled_schedule("alltoall", "klane", topo, 2, 7, optimize="color")
     hit_recs = TRACER.records_since(mark)
     assert [r["name"] for r in hit_recs] == ["cache.hit"]
 
 
 def test_pass_spans_match_pass_records():
-    cs, _ = _small_schedule()
+    cs, topo = _small_schedule()
     TRACER.enable()
     mark = TRACER.mark()
-    pm = PassManager([CompactRounds(limit=None)], validate=True)
-    _, records = pm.run(cs)
+    _, records = run_passes(
+        cs, [ColorRounds(limit=None, procs_per_node=topo.procs_per_node)]
+    )
     recs = TRACER.records_since(mark)
     pass_spans = [r for r in recs if r["ph"] == "X"
                   and r["name"].startswith("pass:")]
@@ -393,7 +396,7 @@ def test_span_closed_on_pipeline_exception():
     topo = Topology(3, 4, 2)
     with pytest.raises(KeyError):
         IR.compiled_schedule("alltoall", "nosuch", topo, 2, 5,
-                             optimize="split")
+                             optimize="color")
     t = TRACER
     assert not t._stack(), "exception leaked an open span"
     err = [r for r in t.records() if r["ph"] == "X"
@@ -503,33 +506,33 @@ def test_oracle_violation_auto_dump_armed_only(tmp_path):
 def test_pass_walls_breakdown_traced_and_fallback():
     from benchmarks.paper_tables import _pass_walls
 
-    cs, _ = _small_schedule()
+    cs, topo = _small_schedule()
     TRACER.enable()
     mark = TRACER.mark()
-    pm = PassManager([CompactRounds(limit=None)], validate=True)
-    _, records = pm.run(cs)
-    traced = _pass_walls(records, mark)
-    assert traced.startswith("compact_rounds=")
+    run_passes(
+        cs, [ColorRounds(limit=None, procs_per_node=topo.procs_per_node)]
+    )
+    traced = _pass_walls(mark)
+    assert traced.startswith("color_rounds=")
     assert "," not in traced and "[" not in traced  # CSV-safe
-    # untraced fallback sums PassRecord wall clocks instead
+    # untraced: no spans to read, an empty column
     TRACER.disable()
-    fallback = _pass_walls(records, None)
-    assert fallback.startswith("compact_rounds=")
+    assert _pass_walls(None) == ""
 
 
 def test_render_optimizer_deltas_breakdown_column():
     from benchmarks.paper_tables import render_optimizer_deltas
 
     rows = [{
-        "table": "OPT", "impl": "opt:klane_a2a", "c": 869,
+        "table": "OPT3", "impl": "opt3:klane_a2a", "c": 869,
         "rounds_before": 8, "rounds_after": 4, "base_us": 10.0,
         "sim_us": 5.0, "paper_us": 42.0, "opt_wall_s": 0.5,
-        "pass_walls": "compact_rounds=0.010;coalesce_messages=0.002",
+        "pass_walls": "color_rounds=0.010;other_pass=0.002",
     }]
     lines = render_optimizer_deltas(rows)
     assert lines[0].endswith("speedup,paper_us,pass_walls")
     assert lines[1].endswith(
-        "2.00x,42.0,compact_rounds=0.010;coalesce_messages=0.002"
+        "2.00x,42.0,color_rounds=0.010;other_pass=0.002"
     )
     # every line splits into the same number of comma cells
     assert len(lines[0].split(",")) == len(lines[1].split(","))
@@ -554,8 +557,7 @@ def test_disabled_tracer_overhead_under_2pct():
     base = IR.compiled_schedule("alltoall", "klane", topo, 2, 5)
 
     def run_once():
-        pm = PassManager([CompactRounds(limit=None)], validate=True)
-        pm.run(base)
+        run_passes(base, [ColorRounds(limit=None, procs_per_node=12)])
 
     assert not TRACER
     run_once()  # warm caches

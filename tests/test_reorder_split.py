@@ -1,10 +1,8 @@
-"""ISSUE 3 scheduling-pass suite: the block-dependency export, non-adjacent
-round reordering, k-lane payload splitting (with the split/merge primitive
-round-trip), the fixpoint lexicographic PassManager including its
-oracle-revert failure path, the selector's 3-probe piecewise fits, the
-bench-trajectory gate, and a dry parse of the CI workflow."""
+"""What remains of the scheduling-pass suite once its passes went: the
+block-dependency export the coloring packer consumes, the selector's
+3-probe piecewise fits, the bench-trajectory gate, and a dry parse of the
+CI workflow."""
 
-import dataclasses
 import json
 import pathlib
 import subprocess
@@ -16,24 +14,10 @@ import pytest
 from repro.core import schedule as S
 from repro.core import schedule_ir as IR
 from repro.core import selector
-from repro.core.passes import (
-    CoalesceMessages,
-    CompactRounds,
-    PassManager,
-    ReorderRounds,
-    SplitPayloads,
-    optimize_schedule,
-)
-from repro.core.simulate import simulate
 from repro.core.topology import Machine, Topology, hydra_machine
-from repro.core.validate import block_dependencies, validate_schedule
+from repro.core.validate import block_dependencies
 
-HYDRA = hydra_machine()
 REPO = pathlib.Path(__file__).resolve().parent.parent
-
-
-def _machine(topo: Topology) -> Machine:
-    return Machine(topo=topo, cost=HYDRA.cost)
 
 
 # ---------------------------------------------------------------------------
@@ -68,335 +52,6 @@ def test_block_dependencies_requires_blocks():
     cs = IR.compile_schedule(S.kported_broadcast(9, 2, 5))  # blockless
     with pytest.raises(ValueError, match="block"):
         block_dependencies(cs)
-
-
-# ---------------------------------------------------------------------------
-# ReorderRounds
-# ---------------------------------------------------------------------------
-
-
-def _alltoall_rounds(p, rounds):
-    """Small hand-built alltoall schedule: each (src, dst) message carries
-    its own pair block (analytically held -> dependency-free)."""
-    sch = S.Schedule(
-        op="alltoall",
-        algorithm="test",
-        p=p,
-        k=1,
-        rounds=tuple(
-            S.Round(tuple(S.Msg(s, d, 1, (s * p + d,)) for s, d in msgs))
-            for msgs in rounds
-        ),
-    )
-    return IR.compile_schedule(sch, with_blocks=True)
-
-
-def test_reorder_beats_adjacent_compaction():
-    """Rounds [0->1], [0->2], [3->4], [3->5]: adjacent merging is stuck at
-    3 rounds (every adjacent pair shares a sender), the list scheduler
-    reaches the optimal 2."""
-    cs = _alltoall_rounds(6, [[(0, 1)], [(0, 2)], [(3, 4)], [(3, 5)]])
-    compact = CompactRounds(limit=1).apply(cs)
-    assert compact.num_rounds == 3
-    reorder = ReorderRounds(limit=1, procs_per_node=6).apply(cs)
-    assert reorder.num_rounds == 2
-    # the toy schedule is a partial alltoall, so compare data-flow health
-    # against the input instead of the full-op postcondition
-    rep, base_rep = validate_schedule(reorder), validate_schedule(cs)
-    assert rep.causality_violations == 0
-    assert rep.missing_final == base_rep.missing_final
-    assert reorder.total_elems() == cs.total_elems()
-
-
-def test_reorder_interleaves_trailing_intra_phase():
-    """klane alltoall's trailing on-node phase packs into its own groups
-    while the inter-node phase compacts to the lane budget — the round
-    count lands at ceil(inter/k) + ceil(intra/k) with no class mixing."""
-    topo = Topology(4, 6, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    opt = ReorderRounds(limit=None, procs_per_node=6).apply(cs)
-    N, n = 4, 6
-    assert opt.num_rounds == -(-(N - 1) * n // 2) + -(-(n - 1) // 2)
-    assert validate_schedule(opt).ok
-    # class purity: no proc both sends on-node and off-node in one round
-    rid = opt.round_ids()
-    inter = (opt.src // n) != (opt.dst // n)
-    skey = rid * topo.p + opt.src
-    both = set(skey[inter].tolist()) & set(skey[~inter].tolist())
-    assert not both
-
-
-def test_reorder_respects_dependency_chains():
-    """Bruck's phases are fully chained: reordering must keep them apart
-    (merging any two would forward a block within a round)."""
-    cs = IR.bruck_alltoall_ir(27, 2, 5)
-    nonempty = int((np.diff(cs.round_ptr) > 0).sum())
-    opt = ReorderRounds(limit=None, procs_per_node=9).apply(cs)
-    assert opt.num_rounds == nonempty
-    assert validate_schedule(opt).ok
-
-
-def test_reorder_requires_blocks_and_divisible_nodes():
-    blockless = IR.compile_schedule(S.kported_scatter(8, 2, 3))
-    with pytest.raises(ValueError, match="block"):
-        ReorderRounds(limit=1, procs_per_node=4).apply(blockless)
-    cs = IR.kported_alltoall_ir(8, 2, 3)
-    with pytest.raises(ValueError, match="divisible"):
-        ReorderRounds(limit=1, procs_per_node=3).apply(cs)
-
-
-@pytest.mark.parametrize("op_alg", sorted(S.ALGORITHMS))
-def test_reorder_never_slower_and_valid(op_alg):
-    """The class-purity + budget + dependency constraints make reordering
-    provably never slower; check it across every family on both the paper
-    machine and a lane-budget-2x rung."""
-    op, alg = op_alg
-    topo = Topology(3, 4, 2)
-    machine = _machine(topo)
-    cs = IR.compiled_schedule(op, alg, topo, 2, 13)
-    for limit in (None, 2 * cs.k):
-        opt = ReorderRounds(limit=limit, procs_per_node=4).apply(cs)
-        assert validate_schedule(opt).ok
-        assert opt.total_elems() == cs.total_elems()
-        assert opt.num_rounds <= cs.num_rounds
-        for ported in (False, True):
-            assert (
-                simulate(opt, machine, ported=ported).time_us
-                <= simulate(cs, machine, ported=ported).time_us + 1e-9
-            )
-
-
-def test_optimize_mode_reorder_via_cache_and_selector_parse():
-    topo = Topology(4, 6, 2)
-    base = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    opt = IR.compiled_schedule("alltoall", "klane", topo, 2, 7, optimize="reorder")
-    assert opt.num_rounds < base.num_rounds
-    assert IR.compiled_schedule(
-        "alltoall", "klane", topo, 2, 7, optimize="reorder"
-    ) is opt
-    # opt: now resolves to the ISSUE 4 coloring packer (see
-    # tests/test_color_pack.py); the reorder mode itself stays available
-    assert selector._parse_alg("opt:klane") == ("klane", "color")
-    assert selector._parse_alg("klane") == ("klane", None)
-    with pytest.raises(ValueError, match="topology"):
-        optimize_schedule(base, "reorder")  # mode needs topo= or machine=
-
-
-# ---------------------------------------------------------------------------
-# split/merge primitives + SplitPayloads
-# ---------------------------------------------------------------------------
-
-
-def test_split_messages_partitions_payload_and_blocks():
-    cs = IR.fulllane_alltoall_ir(Topology(3, 4, 2), 8)
-    factors = np.full(cs.num_msgs, 3, dtype=np.int64)
-    sp = IR.split_messages(cs, factors)
-    assert sp.num_msgs == 3 * cs.num_msgs
-    assert sp.num_rounds == cs.num_rounds
-    assert sp.total_elems() == cs.total_elems()
-    assert np.all(sp.elems > 0)
-    # block multiset unchanged (partition, not duplication)
-    assert np.array_equal(sp.blk_ids, cs.blk_ids)
-    assert sp.blk_ptr[-1] == cs.blk_ptr[-1]
-    assert validate_schedule(sp).ok
-
-
-def test_split_merge_roundtrip_exact():
-    """merge_messages is the inverse of a payload split: bit-identical
-    arrays back (klane rounds are already src-major/canonical)."""
-    cs = IR.klane_alltoall_ir(Topology(3, 4, 2), 7)
-    sp = SplitPayloads(parts=4).apply(cs)
-    assert sp.num_msgs > cs.num_msgs
-    mg = IR.merge_messages(sp)
-    for f in ("src", "dst", "elems", "round_ptr", "blk_ptr", "blk_ids"):
-        assert np.array_equal(getattr(mg, f), getattr(cs, f)), f
-
-
-def test_split_messages_validates_factor_shape():
-    cs = IR.kported_alltoall_ir(8, 2, 3)
-    with pytest.raises(ValueError, match="factors"):
-        IR.split_messages(cs, np.ones(3, dtype=np.int64))
-
-
-def test_split_payloads_clamps_to_elems():
-    """c=1 messages cannot split: the pass is an identity there."""
-    cs = IR.klane_alltoall_ir(Topology(3, 4, 2), 1)
-    assert SplitPayloads(parts=4).apply(cs) is cs
-
-
-def test_split_payloads_ported_win_nonported_neutral():
-    """The k-lane decomposition: a lone sender's port term drops to
-    beta*E/k in the k-ported model; the 1-ported model is unchanged."""
-    topo = Topology(4, 6, 2)
-    machine = _machine(topo)
-    cs = IR.compiled_schedule("broadcast", "klane", topo, 2, 10_000)
-    sp = SplitPayloads().apply(cs)
-    assert sp.num_msgs > cs.num_msgs
-    assert validate_schedule(sp).ok
-    assert (
-        simulate(sp, machine, ported=True).time_us
-        < simulate(cs, machine, ported=True).time_us - 1e-9
-    )
-    assert simulate(sp, machine).time_us == pytest.approx(
-        simulate(cs, machine).time_us, rel=1e-12
-    )
-
-
-def test_optimize_mode_split_clamps_to_topology_lanes():
-    """optimize='split' derives parts from the machine's lane count — a
-    generator port parameter k > k_lanes must not oversplit (oversplitting
-    past k costs serial alpha batches in the ported model)."""
-    topo = Topology(4, 6, 2)  # 2 lanes, but generate with k=6 ports
-    base = IR.compiled_schedule("broadcast", "klane", topo, 6, 6)
-    opt = IR.compiled_schedule(
-        "broadcast", "klane", topo, 6, 6, optimize="split"
-    )
-    machine = _machine(topo)
-    assert (
-        simulate(opt, machine, ported=True).time_us
-        <= simulate(base, machine, ported=True).time_us + 1e-9
-    )
-    assert simulate(opt, machine).time_us == pytest.approx(
-        simulate(base, machine).time_us, rel=1e-12
-    )
-    with pytest.raises(ValueError, match="topology"):
-        optimize_schedule(base, "split")  # mode needs topo= or machine=
-
-
-# ---------------------------------------------------------------------------
-# PassManager: lex policy, fixpoint, oracle-revert failure path
-# ---------------------------------------------------------------------------
-
-
-def test_lex_policy_rejects_neutral_split():
-    """In the 1-ported model a split buys nothing: the lexicographic
-    objective (time, rounds, msgs) must reject the extra messages where
-    plain keep-if-not-worse would keep them."""
-    topo = Topology(4, 6, 2)
-    cs = IR.compiled_schedule("broadcast", "klane", topo, 2, 10_000)
-    pm = PassManager(
-        [SplitPayloads()], machine=_machine(topo), policy="lex", validate=True
-    )
-    opt, records = pm.run(cs)
-    assert opt is cs
-    assert not records[0].applied
-    pm_ported = PassManager(
-        [SplitPayloads()],
-        machine=_machine(topo),
-        ported=True,
-        policy="lex",
-        validate=True,
-    )
-    opt2, records2 = pm_ported.run(cs)
-    assert records2[0].applied and opt2.num_msgs > cs.num_msgs
-
-
-def test_fixpoint_iterates_then_stops():
-    """The limit-2k rung only reaches 2k-per-proc packing by re-running on
-    the limit-k result; the fixpoint loop must stop once a sweep applies
-    nothing."""
-    topo = Topology(4, 6, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    pm = PassManager(
-        [
-            ReorderRounds(limit=None, procs_per_node=6),
-            ReorderRounds(limit=2 * cs.k, procs_per_node=6),
-        ],
-        machine=_machine(topo),
-        policy="lex",
-        validate=True,
-        fixpoint=True,
-    )
-    opt, records = pm.run(cs)
-    assert validate_schedule(opt).ok
-    N, n, k = 4, 6, 2
-    assert opt.num_rounds == -(-(N - 1) * n // (2 * k)) + -(-(n - 1) // (2 * k))
-    iters = {r.iteration for r in records}
-    assert len(iters) >= 2  # progressed sweep + the terminating no-op sweep
-    last = max(iters)
-    assert not any(r.applied for r in records if r.iteration == last)
-
-
-class _DropBlockHop:
-    """Deliberately corrupting pass: silently drops the last block-hop of
-    the first message — the delivery goes missing."""
-
-    name = "drop_block_hop"
-
-    def apply(self, cs):
-        nblk = np.diff(cs.blk_ptr)
-        victim = int(np.flatnonzero(nblk > 0)[0])
-        cut = int(cs.blk_ptr[victim + 1]) - 1
-        blk_ptr = cs.blk_ptr.copy()
-        blk_ptr[victim + 1:] -= 1
-        blk_ids = np.delete(cs.blk_ids, cut)
-        return dataclasses.replace(
-            cs, blk_ptr=blk_ptr, blk_ids=blk_ids, _stats={}
-        )
-
-
-def test_corrupted_schedule_caught_and_reverted():
-    """ISSUE 3 failure-path satellite: a dropped block-hop must be caught
-    by validate_schedule, and PassManager(check=True) must revert the pass
-    instead of shipping the corrupt schedule (validate=True still raises)."""
-    topo = Topology(3, 4, 2)
-    cs = IR.compiled_schedule("alltoall", "klane", topo, 2, 7)
-    corrupt = _DropBlockHop().apply(cs)
-    report = validate_schedule(corrupt)
-    assert not report.ok and report.missing_final > 0
-
-    pm = PassManager([_DropBlockHop()], check=True)
-    opt, records = pm.run(cs)
-    assert opt is cs  # reverted, input untouched
-    assert records[0].applied is False
-    assert records[0].oracle_ok is False
-    assert validate_schedule(opt).ok
-
-    with pytest.raises(AssertionError, match="invalid"):
-        PassManager([_DropBlockHop()], validate=True).run(cs)
-
-    # a healthy pass after the reverted one still lands
-    pm2 = PassManager(
-        [_DropBlockHop(), ReorderRounds(limit=None, procs_per_node=4)],
-        check=True,
-    )
-    opt2, records2 = pm2.run(cs)
-    assert not records2[0].applied and records2[1].applied
-    assert opt2.num_rounds < cs.num_rounds
-    assert validate_schedule(opt2).ok
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 3 acceptance: paper-scale klane alltoall >= 2.2x
-# ---------------------------------------------------------------------------
-
-
-def test_opt2_klane_alltoall_paper_scale_speedup():
-    """At the paper's 36x32/k=2 the full scheduling-pass suite must beat
-    PR 2's 1.99x: >= 2.2x simulated over the unoptimized schedule at c=1,
-    oracle-valid, volume-preserving."""
-    topo = Topology(36, 32, 2)
-    base = IR.klane_alltoall_ir(topo, 1)
-    pm = PassManager(
-        [
-            ReorderRounds(limit=None, procs_per_node=32),
-            ReorderRounds(limit=2 * base.k, procs_per_node=32),
-            SplitPayloads(),
-            CoalesceMessages(),
-        ],
-        machine=HYDRA,
-        policy="lex",
-        validate=True,
-        fixpoint=True,
-    )
-    opt, records = pm.run(base)
-    base_us = simulate(base, HYDRA).time_us
-    opt_us = simulate(opt, HYDRA).time_us
-    assert base_us / opt_us >= 2.2
-    assert opt.num_rounds < 576  # strictly beyond adjacent compaction
-    assert opt.total_elems() == base.total_elems()
-    assert validate_schedule(opt).ok
-    assert any(r.applied and r.name.startswith("reorder") for r in records)
 
 
 # ---------------------------------------------------------------------------

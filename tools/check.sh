@@ -112,7 +112,7 @@ run_step "resilience-smoke" bash -c \
         --append --out chaos_report.json"
 
 # paper-scale OPT smoke (ISSUE 5 CI satellite): a single p=1152 alltoall
-# cell through the full optimize-validate pipeline, CHECK_TIMEOUT-bounded,
+# cell through the planner's optimize-validate pipeline, CHECK_TIMEOUT-bounded,
 # so the optimizer's scalability cannot silently regress in the fast job.
 # ISSUE 7: the smoke runs traced and exports the flight recorder (Chrome
 # trace + JSONL) and the metrics snapshot — CI uploads all three.
@@ -122,7 +122,7 @@ run_step "paper-opt-smoke" bash -c \
         --metrics paper_opt.metrics.json | tail -n 8"
 
 # observability smoke (ISSUE 7 CI satellite): tracer span nesting
-# (compile -> optimize -> pass -> oracle), export validity, selector
+# (compile -> optimize -> pass, compile -> oracle), export validity, selector
 # decision records, metrics counters — plus validation of the paper-opt
 # trace just exported above.
 run_step "obs-smoke" python -m tools.obs_check \
@@ -140,7 +140,7 @@ run_step "store-smoke" python -m tools.store_check
 run_step "load-smoke" python -m benchmarks.load --smoke \
     --report load_report.json
 
-# benchmark smoke -> fresh trajectory + the OPT/OPT2/OPT3 delta table (the
+# benchmark smoke -> fresh trajectory + the OPT3 delta table (the
 # delta file is the CI artifact reviewers diff); the gate fails on zero
 # cells, a disappeared cell, or any >5% sim_us regression vs the committed
 # baseline (with the --abs-tol floor guarding near-zero cells).  The
