@@ -114,7 +114,10 @@ class ParallelConfig:
 
     fsdp: bool = True  # additionally shard params' d_model dim over "data"
     remat: bool = True  # activation checkpointing on the layer scan
-    microbatches: int = 1  # gradient accumulation steps inside train_step
+    # gradient accumulation steps: exactly this many on the pjit step, at
+    # most this many on the shard_map step, which takes the fewest whose
+    # activations fit the chip
+    microbatches: int = 1
     collective_backend: Literal["xla", "fulllane", "kported"] = "xla"
     optimizer_dtype: str = "float32"  # bf16 moments for >=200B models
     grad_dtype: str = "float32"  # accumulation dtype (bf16 saves HBM at scale)

@@ -29,9 +29,11 @@ collective families.
 
   The dry-run lowers both and diffs collective bytes (EXPERIMENTS.md §Perf).
 
-Both support gradient accumulation (``parallel.microbatches``) via
-``lax.scan`` with fp32 accumulators; remat comes from the model's
-period-scan checkpoint policy.
+Both support gradient accumulation via ``lax.scan`` with fp32
+accumulators; remat comes from the model's period-scan checkpoint policy.
+The pjit step runs exactly ``parallel.microbatches`` micro-batches; the
+shard_map step runs the fewest of them, at most that many, whose
+activations fit the chip (:func:`choose_microbatches`).
 
 Each step's stages run under ``jax.named_scope``s: ``grad`` (forward,
 backward, the micro-batch scan), ``sync`` (the shard_map path's gradient
@@ -44,6 +46,7 @@ three.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -128,9 +131,11 @@ def make_act_shard(cfg: ModelConfig, mesh: Mesh):
     return act
 
 
-def _grad_and_metrics(cfg: ModelConfig, params, batch, act_shard=None):
-    """(grads fp32, metrics) with gradient accumulation if configured."""
-    n = max(cfg.parallel.microbatches, 1)
+def _grad_and_metrics(cfg: ModelConfig, params, batch, microbatches: int,
+                      act_shard=None):
+    """(grads fp32, metrics), the batch split into ``microbatches``
+    accumulated micro-batches."""
+    n = max(microbatches, 1)
 
     def loss_of(p, b):
         loss, metrics = lm.loss_fn(cfg, p, b, act_shard=act_shard)
@@ -162,6 +167,108 @@ def _grad_and_metrics(cfg: ModelConfig, params, batch, act_shard=None):
 
 
 # ---------------------------------------------------------------------------
+# Micro-batch count of the shard_map step.
+# ---------------------------------------------------------------------------
+
+# Share of the chip's memory left out of the budget for what neither count
+# holds: XLA's fusion temporaries, the gradient as the backward writes it
+# before its cast to ``grad_dtype``, the batch.
+ACCUM_HEADROOM = 0.1
+
+
+def _slot_bytes(cfg: ModelConfig, spec, rows: int, seq: int) -> int:
+    """Bytes one layer's recompute and backward hold at once for ``rows``
+    sequences of ``seq`` tokens, on the high side."""
+    a = jnp.dtype(cfg.dtype).itemsize
+    t, d, pl = rows * seq, cfg.d_model, cfg.parallel
+    # the residual stream's [t, d] tensors and their cotangents, f32 at most
+    total = 4 * t * d * 4
+    if spec.mixer == "attn":
+        at = cfg.attn
+        kv = at.num_kv_heads if at.kind == "gqa" else at.num_heads
+        q, o = at.num_heads * at.qk_head_dim, at.num_heads * at.out_head_dim
+        k, v = kv * at.qk_head_dim, kv * at.out_head_dim
+        # q, k, v and the output; the flash backward's f32 dq, dk, dv; one
+        # chunk pair's f32 scores, probabilities and their cotangent
+        total += (t * (q + k + v + o) * a + t * (q + k + v) * 4
+                  + 3 * rows * at.num_heads * min(pl.attn_chunk_q, seq)
+                  * min(pl.attn_chunk_kv, seq) * 4)
+        if at.kind == "mla":  # the compressed q and kv and their cotangents
+            total += 2 * t * ((at.q_lora_rank or 0) + at.kv_lora_rank
+                              + at.qk_rope_head_dim) * a
+    else:
+        m = cfg.mamba
+        di = m.expand * d
+        # the projections, conv, dt and gated output in f32; one chunk's
+        # f32 states and their cotangent
+        total += (8 * t * di * 4
+                  + 2 * rows * min(pl.mamba_chunk, seq) * di * m.d_state * 4)
+    if spec.ffn == "dense":  # gate, up, activation and their cotangents
+        total += 6 * t * cfg.d_ff * a
+    elif spec.ffn == "moe":
+        e = cfg.moe
+        cap = moe_mod.capacity(t, e)
+        # dispatch and combine buffers, the held experts' gate, up and
+        # activation, the shared experts', each with its cotangent; the
+        # router's f32 logits, probabilities and their cotangents
+        total += (4 * e.experts_held * cap * d * a
+                  + 6 * e.experts_held * cap * e.d_ff_expert * a
+                  + 6 * t * e.num_shared_experts * e.d_ff_expert * a
+                  + 4 * t * e.num_experts * 4)
+    return total
+
+
+def activation_bytes(cfg: ModelConfig, rows: int, seq: int) -> int:
+    """Bytes of activations one micro-batch of ``rows`` sequences of
+    ``seq`` tokens needs at the peak of the step, counted from the
+    config's shapes and on the high side: the period inputs the remat'd
+    layer scan saves (every period's whole recompute without remat), the
+    leading dense layers' residuals, the head's f32 logits and their
+    cotangent, and the largest transient of one period's recompute and
+    backward, attention chunked by ``attn_chunk_q`` and ``attn_chunk_kv``."""
+    a = jnp.dtype(cfg.dtype).itemsize
+    t = rows * seq
+    periods = lm._scanned_periods(cfg)
+    period = sum(_slot_bytes(cfg, spec, rows, seq)
+                 for spec in cfg.layer_pattern)
+    saved = (periods + 1) * t * cfg.d_model * a
+    if not cfg.parallel.remat:
+        saved += periods * period
+    prelude = cfg.first_k_dense * _slot_bytes(
+        cfg, dataclasses.replace(cfg.layer_pattern[0], ffn="dense"), rows,
+        seq)
+    head = t * cfg.num_codebooks * cfg.padded_vocab * 4 * 2
+    return saved + prelude + head + period
+
+
+def choose_microbatches(cfg: ModelConfig, rows: int, seq: int,
+                        budget: int | None) -> int:
+    """The micro-batch count for ``rows`` sequences of ``seq`` tokens a
+    chip: the smallest divisor ``n`` of ``parallel.microbatches`` that
+    divides ``rows`` and whose ``rows // n`` rows' :func:`activation_bytes`
+    fit ``budget``; where none fits, the largest such divisor.  With no
+    budget (the device reports no memory limit) the configured count."""
+    configured = max(cfg.parallel.microbatches, 1)
+    if budget is None:
+        return configured
+    counts = [n for n in range(1, configured + 1)
+              if configured % n == 0 and rows % n == 0]
+    return next((n for n in counts
+                 if activation_bytes(cfg, rows // n, seq) <= budget),
+                counts[-1])
+
+
+def _memory_limit(device) -> int | None:
+    """The device's ``bytes_limit``; None where it reports none (a CPU, a
+    described chip)."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
+
+
+# ---------------------------------------------------------------------------
 # pjit path.
 # ---------------------------------------------------------------------------
 
@@ -183,8 +290,8 @@ def make_train_step_pjit(cfg: ModelConfig, mesh: Mesh, opt_cfg: OptConfig):
 
     def step(params, opt_state, batch):
         with jax.named_scope("grad"):
-            grads, metrics = _grad_and_metrics(cfg, params, batch,
-                                               act_shard=act)
+            grads, metrics = _grad_and_metrics(
+                cfg, params, batch, cfg.parallel.microbatches, act_shard=act)
         with jax.named_scope("optimizer"):
             params, opt_state, info = adamw_update(grads, opt_state, params,
                                                    opt_cfg)
@@ -265,7 +372,16 @@ def make_train_step_shardmap(
     ``experts_per_chip``, ``capacity`` and the ``dispatch_bytes`` of one
     exchange per chip); ``train_step.zero1`` counts no expert leaf of the
     EP path as sharded.  The step's metrics then carry the
-    layers' ``moe_dropped`` and ``moe_routed``, summed over the DP axes."""
+    layers' ``moe_dropped`` and ``moe_routed``, summed over the DP axes.
+
+    Micro-batches: ``parallel.microbatches`` is the most this step splits
+    a chip's rows into.  Building the step for a batch takes the fewest
+    that divide the count and the rows and whose activations fit what the
+    first mesh device's ``bytes_limit`` leaves beside the resident state
+    (:func:`choose_microbatches`); a device that reports no limit (a CPU, a
+    described chip) runs the configured count.  The build records
+    ``train_step.accum`` (``configured``, ``used``, ``rows_per_micro``,
+    ``activation_bytes``, ``budget_bytes``) as an event of the tracer."""
     if cfg.parallel.fsdp:
         raise ValueError("shard_map path requires fsdp=False (replicated DP params)")
     dp = dp_axes(mesh)
@@ -300,6 +416,15 @@ def make_train_step_shardmap(
                  sharded_bytes_share=sum(
                      n for n, d in zip(sizes, dims) if d is not None)
                  / sum(sizes))
+    # What a chip keeps through the step besides activations: parameters
+    # and gradient as the shard_map holds them (an expert leaf, its EP
+    # share), the moments as it shards them.
+    nd = mesh_axis_sizes(mesh)[shard_axis] if shard_axis else 1
+    held = [n // nep if e is not None else n for n, e in zip(sizes, ep_dims)]
+    p_b, g_b, m_b = (jnp.dtype(t).itemsize for t in (
+        cfg.dtype, cfg.parallel.grad_dtype, cfg.parallel.optimizer_dtype))
+    resident = (sum(held) * (p_b + g_b) + 2 * m_b * sum(
+        n // nd if d is not None else n for n, d in zip(held, dims)))
     counts = lm.counter_names(cfg)
 
     def sync(g):
@@ -329,9 +454,9 @@ def make_train_step_shardmap(
     def sync_expert(g):
         return jax.lax.psum(g, rest) if rest else g
 
-    def step(params, opt_state, batch):
+    def step(n, params, opt_state, batch):
         with jax.named_scope("grad"):
-            grads, metrics = _grad_and_metrics(cfg, params, batch)
+            grads, metrics = _grad_and_metrics(cfg, params, batch, n)
         flat_p, treedef = jax.tree.flatten(params)
         with jax.named_scope("sync"):
             flat_g = [(sync_expert(g) if e is not None
@@ -396,10 +521,19 @@ def make_train_step_shardmap(
                    + counts}
 
     def jitted(batch_tree):
+        labels = batch_tree["labels"]
+        rows, seq = labels.shape[0] // ndp, labels.shape[1]
+        limit = _memory_limit(mesh.devices.flat[0])
+        budget = (None if limit is None
+                  else int(limit * (1 - ACCUM_HEADROOM)) - resident)
+        n = choose_microbatches(cfg, rows, seq, budget)
+        TRACER.event("train_step.accum",
+                     configured=max(cfg.parallel.microbatches, 1), used=n,
+                     rows_per_micro=rows // n,
+                     activation_bytes=activation_bytes(cfg, rows // n, seq),
+                     budget_bytes=budget)
         if ep:
-            labels = batch_tree["labels"]
-            rows = labels.shape[0] // ndp // max(cfg.parallel.microbatches, 1)
-            cap = moe_mod.capacity(rows * labels.shape[1], cfg.moe)
+            cap = moe_mod.capacity(rows // n * seq, cfg.moe)
             TRACER.event(
                 "train_step.ep",
                 expert_leaves=sum(e is not None for e in ep_dims),
@@ -408,7 +542,7 @@ def make_train_step_shardmap(
                 * jnp.dtype(cfg.dtype).itemsize)
         bspec_in = jax.tree.map(lambda _: P(dp), batch_tree)
         inner = jax.shard_map(
-            step,
+            functools.partial(step, n),
             mesh=mesh,
             in_specs=(pspec_in, ospec_in, bspec_in),
             out_specs=(pspec_in, ospec_in, metric_spec),

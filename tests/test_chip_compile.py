@@ -377,3 +377,47 @@ def test_v2_lite_ep_step_fits_a_v5e(topo):
     assert len(scopes) == 24
     assert all(re.search(r"moe/(dispatch|combine)/fulllane_all_to_all/"
                          r"(intra|cross_pod)/", n) for n in scopes), scopes
+
+
+def test_h2o_step_one_backward_on_a_v5e(topo, monkeypatch):
+    """The benchmark's h2o step on one chip (2 layers at published widths,
+    8 x 1024 tokens, configured for 8 micro-batches) where the device
+    reports a 16 GB chip's limit: the step runs one backward, so no f32
+    accumulator pass over the parameters (``grad/while/body/closed_call/
+    add``) is left, and the resident state plus ``activation_bytes`` bound
+    what the compiler allots, on the safe side."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.training import train_step
+    from repro.training.optimizer import OptConfig, init_opt_state
+
+    monkeypatch.setattr(train_step, "_memory_limit", lambda d: int(16e9))
+    base = get_config("h2o_danube_3_4b")
+    cfg = dataclasses.replace(base, num_layers=2, parallel=dataclasses.replace(
+        base.parallel, fsdp=False))
+    assert cfg.parallel.microbatches == 8
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1),
+                ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
+    opt = OptConfig()
+    mk, (pspec, ospec) = train_step.make_train_step_shardmap(cfg, mesh, opt)
+
+    def place(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            tree, specs)
+
+    params = jax.eval_shape(lambda: lm.init_model(cfg, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: init_opt_state(params, opt))
+    batch = {k: jax.ShapeDtypeStruct((8, 1024), jnp.int32,
+                                     sharding=NamedSharding(
+                                         mesh, P(("pod", "data"))))
+             for k in ("tokens", "labels")}
+    compiled = mk(batch).lower(place(params, pspec), place(state, ospec),
+                               batch).compile()
+    assert "grad/while/body/closed_call/add" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # bf16 parameters, f32 gradient, two f32 moments: 14 B a parameter
+    resident = 14 * sum(x.size for x in jax.tree.leaves(params))
+    assert resident + train_step.activation_bytes(cfg, 8, 1024) >= (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes)

@@ -16,8 +16,12 @@ from repro.models import moe as moe_mod
 from repro.models.params import ParamMeta
 from repro.obs.trace import TRACER
 from repro.training.optimizer import OptConfig, adamw_update, init_opt_state
+from repro.training import train_step
 from repro.training.train_step import (
+    ACCUM_HEADROOM,
     _grad_and_metrics,
+    activation_bytes,
+    choose_microbatches,
     dp_axes,
     make_train_step_pjit,
     make_train_step_shardmap,
@@ -73,7 +77,8 @@ def _replicated_step(cfg, mesh, opt):
     ndp = int(np.prod([mesh.shape[a] for a in dp]))
 
     def step(params, state, batch):
-        grads, _ = _grad_and_metrics(cfg, params, batch)
+        grads, _ = _grad_and_metrics(cfg, params, batch,
+                                     cfg.parallel.microbatches)
         grads = jax.tree.map(lambda g: jax.lax.psum(g, dp) / ndp, grads)
         return adamw_update(grads, state, params, opt)
 
@@ -83,9 +88,9 @@ def _replicated_step(cfg, mesh, opt):
                                  axis_names=set(dp), check_vma=False))
 
 
-def _zero1_event(build):
-    """Run ``build`` with the tracer on; the attributes of the
-    ``train_step.zero1`` event it records."""
+def _build_event(build, name):
+    """Run ``build`` with the tracer on; its result and the attributes of
+    the one ``name`` event it records."""
     was = bool(TRACER)
     TRACER.enable()
     mark = TRACER.mark()
@@ -94,10 +99,10 @@ def _zero1_event(build):
     finally:
         if not was:
             TRACER.disable()
-    events = [r for r in TRACER.records_since(mark)
-              if r["name"] == "train_step.zero1"]
+    events = [r["args"] for r in TRACER.records_since(mark)
+              if r["name"] == name]
     assert len(events) == 1
-    return out, events[0]["args"]
+    return out, events[0]
 
 
 @pytest.mark.parametrize("arch,shape,backend,whole_leaves", [
@@ -120,8 +125,9 @@ def test_zero1_step_matches_replicated(arch, shape, backend, whole_leaves):
     mesh = jax.make_mesh(shape, ("pod", "data", "model"),
                          devices=jax.devices()[:n],
                          axis_types=(AxisType.Auto,) * 3)
-    (mk, (_, ospec)), counts = _zero1_event(
-        lambda: make_train_step_shardmap(cfg, mesh, OPT, backend=backend))
+    (mk, (_, ospec)), counts = _build_event(
+        lambda: make_train_step_shardmap(cfg, mesh, OPT, backend=backend),
+        "train_step.zero1")
     leaves = len(jax.tree.leaves(lm.model_meta(cfg),
                                  is_leaf=lambda x: isinstance(x, ParamMeta)))
     assert counts["leaves"] == leaves
@@ -258,6 +264,87 @@ def test_microbatch_equivalence(mesh, arch):
         outs[n] = (float(m["loss"]), float(m["grad_norm"]))
     assert abs(outs[1][0] - outs[2][0]) < 1e-2
     assert abs(outs[1][1] - outs[2][1]) / max(outs[1][1], 1e-6) < 0.05
+
+
+def _micro(arch, n):
+    base = get_smoke_config(arch)
+    return dataclasses.replace(base, parallel=dataclasses.replace(
+        base.parallel, fsdp=False, microbatches=n))
+
+
+@pytest.mark.parametrize("configured,rows,fit_rows,want", [
+    (8, 8, 8, 1),  # the budget holds the chip's whole batch
+    (8, 8, 2, 4),  # the smallest divisor whose rows fit
+    (8, 8, 0, 8),  # nothing fits: the most micro-batches
+    (8, 6, 3, 2),  # 4 and 8 do not divide 6 rows
+    (8, 6, 0, 2),  # nothing fits: the largest count that divides the rows
+    (1, 8, 0, 1),  # a configured 1 stays 1
+    (8, 8, None, 8),  # no budget: the configured count
+    (8, 6, None, 8),
+])
+def test_choose_microbatches(configured, rows, fit_rows, want):
+    """The shard_map step's micro-batch count against a budget that holds
+    the activations of ``fit_rows`` rows (None: the device reports no
+    limit)."""
+    cfg, seq = _micro("h2o_danube_3_4b", configured), 32
+    budget = (None if fit_rows is None
+              else activation_bytes(cfg, max(fit_rows, 1), seq)
+              - (fit_rows == 0))
+    n = choose_microbatches(cfg, rows, seq, budget)
+    assert n == want
+    if budget is not None:
+        assert rows % n == 0 and configured % n == 0
+
+
+def test_activation_bytes_counts_the_head():
+    """At the benchmark's h2o widths the count grows with the rows and
+    holds at least the head's f32 logits and their cotangent: 8 x 8192
+    tokens a chip need 16.9 GB for those alone, more than a 16 GB chip."""
+    from repro.configs import get_config
+
+    cfg = get_config("h2o_danube_3_4b")
+    cfg = dataclasses.replace(cfg, num_layers=2)
+    head = 8 * 8192 * cfg.padded_vocab * 8
+    assert activation_bytes(cfg, 8, 8192) >= head > 16e9
+    assert activation_bytes(cfg, 8, 1024) > activation_bytes(cfg, 4, 1024)
+    assert choose_microbatches(cfg, 8, 8192, int(16e9)) > 1
+
+
+@pytest.mark.parametrize("backend", ["fulllane", "xla"])
+def test_shardmap_step_one_backward_where_it_fits(mesh, monkeypatch,
+                                                  backend):
+    """The h2o smoke model, 4 rows a chip, configured for 4 micro-batches:
+    with no memory limit the step runs all 4; where the device reports a
+    limit that holds a chip's whole batch it runs one backward and gives
+    the same first step (the tolerances of test_microbatch_equivalence).
+    The build records what it chose."""
+    cfg = _micro("h2o_danube_3_4b", 4)
+    batch = _batch(cfg, B=16)
+    params = lm.init_model(cfg, jax.random.PRNGKey(0))
+    opt = init_opt_state(params, OPT)
+    outs, events = {}, {}
+    for limit in (None, 1 << 40):
+        monkeypatch.setattr(train_step, "_memory_limit", lambda d: limit)
+        fn, ev = _build_event(lambda: make_train_step_shardmap(
+            cfg, mesh, OPT, backend=backend)[0](batch), "train_step.accum")
+        _, _, m = fn(jax.tree.map(jnp.copy, params),
+                     jax.tree.map(jnp.copy, opt), batch)
+        outs[limit], events[limit] = (float(m["loss"]),
+                                      float(m["grad_norm"])), ev
+    # every leaf keeps its moments sharded over data (2 chips)
+    n = sum(x.size for x in jax.tree.leaves(params))
+    resident = n * (2 + 4) + 2 * 4 * n // 2
+    assert events[None] == {
+        "configured": 4, "used": 4, "rows_per_micro": 1,
+        "activation_bytes": activation_bytes(cfg, 1, 32),
+        "budget_bytes": None}
+    assert events[1 << 40] == {
+        "configured": 4, "used": 1, "rows_per_micro": 4,
+        "activation_bytes": activation_bytes(cfg, 4, 32),
+        "budget_bytes": int((1 << 40) * (1 - ACCUM_HEADROOM)) - resident}
+    (l4, g4), (l1, g1) = outs[None], outs[1 << 40]
+    assert abs(l1 - l4) < 1e-2
+    assert abs(g1 - g4) / max(g4, 1e-6) < 0.05
 
 
 def test_fsdp_requires_pjit(mesh):
